@@ -5,7 +5,7 @@ applies per-line pragmas and the baseline, and exits non-zero on any
 active finding.  Pure stdlib — no jax import — so it runs in
 milliseconds as a pre-commit hook or the tier-1 gate test.
 
-    python -m loro_tpu.analysis.lint loro_tpu bench.py
+    python -m loro_tpu.analysis.lint loro_tpu bench.py chip_smoke.py
     python -m loro_tpu.analysis.lint --format=json loro_tpu
     python -m loro_tpu.analysis.lint --write-baseline loro_tpu bench.py
 

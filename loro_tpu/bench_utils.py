@@ -1,28 +1,49 @@
-"""Editing-trace loaders for benchmarks.
+"""Editing-trace sources for benchmarks and the chip smoke.
 
 Analog of the reference's bench-utils crate (crates/bench-utils/src/
-lib.rs:27-56 get_automerge_actions): loads the automerge-perf linear
-editing trace and converts it into the framework's op/element model.
-The extracted columnar element table is cached on disk because the
-conversion (running the host engine once to compute Fugue placements,
-i.e. the "source replica" role) is a one-time cost.
+lib.rs:27-56 get_automerge_actions): an automerge-perf style linear
+editing trace converted into the framework's op/element model.
+
+Every run names its source (``TraceSource``), chosen by its caller.
+The published ``automerge-paper.json.gz`` (259,778 single-character
+patches) is not in this repository and nothing of the repository reads
+outside its checkout, so the one source there is
+``TraceSource.synthetic(seed, patches)``: a seeded trace of the same
+shape (typing runs, ~10% deletes, positions valid at apply time), by
+default at the published length.  A missing file never turns into a
+shorter trace behind the caller's back.
+
+Records carry ``source.record()`` so a number is never compared across
+sources.  The extracted columnar tables are cached on disk (git-ignored,
+keyed by the source) because the conversion — running the host engine
+once to compute Fugue placements, the "source replica" role — is a
+one-time cost per checkout.
 """
 from __future__ import annotations
 
 import gzip
-import json
 import os
+import random
 import zipfile
 import zlib
+from dataclasses import dataclass
 from typing import List, Optional, Tuple
 
 import numpy as np
 
-TRACE_PATH = "/root/reference/crates/loro-internal/benches/automerge-paper.json.gz"
-CACHE_PATH = os.path.join(os.path.dirname(__file__), "..", ".bench_cache_automerge.npz")
-SYN_CACHE_PATH = os.path.join(
-    os.path.dirname(__file__), "..", ".bench_cache_automerge_syn.npz"
-)
+# patches in the published automerge-paper trace (text_r.rs B4,
+# BASELINE.md config 3): the default length of the synthetic source
+PUBLISHED_PATCHES = 259_778
+DEFAULT_SEED = 0xA07031
+
+_ROOT = os.path.join(os.path.dirname(__file__), "..")
+
+# Published peaks of the chips this repo has run on, keyed by
+# ``jax.devices()[0].device_kind``.  A device that is not here is an
+# error, not a default.  Source: Google Cloud documentation, "TPU v5e".
+DEVICE_PEAKS = {
+    "TPU v5 lite": {"hbm_bytes_per_s": 819e9},
+}
 
 # Extract-cache schema version.  Bump whenever the SeqExtract layout or
 # the chain/run extraction semantics feeding it change: a cache written
@@ -31,17 +52,39 @@ SYN_CACHE_PATH = os.path.join(
 # from before the tag existed).
 CACHE_SCHEMA = 2
 
-# flips to True when load_automerge_patches had to synthesize a trace
-# (no /root/reference checkout and no committed cache in this image);
-# bench.py tags its record so synthetic-trace numbers never get
-# compared against real-trace rounds
-SYNTHETIC_FALLBACK = False
+Patch = Tuple[int, int, str]
+
+
+@dataclass(frozen=True)
+class TraceSource:
+    """Where a run's patches come from (see the module docstring)."""
+
+    seed: int = DEFAULT_SEED
+    patches: int = PUBLISHED_PATCHES  # trace length
+
+    @classmethod
+    def synthetic(cls, seed: int = DEFAULT_SEED,
+                  patches: int = PUBLISHED_PATCHES) -> "TraceSource":
+        return cls(int(seed), int(patches))
+
+    def record(self) -> dict:
+        """The fields a result record carries to name its source."""
+        return {"trace": "synthetic", "seed": self.seed, "patches": self.patches}
+
+    def tag(self) -> str:
+        """Cache-file tag: distinct per source."""
+        return f"syn{self.seed:x}_{self.patches}"
+
+    def load(self, limit: Optional[int] = None) -> List[Patch]:
+        """[(pos, del_len, insert_str)] single-char patches; ``limit``
+        keeps a prefix."""
+        return _synthetic_patches(self.seed, min(self.patches, limit or self.patches))
 
 
 def _load_extract_cache(path: str):
     """SeqExtract + n_ops from an npz cache, or None when the cache is
     absent, carries a stale/missing schema tag, or is unreadable (a
-    bench child killed mid-savez leaves a truncated zip — rebuild and
+    run killed mid-savez leaves a truncated zip — rebuild and
     overwrite instead of crashing every later run)."""
     from .ops.columnar import SeqExtract
 
@@ -67,18 +110,14 @@ def _load_extract_cache(path: str):
         return None
 
 
-def _synthetic_patches(limit: Optional[int]) -> List[Tuple[int, int, str]]:
+def _synthetic_patches(seed: int, n: int) -> List[Patch]:
     """Deterministic single-char editing trace with the automerge-perf
     shape (typing runs, ~10% deletes, positions valid at apply time).
     Everything downstream replays patches through the host engine, so
-    the whole bench pipeline (variants, extraction, correctness gates)
-    works unchanged — only the absolute numbers aren't comparable to
-    real-trace rounds."""
-    import random
-
-    rng = random.Random(0xA07031)
-    n = (limit or 20000)
-    patches: List[Tuple[int, int, str]] = []
+    variants, extraction and correctness gates work as on the real
+    trace."""
+    rng = random.Random(seed)
+    patches: List[Patch] = []
     length = 0
     pos = 0
     run_left = 0
@@ -100,49 +139,22 @@ def _synthetic_patches(limit: Optional[int]) -> List[Tuple[int, int, str]]:
     return patches
 
 
-def load_automerge_patches(path: str = TRACE_PATH, limit: Optional[int] = None):
-    """[(pos, del_len, insert_str)] single-char patches + final content.
-    Falls back to a seeded synthetic trace when the reference trace
-    file is absent (fresh containers without /root/reference)."""
-    if not os.path.exists(path):
-        global SYNTHETIC_FALLBACK
-        SYNTHETIC_FALLBACK = True
-        return _synthetic_patches(limit), ""
-    with gzip.open(path) as f:
-        data = json.load(f)
-    patches: List[Tuple[int, int, str]] = []
-    for txn in data["txns"][:limit] if limit else data["txns"]:
-        for p in txn["patches"]:
-            patches.append((p[0], p[1], p[2]))
-    return patches, data.get("endContent", "")
-
-
-def automerge_seq_extract(limit: Optional[int] = None, use_cache: bool = True):
-    """SeqExtract of the full automerge trace (peer 1, linear history).
+def automerge_seq_extract(source: TraceSource, limit: Optional[int] = None,
+                          use_cache: bool = True):
+    """SeqExtract of the whole trace (peer 1, linear history).
     Applies the trace through the host engine once to derive each op's
     Fugue (parent, side) placement, then explodes to columns."""
     from .doc import LoroDoc
-    from .ops.columnar import SeqExtract, extract_seq_container
+    from .ops.columnar import extract_seq_container
 
-    # provenance-matched cache: a stale real-trace cache must not be
-    # served when the trace file is gone (the ground-truth text would
-    # replay the SYNTHETIC patches and the bench correctness gate
-    # would fail mid-run) — synthetic extracts cache under their own
-    # name and never shadow the real one
-    if limit is not None:
-        cache = None
-    elif os.path.exists(TRACE_PATH):
-        cache = CACHE_PATH
-    else:
-        cache = SYN_CACHE_PATH
-        global SYNTHETIC_FALLBACK
-        SYNTHETIC_FALLBACK = True  # even on a cache hit: tag the record
-    if use_cache and cache:
+    cache = None
+    if use_cache and limit is None:
+        cache = os.path.join(_ROOT, f".bench_cache_automerge_{source.tag()}.npz")
         hit = _load_extract_cache(cache)
         if hit is not None:
             return hit
 
-    patches, _ = load_automerge_patches(limit=limit)
+    patches = source.load(limit)
     doc = LoroDoc(peer=1)
     t = doc.get_text("text")
     for pos, dels, ins in patches:
@@ -154,7 +166,7 @@ def automerge_seq_extract(limit: Optional[int] = None, use_cache: bool = True):
     changes = doc.oplog.changes_in_causal_order()
     ex = extract_seq_container(changes, t.id)
     n_ops = len(patches)
-    if use_cache and cache:
+    if cache:
         np.savez_compressed(
             cache,
             parent=ex.parent,
@@ -171,116 +183,109 @@ def automerge_seq_extract(limit: Optional[int] = None, use_cache: bool = True):
     return ex, n_ops
 
 
-def automerge_final_text(limit: Optional[int] = None) -> str:
+def automerge_final_text(source: TraceSource, limit: Optional[int] = None) -> str:
     """Ground-truth final text by direct patch application."""
-    patches, end = load_automerge_patches(limit=limit)
-    buf: List[str] = []
     s = ""
-    for pos, dels, ins in patches:
+    for pos, dels, ins in source.load(limit):
         s = s[:pos] + ins + s[pos + dels :]
     return s
 
 
-VARIANT_CACHE_DIR = os.path.join(
-    os.path.dirname(__file__), "..", ".bench_cache_variants"
-)
+VARIANT_CACHE_DIR = os.path.join(_ROOT, ".bench_cache_variants")
+
+
+def concurrent_trace_variant(patches: List[Patch], seed: int, v: int,
+                             n_peers: int = 4, sync_every: int = 4000) -> dict:
+    """One genuinely-concurrent multi-peer variant of a patch stream:
+    the stream is routed across ``n_peers`` replicas in randomized
+    windows (editing sessions interleave at window granularity — this
+    preserves the trace's typing runs while creating real concurrency),
+    all replicas syncing every ``sync_every`` patches and fully at the
+    end.  The windows come from ``(seed, v)``; peer ids from ``v``.
+    Host-only Python (no device, no JAX): a module-level function so
+    that worker processes can run variants side by side.
+
+    Returns a dict:
+      payload: envelope-stripped update bytes (full history, all peers)
+      extract: SeqExtract ((peer, counter)-sorted element table)
+      text:    the converged document text (host-engine oracle)
+      n_ops:   patches actually applied (clamped deletes drop)
+    """
+    from .doc import LoroDoc, strip_envelope
+    from .ops.columnar import extract_seq_container
+
+    rng = random.Random(seed * 1_000_003 + 0xBE5C + v)
+    docs = [LoroDoc(peer=((v + 1) << 8) + i + 1) for i in range(n_peers)]
+    texts = [d.get_text("text") for d in docs]
+
+    def sync_all():
+        for d in docs[1:]:
+            docs[0].import_(d.export_updates(docs[0].oplog_vv()))
+        for d in docs[1:]:
+            d.import_(docs[0].export_updates(d.oplog_vv()))
+
+    cur = 0
+    window_left = 0
+    n_applied = 0  # trace events actually applied (clamped deletes drop)
+    for i, (pos, dels, ins) in enumerate(patches):
+        if window_left == 0:
+            cur = rng.randrange(n_peers)
+            window_left = rng.randint(32, 256)
+        window_left -= 1
+        t = texts[cur]
+        L = len(t)
+        p = min(pos, L)
+        applied = False
+        if dels:
+            d = min(dels, L - p)
+            if d:
+                t.delete(p, d)
+                applied = True
+        if ins:
+            t.insert(p, ins)
+            applied = True
+        if applied:  # same unit as the pristine n_ops: patch events
+            n_applied += 1
+        if (i + 1) % sync_every == 0:
+            sync_all()
+    sync_all()
+    sync_all()  # second round so every replica converges
+    ref = docs[0]
+    text = texts[0].to_string()
+    for t in texts[1:]:
+        if t.to_string() != text:
+            raise RuntimeError(f"variant {v}: replicas failed to converge")
+    payload = strip_envelope(ref.export_updates())
+    ex = extract_seq_container(ref.oplog.changes_in_causal_order(), texts[0].id)
+    return {"payload": payload, "extract": ex, "text": text, "n_ops": n_applied}
 
 
 def concurrent_trace_variants(
+    source: TraceSource,
     n_variants: int = 8,
     n_peers: int = 4,
     sync_every: int = 4000,
     limit: Optional[int] = None,
     use_cache: bool = True,
 ):
-    """Genuinely-concurrent multi-peer variants of the automerge trace.
-
-    Each variant routes the patch stream across `n_peers` replicas in
-    randomized windows (editing sessions interleave at window
-    granularity — this preserves the trace's typing runs while creating
-    real concurrency), syncing all replicas every `sync_every` patches
-    and fully at the end.  Every variant is a distinct document: the
-    concurrency windows, peer ids, and resulting Fugue trees differ per
-    variant seed.
-
-    Returns a list of dicts per variant:
-      payload: envelope-stripped update bytes (full history, all peers)
-      extract: SeqExtract ((peer, counter)-sorted element table)
-      text:    the converged document text (host-engine oracle)
-
-    Results cache to disk — generation replays the trace through the
-    host engine n_variants times (the one-time "source replica" cost).
-    """
+    """``n_variants`` distinct concurrent documents of one trace (see
+    ``concurrent_trace_variant``), as a list.  Results cache to disk —
+    generation replays the trace through the host engine once per
+    variant (tens of seconds each at the published length)."""
     import pickle
-    import random
 
-    from .doc import LoroDoc
-    from .ops.columnar import SeqExtract, extract_seq_container
-
-    tag = f"v{n_variants}_p{n_peers}_s{sync_every}_l{limit or 'full'}_n2"
-    if not os.path.exists(TRACE_PATH):
-        tag += "_syn"  # synthetic-trace variants cache separately
-    # gzip-pickled so the full-trace cache is small enough to COMMIT:
-    # a cold regeneration costs ~26s/variant on a 1-core image, which
-    # blew the round-2 driver bench budget before the first device op
+    tag = (f"v{n_variants}_p{n_peers}_s{sync_every}_l{limit or 'full'}"
+           f"_{source.tag()}_n3")
     cache = os.path.join(VARIANT_CACHE_DIR, tag + ".pkl.gz") if use_cache else None
     if cache and os.path.exists(cache):
         with gzip.open(cache, "rb") as f:
-            return pickle.load(f)
-    legacy = cache[: -len(".gz")] if cache else None
-    if legacy and os.path.exists(legacy):
-        with open(legacy, "rb") as f:
-            return pickle.load(f)
+            return pickle.load(f)  # written by this function, below
 
-    patches, _ = load_automerge_patches(limit=limit)
-    out = []
-    for v in range(n_variants):
-        rng = random.Random(0xBE5C + v)
-        docs = [LoroDoc(peer=((v + 1) << 8) + i + 1) for i in range(n_peers)]
-        texts = [d.get_text("text") for d in docs]
-
-        def sync_all():
-            for d in docs[1:]:
-                docs[0].import_(d.export_updates(docs[0].oplog_vv()))
-            for d in docs[1:]:
-                d.import_(docs[0].export_updates(d.oplog_vv()))
-
-        cur = 0
-        window_left = 0
-        n_applied = 0  # trace events actually applied (clamped deletes drop)
-        for i, (pos, dels, ins) in enumerate(patches):
-            if window_left == 0:
-                cur = rng.randrange(n_peers)
-                window_left = rng.randint(32, 256)
-            window_left -= 1
-            t = texts[cur]
-            L = len(t)
-            p = min(pos, L)
-            applied = False
-            if dels:
-                d = min(dels, L - p)
-                if d:
-                    t.delete(p, d)
-                    applied = True
-            if ins:
-                t.insert(p, ins)
-                applied = True
-            if applied:  # same unit as the pristine n_ops: patch events
-                n_applied += 1
-            if (i + 1) % sync_every == 0:
-                sync_all()
-        sync_all()
-        sync_all()  # second round so every replica converges
-        ref = docs[0]
-        text = texts[0].to_string()
-        for d, t in zip(docs[1:], texts[1:]):
-            assert t.to_string() == text, "variant replicas failed to converge"
-        from .doc import strip_envelope
-
-        payload = strip_envelope(ref.export_updates())
-        ex = extract_seq_container(ref.oplog.changes_in_causal_order(), texts[0].id)
-        out.append({"payload": payload, "extract": ex, "text": text, "n_ops": n_applied})
-        del docs, texts
+    patches = source.load(limit)
+    out = [
+        concurrent_trace_variant(patches, source.seed, v, n_peers, sync_every)
+        for v in range(n_variants)
+    ]
 
     if cache:
         os.makedirs(VARIANT_CACHE_DIR, exist_ok=True)

@@ -57,7 +57,7 @@ afterwards, which is a crash test, not a composition test),
 ``wal_torn_tail``/``ckpt_corrupt`` (byzantine-disk mangling: the
 durable bytes no longer match what the server acked, which the
 convergence oracle cannot model — targeted recovery tests own them),
-``backend_init`` (probe-subprocess only), ``evict_flush`` (armed only
+``evict_flush`` (armed only
 PAIRED directly before a ``demote`` step: fired mid-sync-ingest it
 would fail the fan-in worker, a known contract documented in
 docs/RESILIENCE.md), ``revive_replay`` (same pairing problem without a

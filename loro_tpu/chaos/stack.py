@@ -546,7 +546,7 @@ class ChaosStack:
                 # accept refusal: the FIRST reconnect attempt is
                 # refused typed; the retry (fault exhausted) serves
                 faultinject.inject("net_accept", action="raise", times=1)
-                cli.kill()  # tpulint: disable=LT-TUNNEL(NetClient.kill = abrupt socket close, not a process signal)
+                cli.kill()  # tpulint: disable=LT-CHIP(NetClient.kill = abrupt socket close, not a process signal)
                 try:
                     cli.reconnect()
                     bad.append(
@@ -556,7 +556,7 @@ class ChaosStack:
                     pass
             # abrupt kill + reconnect-with-frontier resume (retry once:
             # the armed fault above may have already torn the socket)
-            cli.kill()  # tpulint: disable=LT-TUNNEL(NetClient.kill = abrupt socket close, not a process signal)
+            cli.kill()  # tpulint: disable=LT-CHIP(NetClient.kill = abrupt socket close, not a process signal)
             for attempt in range(2):
                 try:
                     cli.reconnect()
@@ -572,7 +572,7 @@ class ChaosStack:
             for site in ("conn_stall", "net_frame", "net_accept"):
                 faultinject.clear(site)
             if cli is not None:
-                cli.kill()  # tpulint: disable=LT-TUNNEL(NetClient.kill = abrupt socket close, not a process signal)
+                cli.kill()  # tpulint: disable=LT-CHIP(NetClient.kill = abrupt socket close, not a process signal)
             if srv is not None:
                 srv.close()
         return bad

@@ -1,14 +1,20 @@
 """ctypes binding + on-demand build of the native wire->SoA decoder.
 
-Builds codec.cpp with g++ on first use (cached as codec.so next to the
-source; rebuilt when the source is newer).  Falls back gracefully: all
-callers must handle `available() == False` (pure-Python paths exist for
-everything — the native decoder is the throughput path for fleet
-decode, reference-parity with loro's Rust block decode).
+Builds codec.cpp with g++ on first use, cached next to the source as
+``codec.<hash>.so`` where the hash covers the source and the compiler
+flags: a binary built from other source — one copied along with a
+tree, say — has another name and is never loaded.  Library users fall
+back gracefully: every caller handles ``available() == False``
+(pure-Python paths exist for everything — the native decoder is the
+throughput path for fleet decode, reference-parity with loro's Rust
+block decode).  Measurement paths (bench.py, chip_smoke.py) call
+``require()`` instead, which makes a failed build an error.
 """
 from __future__ import annotations
 
 import ctypes
+import glob
+import hashlib
 import os
 import subprocess
 import threading
@@ -16,7 +22,7 @@ from typing import Optional
 
 import numpy as np
 
-from ..errors import CodecDecodeError
+from ..errors import CodecDecodeError, LoroError
 from ..obs import metrics as _obs
 from ..resilience import faultinject as _fi
 
@@ -27,32 +33,52 @@ _fi.register_site(
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_DIR, "codec.cpp")
-_SO = os.path.join(_DIR, "codec.so")
+_CXX = ("g++", "-O3", "-shared", "-fPIC", "-std=c++17")
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _build_failed = False
+_build_error = ""  # why the last build/load failed (require() reports it)
 
 
-def _build() -> bool:
-    tmp = f"{_SO}.{os.getpid()}.tmp"  # per-process: concurrent builds don't race
+def _so_path() -> str:
+    """``codec.<hash>.so``: the hash is over codec.cpp and the compiler
+    command, so the name changes whenever either does."""
+    h = hashlib.blake2b(" ".join(_CXX).encode(), digest_size=8)
+    with open(_SRC, "rb") as f:
+        h.update(f.read())
+    return os.path.join(_DIR, f"codec.{h.hexdigest()}.so")
+
+
+def _build(so: str) -> bool:
+    global _build_error
+    tmp = f"{so}.{os.getpid()}.tmp"  # per-process: concurrent builds don't race
     _obs.counter("codec.native_build_total").inc()
     try:
         subprocess.run(
-            ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", "-o", tmp, _SRC],
+            [*_CXX, "-o", tmp, _SRC],
             check=True,
             capture_output=True,
             timeout=120,
         )
-        os.replace(tmp, _SO)
-        return True
-    except (subprocess.SubprocessError, OSError):
+        os.replace(tmp, so)
+    except (subprocess.SubprocessError, OSError) as e:
         _obs.counter("codec.native_build_failed_total").inc()
+        stderr = getattr(e, "stderr", None) or b""
+        _build_error = f"{type(e).__name__}: {e} {stderr.decode(errors='replace')[-2000:]}"
         try:
             os.unlink(tmp)
         except OSError:
             pass
         return False
+    # binaries of older source: nothing loads them any more
+    for old in glob.glob(os.path.join(_DIR, "codec*.so")):
+        if old != so:
+            try:
+                os.unlink(old)
+            except OSError:
+                pass
+    return True
 
 
 def _obs_decode(fn: str, payload: bytes) -> bytes:
@@ -68,20 +94,21 @@ def _obs_decode(fn: str, payload: bytes) -> bytes:
 
 
 def _load() -> Optional[ctypes.CDLL]:
-    global _lib, _build_failed
+    global _lib, _build_failed, _build_error
     with _lock:
         if _lib is not None:
             return _lib
         if _build_failed:
             return None
-        need_build = not os.path.exists(_SO) or os.path.getmtime(_SO) < os.path.getmtime(_SRC)
-        if need_build and not _build():
+        so = _so_path()
+        if not os.path.exists(so) and not _build(so):
             _build_failed = True
             return None
         try:
-            lib = ctypes.CDLL(_SO)
-        except OSError:
+            lib = ctypes.CDLL(so)
+        except OSError as e:
             _build_failed = True
+            _build_error = f"{type(e).__name__}: {e}"
             return None
         lib.loro_count_seq_elements.restype = ctypes.c_longlong
         lib.loro_count_seq_elements.argtypes = [
@@ -216,6 +243,18 @@ def _load() -> Optional[ctypes.CDLL]:
 
 def available() -> bool:
     return _load() is not None
+
+
+def require() -> None:
+    """Raise unless the native decoder is built and loaded.  For paths
+    whose numbers or checks are about the native decode (bench.py,
+    chip_smoke.py): there a Python fallback would be a different
+    program, so a failed build is an error and says why."""
+    if _load() is None:
+        raise LoroError(
+            "native decoder unavailable (g++ build or load of "
+            f"{os.path.basename(_SRC)} failed): {_build_error}"
+        )
 
 
 def explode_seq_payload(payload: bytes, target_cid_index: int):

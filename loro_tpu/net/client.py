@@ -70,7 +70,7 @@ class NetClient:
         """Resume: fresh socket, HELLO with the frontiers this client
         already holds.  Safe after ``kill()`` or a server-side close."""
         if self._sock is not None:
-            self.kill()  # tpulint: disable=LT-TUNNEL(NetClient.kill is a socket close on a CPU-only TCP client — no process, no device work)
+            self.kill()  # tpulint: disable=LT-CHIP(NetClient.kill is a socket close on a TCP client — no process is signalled)
         return self.connect()
 
     def close(self) -> None:
@@ -81,7 +81,7 @@ class NetClient:
             self._send(wire.encode_bye())
         except (NetError, OSError):
             pass
-        self.kill()  # tpulint: disable=LT-TUNNEL(NetClient.kill is a socket close on a CPU-only TCP client — no process, no device work)
+        self.kill()  # tpulint: disable=LT-CHIP(NetClient.kill is a socket close on a TCP client — no process is signalled)
 
     def kill(self) -> None:
         """Abrupt close — the in-process stand-in for a SIGKILLed
@@ -187,7 +187,7 @@ class NetClient:
         try:
             s.sendall(wire.frame(body, self.max_frame))
         except OSError as e:
-            self.kill()  # tpulint: disable=LT-TUNNEL(NetClient.kill is a socket close on a CPU-only TCP client — no process, no device work)
+            self.kill()  # tpulint: disable=LT-CHIP(NetClient.kill is a socket close on a TCP client — no process is signalled)
             raise NetError(f"send failed: {e}") from e
 
     def _recv_exact(self, n: int) -> bytes:
@@ -201,10 +201,10 @@ class NetClient:
                     f"timed out waiting for {n - len(buf)} more bytes "
                     f"after {self.timeout}s") from e
             except OSError as e:
-                self.kill()  # tpulint: disable=LT-TUNNEL(NetClient.kill is a socket close on a CPU-only TCP client — no process, no device work)
+                self.kill()  # tpulint: disable=LT-CHIP(NetClient.kill is a socket close on a TCP client — no process is signalled)
                 raise NetError(f"recv failed: {e}") from e
             if not chunk:
-                self.kill()  # tpulint: disable=LT-TUNNEL(NetClient.kill is a socket close on a CPU-only TCP client — no process, no device work)
+                self.kill()  # tpulint: disable=LT-CHIP(NetClient.kill is a socket close on a TCP client — no process is signalled)
                 raise NetError("connection closed by the server")
             buf += chunk
         return bytes(buf)
@@ -231,7 +231,7 @@ class NetClient:
                         wire.raise_error(fields)
                     continue  # a stale request's error: not ours
                 if t == wire.BYE:
-                    self.kill()  # tpulint: disable=LT-TUNNEL(NetClient.kill is a socket close on a CPU-only TCP client — no process, no device work)
+                    self.kill()  # tpulint: disable=LT-CHIP(NetClient.kill is a socket close on a TCP client — no process is signalled)
                     raise NetError("server said BYE (shutting down)")
                 if t == wire.EVENT and (rid is None
                                         or fields.get("rid") != rid):

@@ -369,8 +369,8 @@ class ExportIndex:
         first use) at the CURRENT row capacity.  The kernel jit-caches
         per (requests, frontier-width, capacity) bucket, so without
         this the first window at each fresh bucket pays the XLA
-        compile INSIDE a session's pull latency — a p99 spike, and on
-        a real chip a remote-compile round-trip.  Also pre-compiles
+        compile INSIDE a session's pull latency — a p99 spike (the top
+        rung takes ~20 s to compile for a v5e).  Also pre-compiles
         the dirty-doc scatter delta (``_device_cols``) over its own
         idx-bucket ladder — on the CPU mesh the scatter's first
         compile dominates the first post-commit window, not the

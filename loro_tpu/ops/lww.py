@@ -77,11 +77,6 @@ def make_lww_sharded(mesh, n_slots: int):
     P(docs, ops) -> (value_idx, lamport, peer) [D, S] P(docs)."""
     from jax.sharding import PartitionSpec as P
 
-    try:  # jax >= 0.8
-        from jax import shard_map
-    except ImportError:  # pragma: no cover - older jax
-        from jax.experimental.shard_map import shard_map
-
     from ..parallel.mesh import DOC_AXIS, OP_AXIS
 
     def local(cols: MapOpCols):
@@ -100,7 +95,7 @@ def make_lww_sharded(mesh, n_slots: int):
         return g_val, g_lam, g_peer
 
     return jax.jit(
-        shard_map(
+        jax.shard_map(
             local,
             mesh=mesh,
             in_specs=(MapOpCols(*([P(DOC_AXIS, OP_AXIS)] * 5)),),

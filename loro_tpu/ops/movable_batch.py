@@ -10,14 +10,13 @@ revives it — matching models/movable_list_state.py).
 """
 from __future__ import annotations
 
-import functools
 from typing import NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .fugue_batch import SeqColumns, fugue_order, rank_bound
+from .fugue_batch import SeqColumns, doc_batch_jit, fugue_order, rank_bound
 
 NEG = jnp.int32(-(2**31) + 1)
 
@@ -96,7 +95,7 @@ def movable_merge_doc(cols: MovableCols, n_elems: int) -> Tuple[jax.Array, jax.A
     return out, count
 
 
-@functools.partial(jax.jit, static_argnums=(1,))
+@doc_batch_jit
 def movable_merge_batch(cols: MovableCols, n_elems: int):
     return jax.vmap(lambda c: movable_merge_doc(c, n_elems))(cols)
 

@@ -2,32 +2,28 @@
 of the Fugue order solve.
 
 The XLA formulation (ops/fugue_batch._order_core) round-trips the succ/
-dist arrays through HBM on every pointer-doubling step; profiling on a
-v5e showed that loop dominating merge time (random-access gathers at
-~100M elem/s).  A chain-contracted ring (typically <=48k tokens =
-<=200KB) fits in VMEM (~16MB/core), so this kernel keeps both arrays
-on-chip for all ceil(log2(m)) rounds and only touches HBM twice.
+dist arrays through HBM on every pointer-doubling step.  A
+chain-contracted ring (typically <=48k tokens = <=200KB) fits in VMEM
+(~16MB/core), so this kernel keeps both arrays on-chip for all
+ceil(log2(m)) rounds and only touches HBM twice.
 
-Status: validated AND profiled on a real v5e (2026-07-29).  The
-deployed Mosaic toolchain only lowers dynamic_gather along lanes
-(axis=1, <=128 lanes; axis-0 gathers past one 8-sublane vreg fail
-remote compile), so the arbitrary gather is decomposed as an R-step
-row-rotate loop (see _vmem_gather).  Measured on the flagship ring
-shape (m=32896), amortized over distinct rings in one jit:
-  single ring: 5.0 ms vs 11.1 ms XLA textbook loop
-  vmap8 chunk: 15.2 ms vs 128.2 ms XLA  (8.4x on the bench shape;
-    grid programs pipeline, so per-ring cost drops to 1.9 ms)
+The arbitrary gather is decomposed as an R-step row-rotate loop over
+within-row lane gathers (see _vmem_gather): take_along_axis along
+axis 1, <=128 lanes, is the dynamic_gather form these kernels rely on.
+All four kernels (packed wyllie, packed ruling, blocked, dual-table
+wide) compile and agree with the XLA rank on a TPU v5 lite under
+jax/jaxlib 0.9.0 + libtpu 0.0.34 (PERF.md, PR 21); tests/
+test_chip_compile.py compiles each for a described v5e on every run.
+
 Default: ON when the backend is TPU and the ring fits
 PALLAS_RANK_MAX_M; force with PALLAS_RANK=1, disable with
 PALLAS_RANK=0.  Off-TPU the XLA path remains the default (the
 interpreter-mode kernel is for differential tests).
 
-PALLAS_RANK_ALGO selects ruling (default) | wyllie for rings <= 65536.
-The ruling-set kernel (phase-1 adaptive freeze at index%8 rulers with
-terminal-absorption detection, dense m/8 ruler ring + sink row,
-small-table recombine) measured 12.6 ms vs 15.6 ms wyllie on the vmap8
-bench chunk once the phase-1 early exit also recognised non-ruler
-terminals; flagship bench 70.2M -> 79.3M ops/s.
+PALLAS_RANK_ALGO selects ruling (default) | wyllie | blocked for rings
+<= 65536.  The ruling-set kernel: phase-1 adaptive freeze at index%8
+rulers with terminal-absorption detection, dense m/8 ruler ring + sink
+row, small-table recombine.
 """
 from __future__ import annotations
 
@@ -39,13 +35,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-try:  # pallas is part of jax, but keep the import soft for safety
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    HAVE_PALLAS = True
-except Exception:  # pragma: no cover — tpulint: disable=LT-EXC(soft import probe: any pallas breakage means "no pallas", not a crash)
-    HAVE_PALLAS = False
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 PALLAS_RANK_ALGOS = ("wyllie", "ruling", "blocked")
@@ -65,18 +56,13 @@ def _pallas_rank_algo() -> str:
 
 def use_pallas_rank() -> bool:
     """PALLAS_RANK=1 forces on, =0 forces off; unset = auto (on iff the
-    backend is TPU — measured 8.4x over the XLA rank on v5e)."""
-    if not HAVE_PALLAS:
-        return False
+    backend is TPU)."""
     flag = os.environ.get("PALLAS_RANK", "")
     if flag == "0":
         return False
     if flag:
         return True
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:  # tpulint: disable=LT-EXC(backend init failure means stay on the XLA path, whatever the backend threw)
-        return False
+    return jax.default_backend() == "tpu"
 
 
 # Above this ring length the R-step rotate loop (R = m/128 iterations
@@ -93,11 +79,9 @@ _LANES = 128
 
 
 def _vmem_gather(tbl, rows, cols):
-    """Full dynamic gather out[i,j] = tbl[rows[i,j], cols[i,j]] from the
-    one dynamic_gather form the deployed Mosaic accepts: within-row lane
-    gather (take_along_axis axis=1, <=128 lanes, any sublane count;
-    axis-0 gathers beyond one 8-sublane vreg fail to compile on this
-    libtpu).  Arbitrary (row, lane) addressing is decomposed as an
+    """Full dynamic gather out[i,j] = tbl[rows[i,j], cols[i,j]] from
+    the within-row lane gather (take_along_axis axis=1, <=128 lanes, any
+    sublane count).  Arbitrary (row, lane) addressing is decomposed as an
     R-step row-rotate loop: after t rolls, rot[i, :] = tbl[(i+t) % R, :],
     so a lane-gather with `cols` yields tbl[(i+t) % R, cols[i,j]], kept
     wherever rows[i,j] == (i+t) % R.  All operands stay in
@@ -468,7 +452,11 @@ def wyllie_rank(
     )
     if needs_wide:
         quantum = _LANES
-    mp = -(-m // quantum) * quantum
+    # at least two rows in every table the kernel gathers from (the ring
+    # itself, and the dense ruler ring of mp/quantum rows): Mosaic refuses
+    # the lane gather on a one-row table ("Shape mismatch in input,
+    # indices and output").  Pad tokens are self-loop terminals.
+    mp = max(2, -(-m // quantum)) * quantum
     if mp > PALLAS_RANK_MAX_M:
         raise ValueError(f"ring too long for VMEM ranking: {m}")
     tok = jnp.arange(m, dtype=jnp.int32)

@@ -25,6 +25,7 @@ from .fugue_batch import (
     SeqColumns,
     _order_core,
     chain_positions,
+    doc_batch_jit,
     fugue_order,
     rank_bound,
 )
@@ -169,7 +170,7 @@ def richtext_merge_doc(
     return codes, count, bounds, win_value
 
 
-@functools.partial(jax.jit, static_argnums=(1,))
+@doc_batch_jit
 def richtext_merge_batch(cols: RichtextCols, n_keys: int):
     return jax.vmap(lambda c: richtext_merge_doc(c, n_keys))(cols)
 
@@ -230,7 +231,7 @@ def richtext_chain_merge_doc(
     return codes, count, bounds, win_value
 
 
-@functools.partial(jax.jit, static_argnums=(1,))
+@doc_batch_jit
 def richtext_chain_merge_batch(cols: RichtextChainCols, n_keys: int):
     return jax.vmap(lambda c: richtext_chain_merge_doc(c, n_keys))(cols)
 

@@ -11,8 +11,6 @@ instrumented choke points of the device pipeline:
                      wire bytes before the C++ parser sees them
 - ``poison_doc``   — ResidentServer.ingest: corrupt one doc's payload
                      in a round (per-doc isolation test)
-- ``backend_init`` — resilience.probe subprocesses: hang or raise
-                     during backend init (the TPU-pool lottery)
 - ``wal_write``    — persist.wal append: raise/delay before the frame
                      reaches disk (durability-path failures)
 - ``wal_torn_tail``— persist.wal append: mangle the frame bytes on
@@ -77,8 +75,8 @@ Arm programmatically::
     finally:
         fi.clear()
 
-or from the environment (processes you can't reach, e.g. probe
-subprocesses): ``LORO_FAULT="launch:raise:times=2;decode:truncate=16"``.
+or from the environment (processes you can't reach, e.g. crash-test
+children): ``LORO_FAULT="launch:raise:times=2;decode:truncate=16"``.
 Entries are ``;``-separated ``site:action[:k=v]*`` specs; actions are
 ``raise`` (optional ``msg=``, default transient ``UNAVAILABLE``),
 ``delay`` (``s=`` seconds), ``hang`` (delay with a 60s safety clamp),
@@ -123,7 +121,6 @@ from ..obs import metrics as _obs
 # but undocumented).
 _SITE_MODULES = (
     "loro_tpu.resilience.supervisor",
-    "loro_tpu.resilience.probe",
     "loro_tpu.native",
     "loro_tpu.parallel.fleet",
     "loro_tpu.parallel.server",
@@ -381,7 +378,7 @@ def mangle(site: str, payload, doc: Optional[int] = None):
 
 # -- env wiring (LORO_FAULT) -------------------------------------------
 def _load_env() -> None:
-    """Parse LORO_FAULT once per process (probe subprocesses and CI
+    """Parse LORO_FAULT once per process (crash-test children and CI
     runs arm faults without touching Python)."""
     global _env_loaded
     if _env_loaded:

@@ -155,30 +155,30 @@ class TestRuleFixtures:
         )
         assert rules_of(lint_source(ok, path="loro_tpu/sync/fixture.py")) == []
 
-    def test_tunnel_rule_flags_all_three_post_mortems(self):
+    @pytest.mark.parametrize("path", [
+        "loro_tpu/parallel/fixture.py", "bench.py", "chip_smoke.py",
+    ])
+    def test_chip_rule_flags_every_way_to_signal_a_process(self, path):
         bad = (
-            "import os, signal, jax\n"
-            "from jax import lax\n"
-            "def f(out, pid, proc, n, body, x):\n"
-            "    jax.block_until_ready(out)\n"
+            "import os, signal\n"
+            "def f(pid, proc):\n"
             "    os.kill(pid, signal.SIGTERM)\n"
             "    proc.terminate()\n"
-            "    return lax.fori_loop(0, n, body, x, unroll=8)\n"
+            "    proc.kill()\n"
+            "    proc.send_signal(signal.SIGINT)\n"
         )
-        got = rules_of(lint_source(bad, path="loro_tpu/parallel/fixture.py",
-                                   rules=["LT-TUNNEL"]))
-        assert got == ["LT-TUNNEL"] * 4
+        got = rules_of(lint_source(bad, path=path, rules=["LT-CHIP"]))
+        assert got == ["LT-CHIP"] * 4
 
-    def test_tunnel_rule_clean_for_honest_sync_and_sig0(self):
+    def test_chip_rule_clean_for_sync_and_sig0(self):
         ok = (
-            "import os\nimport numpy as np\n"
-            "from jax import lax\n"
-            "def f(out, pid, n, body, x):\n"
-            "    np.asarray(out)  # the honest fetch-sync\n"
+            "import os\nimport jax\n"
+            "def f(out, pid):\n"
+            "    jax.block_until_ready(out)  # synchronises on the chip\n"
             "    os.kill(pid, 0)  # existence probe, sends nothing\n"
-            "    return lax.fori_loop(0, n, body, x, unroll=1)\n"
         )
-        assert rules_of(lint_source(ok, path="loro_tpu/parallel/fixture.py")) == []
+        assert rules_of(lint_source(ok, path="loro_tpu/parallel/fixture.py",
+                                    rules=["LT-CHIP"])) == []
 
     def test_lock_rule_flags_inverted_static_nesting(self):
         bad = (
@@ -202,7 +202,7 @@ class TestRuleFixtures:
             "                    pass\n"
         )
         assert rules_of(lint_source(
-            ok, path="loro_tpu/parallel/fixture.py", rules=["LT-TUNNEL"]
+            ok, path="loro_tpu/parallel/fixture.py", rules=["LT-CHIP"]
         )) == []
 
 
@@ -312,11 +312,11 @@ class TestBaseline:
 class TestRepoGate:
     def test_repo_is_lint_clean(self):
         """THE gate: zero unsuppressed findings over loro_tpu/ +
-        bench.py, every suppression carrying a reason.  A new finding
-        means: fix it, or pragma it with the reason a reviewer should
-        read."""
+        bench.py + chip_smoke.py, every suppression carrying a reason.
+        A new finding means: fix it, or pragma it with the reason a
+        reviewer should read."""
         res = lint_paths(
-            [os.path.join(REPO, "loro_tpu"), os.path.join(REPO, "bench.py")]
+            [os.path.join(REPO, p) for p in ("loro_tpu", "bench.py", "chip_smoke.py")]
         )
         assert res.active == [], "\n" + "\n".join(
             f.render() for f in res.active
@@ -368,7 +368,7 @@ class TestCli:
         r = self._run(["--list-rules"], cwd=tmp_path)
         assert r.returncode == 0
         for rid in ("LT-DEV", "LT-PAD", "LT-HASH", "LT-TIME", "LT-EXC",
-                    "LT-TUNNEL", "LT-LOCK"):
+                    "LT-CHIP", "LT-LOCK"):
             assert rid in r.stdout
 
 

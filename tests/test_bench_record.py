@@ -2,7 +2,9 @@
 bench's FINAL stdout line must always be compact enough that a
 2,000-char tail window captures every flagship field — verbose notes
 and dict sidecars ride a separate `sidecars_for` line printed before
-it, and the parent's backward scan re-merges the two."""
+it.  Plus what every record must say about where it ran: platform,
+device kind, device count, the trace source — and that a number from
+the CPU never goes under a device metric's name."""
 import importlib.util
 import json
 import os
@@ -38,7 +40,11 @@ def _fat_checkpoint():
         value=5.9e6,
         metric="ops_merged_per_sec_per_chip (test)",
         unit="ops/s",
-        device="tpu:v5e",
+        platform="tpu",
+        device_kind="TPU v5 lite",
+        device_count=1,
+        trace_source={"trace": "synthetic", "seed": 10514481,
+                      "patches": 259778},
         kernel="pallas",
         place_algo="sort",
         last_phase="done",
@@ -51,7 +57,6 @@ def _fat_checkpoint():
         merge_latency_ms_max=200.0,
         latency_samples=1024,
         latency_note="x" * 400,
-        tunnel_rtt_ms=75.0,
         ring_tokens_per_doc=20000,
         rank_rounds=15,
         gather_rows_per_sec=90_000_000,
@@ -217,7 +222,8 @@ class TestFlagshipLine:
         assert len(line) <= bench.FLAGSHIP_BUDGET, len(line)
         back = json.loads(line)  # parses standalone
         # flagship numerics survive the split
-        for k in ("metric", "value", "unit", "vs_baseline", "device",
+        for k in ("metric", "value", "unit", "vs_baseline", "platform",
+                  "device_kind", "device_count", "trace_source",
                   "resident_pipeline_speedup", "resident_durable_fsyncs",
                   "resident_durable_group_fsyncs", "rank_gather_reduction",
                   "sync_sessions", "sync_pushes_per_sec",
@@ -262,16 +268,6 @@ class TestFlagshipLine:
         tail = "\n".join(out)[-2000:]
         assert json.loads(tail.splitlines()[-1]) == last
 
-    def test_last_json_record_remerges_sidecars(self, bench, tmp_path):
-        rec = bench.assemble_record(_fat_checkpoint())
-        p = tmp_path / "out.jsonl"
-        flag, side = bench.split_record(rec)
-        p.write_text(json.dumps(side) + "\n" + json.dumps(flag) + "\n")
-        merged = bench._last_json_record(str(p))
-        assert merged["metric"] == flag["metric"]
-        assert "metrics" in merged and "resilience" in merged
-        assert "sidecars" not in merged
-
     def test_small_record_stays_single_line(self, bench, capsys):
         bench.emit_record({"metric": "m", "value": 1, "unit": "ops/s",
                            "vs_baseline": 0.5})
@@ -290,3 +286,60 @@ class TestFlagshipLine:
             assert k in flag
         spilled = [k for k in side if k.startswith("extra_field_")]
         assert spilled  # the overflow went to the sidecar line
+
+
+class TestWhereItRan:
+    def test_tpu_record_keeps_the_device_metric_name(self, bench):
+        rec = bench.assemble_record(_fat_checkpoint())
+        assert rec["metric"] == "ops_merged_per_sec_per_chip (test)"
+        assert (rec["platform"], rec["device_kind"], rec["device_count"]) == (
+            "tpu", "TPU v5 lite", 1)
+
+    @pytest.mark.parametrize("platform", ["cpu", "gpu", "unknown"])
+    def test_off_chip_number_never_goes_under_a_device_metric_name(
+            self, bench, platform):
+        ck = dict(_fat_checkpoint(), platform=platform, device_kind=platform)
+        if platform == "unknown":
+            del ck["platform"]  # a record banked before device contact
+        metric = bench.assemble_record(ck)["metric"]
+        assert not metric.startswith("ops_merged_per_sec_per_chip")
+        assert "not a device metric" in metric and platform in metric
+
+    def test_no_tpu_is_an_error_unless_the_cpu_was_asked_for(
+            self, bench, monkeypatch):
+        """This process runs on the CPU backend.  Without an explicit
+        JAX_PLATFORMS that is a failure (exit, no record); with it, a
+        labelled rehearsal."""
+        monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+        with pytest.raises(SystemExit) as ei:
+            bench.device_fields()
+        assert ei.value.code not in (0, None) and "no TPU" in str(ei.value.code)
+        monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+        dev = bench.device_fields()
+        assert dev["platform"] == "cpu" and dev["device_count"] >= 1
+        assert "device_kind" in dev
+
+    def test_a_tpu_outside_the_peaks_table_is_an_error(self, bench, monkeypatch):
+        import jax
+
+        class FakeTpu:
+            platform = "tpu"
+            device_kind = "TPU v99 imaginary"
+
+        monkeypatch.setattr(jax, "devices", lambda: [FakeTpu()])
+        with pytest.raises(SystemExit) as ei:
+            bench.device_fields()
+        assert "DEVICE_PEAKS" in str(ei.value.code)
+
+    def test_simple_configs_carry_device_fields(self, bench, capsys, monkeypatch):
+        monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+        bench._emit_simple("lww_map ops merged/sec", 1e6)
+        rec = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+        assert rec["platform"] == "cpu" and "not a device metric" in rec["metric"]
+        assert rec["device_count"] >= 1 and "device_kind" in rec
+
+    def test_the_guarded_parent_is_gone(self, bench):
+        for name in ("main_guarded", "_emit_terminal_failure",
+                     "_run_capture_child", "_last_json_record", "_ckpt_path",
+                     "_fetch_sync", "HBM_PEAK"):
+            assert not hasattr(bench, name), name

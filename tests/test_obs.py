@@ -129,12 +129,12 @@ def test_sidecar_shape(reg):
     from loro_tpu.obs.exposition import sidecar
 
     reg.counter("fleet.ops_merged_total").inc(7, family="text")
-    reg.gauge("tunnel.rtt_ms").set(74.0)
+    reg.gauge("resilience.in_flight").set(3.0)
     reg.histogram("server.epoch_seconds").observe(0.25)
     side = sidecar(reg)
     assert side["fleet.ops_merged_total"] == 7
     assert side["fleet.ops_merged_total{family=text}"] == 7
-    assert side["tunnel.rtt_ms"] == 74
+    assert side["resilience.in_flight"] == 3
     hs = side["server.epoch_seconds"]
     assert hs["count"] == 1 and hs["p50"] is not None
 

@@ -3,9 +3,7 @@ CPU mesh; hardware lowering is profiled on TPU separately)."""
 import numpy as np
 import pytest
 
-from loro_tpu.ops.pallas_rank import HAVE_PALLAS, wyllie_rank, wyllie_rank_xla
-
-pytestmark = pytest.mark.skipif(not HAVE_PALLAS, reason="pallas unavailable")
+from loro_tpu.ops.pallas_rank import wyllie_rank, wyllie_rank_xla
 
 
 def _random_ring(m: int, seed: int) -> np.ndarray:

@@ -392,16 +392,19 @@ def test_coalesced_supernode_guard():
     )
 
 
-def test_coalesced_guard_on_real_trace_rings():
-    """The flagship ring shape (chain-contracted automerge trace padded
-    to the bench quantum — the exact ring bench.py ranks) must show the
+def test_coalesced_guard_on_trace_rings():
+    """The flagship ring shape (a chain-contracted editing trace padded
+    to the bench quantum — the kind of ring bench.py ranks; here the
+    first 20,000 patches of the seeded synthetic source) must show the
     >=2x global gather-row reduction for coalesced-at-measured-budget
     vs wyllie.  This is the ISSUE 6 acceptance bound as a standing
     guard; the bench banks the same counts in its `rank` sidecar."""
-    from loro_tpu.bench_utils import automerge_seq_extract
+    from loro_tpu.bench_utils import TraceSource, automerge_seq_extract
     from loro_tpu.ops.columnar import contract_chains
 
-    ex, _n_ops = automerge_seq_extract()
+    ex, _n_ops = automerge_seq_extract(
+        TraceSource.synthetic(patches=20_000), use_cache=False
+    )
     ch = contract_chains(ex)
     pad_c = -(-ch.n_chains // 1024) * 1024  # the bench quantum
     parent = np.full(pad_c, -1, np.int32)

@@ -38,8 +38,8 @@ def test_readme_quickstart_executes():
     }
     fleet_block = blocks[1]
     # shrink the illustrative capacities so the smoke run is fast
-    fleet_block = fleet_block.replace("n_docs=10_000", "n_docs=3").replace(
-        "capacity=1 << 18", "capacity=1024"
+    fleet_block = fleet_block.replace("n_docs=4096", "n_docs=3").replace(
+        "capacity=1 << 14", "capacity=1024"
     )
     exec(fleet_block, ns2)  # noqa: S102
     assert ns2["texts"] == [d.get_text("t").to_string() for d in docs]

@@ -1,14 +1,11 @@
 """Unit tests for loro_tpu.resilience: supervisor retry/backoff under a
 fake clock (no wall-clock sleeps in tier-1), the bounded in-flight
-drain budget, cooperative deadlines, the fault-injection harness, and
-the backend-init probe ladder with injectable spawn/clock/sleep."""
-import json
-import os
+drain budget, cooperative deadlines, and the fault-injection
+harness."""
 
 import pytest
 
 from loro_tpu.errors import (
-    BackendUnavailable,
     CodecDecodeError,
     DeadlineExceeded,
     DeviceFailure,
@@ -18,7 +15,6 @@ from loro_tpu.resilience import (
     RetryPolicy,
     default_transient,
     faultinject,
-    probe,
 )
 
 
@@ -86,7 +82,7 @@ class TestRetry:
         sup, clk = make_sup()
 
         def thunk():
-            raise OSError("tunnel dropped mid-upload")
+            raise OSError("link dropped mid-upload")
 
         with pytest.raises(DeviceFailure) as ei:
             sup.launch(thunk)
@@ -183,7 +179,7 @@ class TestDrainBudget:
 
         class Exploding:
             def __array__(self, *a, **kw):
-                raise OSError("tunnel dropped at fetch")
+                raise OSError("link dropped at fetch")
 
         with pytest.raises(DeviceFailure):
             sup.fetch(Exploding())
@@ -282,91 +278,3 @@ class TestFaultInject:
                 faultinject.check("launch")
         finally:
             faultinject.clear()
-
-
-# ---------------------------------------------------------------------------
-# backend-init probe ladder
-# ---------------------------------------------------------------------------
-
-
-class TestProbe:
-    def test_wait_for_backend_staggers_and_succeeds(self, tmp_path):
-        """Injectable ladder: the first two probes 'hang' (never write
-        done), the third reports done — wait_for_backend keeps
-        spawning fresh probes every stagger_s and NEVER signals the
-        stale ones."""
-        status = str(tmp_path / "probe.json")
-        clk = FakeClock()
-        spawned = []
-
-        def spawn(path):
-            spawned.append(path)
-            if len(spawned) == 3:
-                with open(path, "w") as f:
-                    json.dump({"step": "done", "platform": "fake"}, f)
-
-        st = probe.wait_for_backend(
-            1000.0, status_path=status, stagger_s=120.0, poll_s=2.0,
-            clock=clk, sleep=clk.sleep, spawn=spawn,
-        )
-        assert st["ok"] and st["probes"] == 3
-        assert len(spawned) == 3
-        # ~2 staggers of fake time elapsed, no wall time at all
-        assert 240.0 <= st["waited_s"] <= 300.0
-
-    def test_wait_for_backend_timeout(self, tmp_path):
-        status = str(tmp_path / "probe.json")
-        clk = FakeClock()
-        st = probe.wait_for_backend(
-            300.0, status_path=status, stagger_s=120.0, poll_s=5.0,
-            clock=clk, sleep=clk.sleep, spawn=lambda p: None,
-        )
-        assert not st["ok"]
-        assert st["probes"] == 3  # t=0, 120, 240
-        with pytest.raises(BackendUnavailable):
-            probe.wait_for_backend(
-                10.0, status_path=status, stagger_s=120.0, poll_s=5.0,
-                clock=clk, sleep=clk.sleep, spawn=lambda p: None,
-                raise_on_timeout=True,
-            )
-
-    def test_real_probe_subprocess_fake_ok(self, tmp_path, monkeypatch):
-        """One real detached probe subprocess (LORO_PROBE_FAKE=ok skips
-        backend init so this stays fast): status file goes spawned ->
-        done; the parent never signals it."""
-        monkeypatch.setenv("LORO_PROBE_FAKE", "ok")
-        status = str(tmp_path / "probe.json")
-        st = probe.wait_for_backend(
-            30.0, status_path=status, stagger_s=30.0, poll_s=0.05
-        )
-        assert st["ok"] and st.get("platform") == "fake"
-
-    def test_real_probe_subprocess_raise(self, tmp_path, monkeypatch):
-        """A probe whose backend init raises writes step=error and the
-        ladder times out cooperatively (typed outcome, no hang)."""
-        monkeypatch.setenv("LORO_PROBE_FAKE", "raise")
-        status = str(tmp_path / "probe.json")
-        st = probe.wait_for_backend(
-            2.0, status_path=status, stagger_s=60.0, poll_s=0.05
-        )
-        assert not st["ok"]
-        assert st.get("step") in ("error", "spawned", "init")
-
-    def test_read_status_missing_or_garbage(self, tmp_path):
-        assert probe.read_status(str(tmp_path / "nope.json")) is None
-        p = tmp_path / "bad.json"
-        p.write_text("{not json")
-        assert probe.read_status(str(p)) is None
-
-    def test_stale_done_status_is_not_trusted(self, tmp_path):
-        """A leftover step=done from a PREVIOUS session must not pass
-        for a live backend: wait_for_backend unlinks the status file
-        before its first poll."""
-        status = tmp_path / "probe.json"
-        status.write_text(json.dumps({"step": "done", "platform": "yesterday"}))
-        clk = FakeClock()
-        st = probe.wait_for_backend(
-            100.0, status_path=str(status), stagger_s=60.0, poll_s=5.0,
-            clock=clk, sleep=clk.sleep, spawn=lambda p: None,
-        )
-        assert not st["ok"]

@@ -160,7 +160,7 @@ class TestFleetDegradation:
         a, _ = _mk_pair("text", i=14)
         fleet = Fleet()
         n0 = obs.counter("fleet.degraded_merges_total").get(family="text")
-        faultinject.inject("fetch", exc=OSError("tunnel dropped at fetch"),
+        faultinject.inject("fetch", exc=OSError("link dropped at fetch"),
                            times=1)
         try:
             got = fleet.merge_text_changes(

@@ -25,6 +25,7 @@ import numpy as np
 from ..errors import CodecDecodeError, LoroError
 from ..obs import metrics as _obs
 from ..resilience import faultinject as _fi
+from ..utils import tracing as _tracing
 
 _fi.register_site(
     "decode", "native explode entries: truncate/bit-flip the wire bytes "
@@ -267,31 +268,32 @@ def explode_seq_payload(payload: bytes, target_cid_index: int):
     lib = _load()
     if lib is None:
         return None
-    payload = _obs_decode("seq", payload)
-    n = lib.loro_count_seq_elements(payload, len(payload), target_cid_index)
-    if n < 0:
-        raise CodecDecodeError("native decode failed (malformed payload?)")
-    parent = np.empty(n, np.int32)
-    side = np.empty(n, np.int32)
-    peer = np.empty(n, np.int32)
-    counter = np.empty(n, np.int32)
-    deleted = np.zeros(n, np.uint8)
-    content = np.empty(n, np.int32)
-    wrote = lib.loro_explode_seq(
-        payload,
-        len(payload),
-        target_cid_index,
-        parent.ctypes.data_as(ctypes.c_void_p),
-        side.ctypes.data_as(ctypes.c_void_p),
-        peer.ctypes.data_as(ctypes.c_void_p),
-        counter.ctypes.data_as(ctypes.c_void_p),
-        deleted.ctypes.data_as(ctypes.c_void_p),
-        content.ctypes.data_as(ctypes.c_void_p),
-        n,
-    )
-    if wrote != n:
-        raise CodecDecodeError("native decode failed (unresolvable refs or count mismatch)")
-    return parent, side, peer, counter, deleted.astype(bool), content
+    with _tracing.span("native.explode", bytes=len(payload)):
+        payload = _obs_decode("seq", payload)
+        n = lib.loro_count_seq_elements(payload, len(payload), target_cid_index)
+        if n < 0:
+            raise CodecDecodeError("native decode failed (malformed payload?)")
+        parent = np.empty(n, np.int32)
+        side = np.empty(n, np.int32)
+        peer = np.empty(n, np.int32)
+        counter = np.empty(n, np.int32)
+        deleted = np.zeros(n, np.uint8)
+        content = np.empty(n, np.int32)
+        wrote = lib.loro_explode_seq(
+            payload,
+            len(payload),
+            target_cid_index,
+            parent.ctypes.data_as(ctypes.c_void_p),
+            side.ctypes.data_as(ctypes.c_void_p),
+            peer.ctypes.data_as(ctypes.c_void_p),
+            counter.ctypes.data_as(ctypes.c_void_p),
+            deleted.ctypes.data_as(ctypes.c_void_p),
+            content.ctypes.data_as(ctypes.c_void_p),
+            n,
+        )
+        if wrote != n:
+            raise CodecDecodeError("native decode failed (unresolvable refs or count mismatch)")
+        return parent, side, peer, counter, deleted.astype(bool), content
 
 
 def explode_seq_delta_payload(payload: bytes, target_cid_index: int):

@@ -331,14 +331,19 @@ class ChainExtract:
 
 
 def chain_columns(
-    ex: SeqExtract, pad_n: Optional[int] = None, pad_c: Optional[int] = None, bucket: bool = False
+    ex: SeqExtract,
+    pad_n: Optional[int] = None,
+    pad_c: Optional[int] = None,
+    bucket: bool = False,
+    chains: Optional["ChainExtract"] = None,
 ):
     """Padded numpy ChainColumns for the chain-contracted device path.
     With bucket=True, both dims pad to power-of-two buckets (shares the
-    jit cache across varying sizes) without a separate contract pass."""
+    jit cache across varying sizes) without a separate contract pass.
+    ``chains`` is ``contract_chains(ex)`` where the caller has made it."""
     from .fugue_batch import ChainColumns, pad_bucket
 
-    ch = contract_chains(ex)
+    ch = chains if chains is not None else contract_chains(ex)
     if bucket:
         n = pad_n or pad_bucket(max(1, ex.n))
         c = pad_c or pad_bucket(max(1, ch.n_chains))

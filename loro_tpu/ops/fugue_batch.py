@@ -128,6 +128,12 @@ def doc_batch_jit(fn):
 RANK_ALGOS = ("wyllie", "ruling", "blocked", "coalesced")
 
 
+# Device stages carry a ``jax.named_scope`` at their single dispatch
+# point — ring, rank, compact, place, unpack, checksum — so the profile's
+# device ops keep a stage name whatever XLA numbers its fusions
+# (metadata only: no operation, shape or fusion changes).
+
+
 def _rank_algo() -> str:
     """XLA ranking algorithm (RANK_ALGO): "wyllie" (default), "ruling"
     (two-level ruling-set; ~2x fewer gather rows in expectation),
@@ -572,6 +578,7 @@ def _resolve_rank_spec(rank_impl: Optional[str], m: int) -> Tuple[str, str]:
     return "xla", algo
 
 
+@jax.named_scope("rank")
 def _rank_dist(
     succ: jax.Array,
     backend: str,
@@ -593,6 +600,7 @@ def _rank_dist(
     return _wyllie_dist(succ)
 
 
+@jax.named_scope("ring")
 def _ring_and_anchors(
     parent_in: jax.Array,
     side_in: jax.Array,
@@ -753,6 +761,7 @@ def visible_order(cols: SeqColumns) -> Tuple[jax.Array, jax.Array]:
     return perm.astype(jnp.int32), visible.sum().astype(jnp.int32)
 
 
+@jax.named_scope("compact")
 def _compact(rank: jax.Array, visible: jax.Array, content: jax.Array) -> Tuple[jax.Array, jax.Array]:
     """Sort-free compaction shared by both element-table layouts: ranks
     are unique values < rank_bound(N) = 2*(N+1), so a scatter into an
@@ -850,6 +859,7 @@ def _place_algo() -> str:
     return algo
 
 
+@jax.named_scope("place")
 def _place_by_chain(
     crank: jax.Array,
     c_valid: jax.Array,
@@ -1038,6 +1048,7 @@ def chain_merge_docs(cols: ChainColumns) -> Tuple[jax.Array, jax.Array]:
     return _chain_merge_docs_jit(cols)
 
 
+@jax.named_scope("checksum")
 def _weighted_checksum(codes: jax.Array) -> jax.Array:
     """Order-sensitive per-doc checksum of merged codes [D, N] -> [D]."""
     n = codes.shape[1]
@@ -1183,6 +1194,7 @@ def pack_chain_doc_into(cols: ChainColumns, out_row: np.ndarray) -> None:
     assert o == out_row.shape[0]
 
 
+@jax.named_scope("unpack")
 def _unpack_chain_batch(packed: jax.Array, pad_c: int, pad_n: int) -> ChainColumns:
     """In-jit inverse of pack_chain_doc_into ([D, W] u8 -> ChainColumns)."""
     d = packed.shape[0]
@@ -1255,43 +1267,82 @@ def merge_text_payloads_packed(
     import time
     from concurrent.futures import ThreadPoolExecutor
 
-    from .columnar import chain_columns, extract_seq_from_payload
+    from ..obs import metrics as obs
+    from ..utils import tracing
+    from .columnar import chain_columns, contract_chains, extract_seq_from_payload
 
     if n_docs % chunk:
         raise ValueError(f"n_docs={n_docs} is not a multiple of chunk={chunk}")
     row_w = packed_row_bytes(pad_c, pad_n)
+    # one trace id per launch round; a document's decode runs under the
+    # id of the round that will take it
+    call_id = tracing.new_trace_id("k")
 
     def decode_one(i: int):
-        pl, p_ops = payloads[i % len(payloads)]
-        exd = extract_seq_from_payload(pl, cid)
-        row = np.empty(row_w, np.uint8)
-        pack_chain_doc_into(chain_columns(exd, pad_n=pad_n, pad_c=pad_c), row)
-        return row, p_ops
+        with tracing.span("packed.decode_one", trace_id=f"{call_id}.{i // chunk}", doc=i):
+            pl, p_ops = payloads[i % len(payloads)]
+            with tracing.span("packed.extract", bytes=len(pl)):
+                exd = extract_seq_from_payload(pl, cid)
+            row = np.empty(row_w, np.uint8)
+            with tracing.span("packed.contract"):
+                chains = contract_chains(exd)
+            with tracing.span("packed.pack"):  # padding to the row's widths + the u8 row
+                cols = chain_columns(exd, pad_n=pad_n, pad_c=pad_c, chains=chains)
+                pack_chain_doc_into(cols, row)
+            return row, p_ops
 
     n_workers = min(8, os.cpu_count() or 1)
     done = 0
     ops = 0
     outs = []
+    ahead = obs.histogram(
+        "packed.decoded_ahead",
+        "documents of the next launch already decoded when the launcher "
+        "asked (0 = host-bound, chunk = device-bound)",
+        buckets=range(9),
+    )
+    n_docs_c, n_bytes_c, n_put_c, n_launch_c = (
+        obs.counter(f"packed.{n}_total")
+        for n in ("docs_decoded", "payload_bytes", "row_bytes_put", "launches")
+    )
     pool = ThreadPoolExecutor(max_workers=n_workers)
     try:
         t0 = time.perf_counter()
         futs = [pool.submit(decode_one, i) for i in range(min(3 * chunk, n_docs))]
         next_submit = len(futs)
         while done < n_docs and (time.perf_counter() - t0) < budget_s:
-            group = futs[done : done + chunk]
-            docs = []
-            for j, f in enumerate(group):
-                c, p_ops = f.result()
-                docs.append(c)
-                ops += p_ops
-                futs[done + j] = None  # release decoded columns
-            while next_submit < n_docs and next_submit < done + 3 * chunk:
-                futs.append(pool.submit(decode_one, next_submit))
-                next_submit += 1
-            dev = jax.device_put(np.stack(docs))  # one put per chunk
-            outs.append(chain_merge_docs_packed_checksum(dev, pad_c, pad_n))  # async
-            done += chunk
-        jax.block_until_ready(outs)
+            with tracing.span(
+                "packed.round", trace_id=f"{call_id}.{done // chunk}", docs=chunk
+            ):
+                group = futs[done : done + chunk]
+                ahead.observe(sum(f.done() for f in group))
+                docs = []
+                with tracing.span("packed.wait_decoded"):
+                    for j, f in enumerate(group):
+                        c, p_ops = f.result()
+                        docs.append(c)
+                        ops += p_ops
+                        futs[done + j] = None  # release decoded columns
+                with tracing.span("packed.submit"):
+                    while next_submit < n_docs and next_submit < done + 3 * chunk:
+                        futs.append(pool.submit(decode_one, next_submit))
+                        next_submit += 1
+                with tracing.span("packed.stack"):
+                    stacked = np.stack(docs)
+                with tracing.span("packed.put"):
+                    dev = jax.device_put(stacked)  # one put per chunk
+                del stacked  # jax holds it while it must: the next stack reuses the block
+                with tracing.span("packed.dispatch"):
+                    outs.append(chain_merge_docs_packed_checksum(dev, pad_c, pad_n))  # async
+                n_docs_c.inc(chunk)
+                n_bytes_c.inc(
+                    sum(len(payloads[i % len(payloads)][0]) for i in range(done, done + chunk))
+                )
+                n_put_c.inc(chunk * row_w)
+                n_launch_c.inc()
+                done += chunk
+        with tracing.span("packed.drain"):
+            jax.block_until_ready(outs)
         dt = time.perf_counter() - t0
     finally:
         pool.shutdown(wait=False, cancel_futures=True)

@@ -185,39 +185,51 @@ class Fleet:
         doc axis is padded to a multiple of the mesh's doc dimension."""
         if self._text_fn is None:
             self._text_fn = self._build_text_fn()
-        tracing.instant("fleet.merge_text_docs", docs=len(extracts))
-        n = pad_bucket(max(e.n for e in extracts))
-        d = len(extracts)
-        d_pad = pad_docs or _mesh_pad(self.mesh, d)
-        _obs_merge("text", d, sum(e.n for e in extracts), n * d_pad, (n, d_pad))
-        cols_np = [e.to_seq_columns(pad_to=n) for e in extracts]
-        empty = SeqColumns(
-            parent=np.full(n, -1, np.int32),
-            side=np.zeros(n, np.int32),
-            peer=np.zeros(n, np.int32),
-            counter=np.zeros(n, np.int32),
-            deleted=np.ones(n, bool),
-            content=np.full(n, -1, np.int32),
-            valid=np.zeros(n, bool),
-        )
-        cols_np += [empty] * (d_pad - d)
-        batched = SeqColumns(
-            *[np.stack([getattr(c, f) for c in cols_np]) for f in SeqColumns._fields]
-        )
-        sh = doc_sharding(self.mesh)
-        # the upload is supervised too: a device that is gone raises
-        # synchronously at device_put, and that must be a typed
-        # DeviceFailure for the degradation handlers, not a raw crash
-        batched = _sup_launch(
-            "fleet.text",
-            lambda: SeqColumns(*[jax.device_put(a, sh) for a in batched]),
-        )
-        codes, counts = _sup_launch("fleet.text", lambda: self._text_fn(batched))
-        codes = _sup_fetch("fleet.text", codes)
-        counts = _sup_fetch("fleet.text", counts)
-        texts = [
-            "".join(map(chr, codes[i, : counts[i]])) for i in range(d)
-        ]
+        with tracing.span("fleet.merge_text_docs", docs=len(extracts)):
+            n = pad_bucket(max(e.n for e in extracts))
+            d = len(extracts)
+            d_pad = pad_docs or _mesh_pad(self.mesh, d)
+            _obs_merge("text", d, sum(e.n for e in extracts), n * d_pad, (n, d_pad))
+            with tracing.span("fleet.stack"):
+                cols_np = [e.to_seq_columns(pad_to=n) for e in extracts]
+                empty = SeqColumns(
+                    parent=np.full(n, -1, np.int32),
+                    side=np.zeros(n, np.int32),
+                    peer=np.zeros(n, np.int32),
+                    counter=np.zeros(n, np.int32),
+                    deleted=np.ones(n, bool),
+                    content=np.full(n, -1, np.int32),
+                    valid=np.zeros(n, bool),
+                )
+                cols_np += [empty] * (d_pad - d)
+                batched = SeqColumns(
+                    *[np.stack([getattr(c, f) for c in cols_np]) for f in SeqColumns._fields]
+                )
+            sh = doc_sharding(self.mesh)
+            # the upload is supervised too: a device that is gone raises
+            # synchronously at device_put, and that must be a typed
+            # DeviceFailure for the degradation handlers, not a raw crash
+            with tracing.span("fleet.upload"):
+                batched = _sup_launch(
+                    "fleet.text",
+                    lambda: SeqColumns(*[jax.device_put(a, sh) for a in batched]),
+                )
+            with tracing.span("fleet.launch"):
+                codes, counts = _sup_launch("fleet.text", lambda: self._text_fn(batched))
+            # the wait is the device's time, the fetch the host's copy:
+            # kept apart, under the same guard (a device that fails
+            # mid-merge surfaces at the first sync point)
+            with tracing.span("fleet.device_wait"):
+                get_supervisor().guard(
+                    lambda: jax.block_until_ready((codes, counts)), label="fleet.text"
+                )
+            with tracing.span("fleet.fetch"):
+                codes = _sup_fetch("fleet.text", codes)
+                counts = _sup_fetch("fleet.text", counts)
+            with tracing.span("fleet.join"):
+                texts = [
+                    "".join(map(chr, codes[i, : counts[i]])) for i in range(d)
+                ]
         return TextMergeResult(texts)
 
     def merge_text_changes(
@@ -247,31 +259,38 @@ class Fleet:
         from ..codec.binary import decode_changes
         from ..ops.columnar import extract_seq_from_payload
 
-        extracts = []
-        for p in payloads:
+        # one trace id per call (a request's own, when it made the call)
+        with tracing.span(
+            "fleet.merge_text_payloads",
+            trace_id=tracing.current() or tracing.new_trace_id("f"),
+            docs=len(payloads),
+        ):
+            extracts = []
+            for p in payloads:
+                with tracing.span("fleet.decode", bytes=len(p)):
+                    try:
+                        ex = extract_seq_from_payload(p, cid)
+                    except ValueError:
+                        # native path can't resolve (e.g. incremental payload
+                        # referencing elements outside it): python fallback
+                        ex = None
+                    if ex is None:
+                        _obs_fallback("payload_extract")
+                        try:
+                            ex = extract_seq_container(decode_changes(p), cid)
+                        except KeyError as e:
+                            raise ValueError(
+                                "payload is not self-contained (references elements "
+                                f"outside it: {e}); one-shot fleet merges need full-"
+                                "history payloads — use DeviceDocBatch for deltas"
+                            ) from e
+                extracts.append(ex)
             try:
-                ex = extract_seq_from_payload(p, cid)
-            except ValueError:
-                # native path can't resolve (e.g. incremental payload
-                # referencing elements outside it): python fallback
-                ex = None
-            if ex is None:
-                _obs_fallback("payload_extract")
-                try:
-                    ex = extract_seq_container(decode_changes(p), cid)
-                except KeyError as e:
-                    raise ValueError(
-                        "payload is not self-contained (references elements "
-                        f"outside it: {e}); one-shot fleet merges need full-"
-                        "history payloads — use DeviceDocBatch for deltas"
-                    ) from e
-            extracts.append(ex)
-        try:
-            return self.merge_text_docs(extracts)
-        except DeviceFailure:
-            return TextMergeResult(
-                _host_degrade("text", [decode_changes(p) for p in payloads], cid)
-            )
+                return self.merge_text_docs(extracts)
+            except DeviceFailure:
+                return TextMergeResult(
+                    _host_degrade("text", [decode_changes(p) for p in payloads], cid)
+                )
 
     # ------------------------------------------------------------------
     # rich text merge

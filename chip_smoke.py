@@ -8,7 +8,10 @@ against the plain host engine (``LoroDoc``):
 - ``import`` — the north-star bulk import on documents of 259,778
   single-character patches (the length and shape of B4's automerge
   trace, from the seeded generator): (a) ``public``, the library's
-  ``Fleet.merge_text_payloads`` on 16 documents in its one launch;
+  ``Fleet.merge_text_payloads`` on 16 documents in its one launch of
+  the chain-contracted merge (packed u8 rows, or plain ``ChainColumns``
+  where the chain bucket outgrows 16-bit chain ids, as the seeded
+  trace's ~51,000 chains do; the rank chosen from the chain ring);
   (b) ``flagship``, the decode -> contract -> pack -> merge pipeline of
   bench.py's e2e phase (``merge_text_payloads_packed``) on 64 documents
   in 8-document launches, where the Pallas rank must be the one that
@@ -332,6 +335,22 @@ def phase_sync(dev) -> dict:
     return rec
 
 
+def public_entry_plan(variants) -> dict:
+    """What ``Fleet.merge_text_payloads`` is to do with these documents,
+    from their own sizes: the chain and element buckets, how the batch
+    travels, the ring the rank walks and the rank that ring resolves to."""
+    from loro_tpu.ops.columnar import contract_chains
+    from loro_tpu.ops.fugue_batch import _resolve_rank_spec, rank_bound
+    from loro_tpu.parallel.fleet import text_pads, text_transport
+
+    n_chains = max(contract_chains(v["extract"]).n_chains for v in variants)
+    pad_c, pad_n = text_pads(n_chains, max(v["extract"].n for v in variants))
+    ring = rank_bound(pad_c)
+    return {"chains": n_chains, "pad_c": pad_c, "pad_n": pad_n,
+            "transport": text_transport(pad_c, pad_n), "ring_tokens": ring,
+            "rank_spec": ":".join(_resolve_rank_spec(None, ring))}
+
+
 def phase_import(variants, mesh, public_docs: int, flagship_docs: int,
                  chunk: int, events: CompileEvents,
                  pipeline_runs: int = 3) -> list:
@@ -345,7 +364,6 @@ def phase_import(variants, mesh, public_docs: int, flagship_docs: int,
         chain_merge_docs_packed_checksum,
         merge_text_payloads_packed,
         packed_row_bytes,
-        pad_bucket,
     )
     from loro_tpu.parallel.fleet import Fleet
 
@@ -356,7 +374,10 @@ def phase_import(variants, mesh, public_docs: int, flagship_docs: int,
     # -- (a) the public entry ------------------------------------------
     payloads = [variants[i % len(variants)]["payload"] for i in range(public_docs)]
     fleet = Fleet(mesh)
-    n0 = launches.total()
+    plan = public_entry_plan(variants)
+    ring, spec = plan["ring_tokens"], plan["rank_spec"]
+    ranked = obs.counter("rank.ring_tokens")
+    n0, r0 = launches.total(), ranked.get(algo=spec)
     t0 = time.perf_counter()
     texts = fleet.merge_text_payloads(payloads, IMPORT_CID).texts
     first_s = time.perf_counter() - t0
@@ -369,13 +390,15 @@ def phase_import(variants, mesh, public_docs: int, flagship_docs: int,
     for i, text in enumerate(texts):
         require(text == variants[i % len(variants)]["text"],
                 f"import.public: document {i} differs from the host replay")
-    n = pad_bucket(max(v["extract"].n for v in variants))
-    ring = 2 * (n + 1)
+    # ... and what it says it did: both calls ranked the chain ring
+    require(ranked.get(algo=spec) - r0 == 2 * public_docs * ring,
+            f"import.public: rank.ring_tokens{{algo={spec}}} moved by "
+            f"{ranked.get(algo=spec) - r0}, not by two calls of "
+            f"{public_docs} rings of {ring} tokens")
     public = {
         "phase": "import.public", "entry": "Fleet.merge_text_payloads",
         "docs": public_docs, "distinct": len(variants),
-        "padded_shape": [public_docs, n], "ring_tokens": ring,
-        "rank_spec": ":".join(_resolve_rank_spec(None, ring)),
+        "padded_shape": [public_docs, plan.pop("pad_n")], **plan,
         "first_call_s": first_s, "second_call_s": second_s,
         "compile_s": first_s - second_s,
         "launches": int(launches.total() - n0) // 2,
@@ -659,9 +682,9 @@ def phase_chips4_mesh(fleet_variants, batch_variants) -> dict:
     # Fleet.merge_text_payloads
     payloads = [fleet_variants[i % len(fleet_variants)]["payload"]
                 for i in range(2 * len(devs))]
-    n = pad_bucket(max(v["extract"].n for v in fleet_variants))
-    spec = ":".join(_resolve_rank_spec(None, 2 * (n + 1)))
-    require(spec.startswith("pallas:"), f"chips4.mesh: Fleet rank is {spec}")
+    plan = public_entry_plan(fleet_variants)
+    require(plan["rank_spec"].startswith("pallas:"),
+            f"chips4.mesh: Fleet rank is {plan['rank_spec']}")
     t0 = time.perf_counter()
     many = Fleet(mesh_n).merge_text_payloads(payloads, IMPORT_CID).texts
     rec["fleet_mesh_s"] = time.perf_counter() - t0
@@ -670,8 +693,7 @@ def phase_chips4_mesh(fleet_variants, batch_variants) -> dict:
     require(all(t == fleet_variants[i % len(fleet_variants)]["text"]
                 for i, t in enumerate(one)),
             "Fleet differs from the host replay")
-    rec["fleet"] = {"docs": len(payloads), "padded_rows": n, "rank_spec": spec,
-                    "equal_one_device": True}
+    rec["fleet"] = {"docs": len(payloads), **plan, "equal_one_device": True}
     # DeviceDocBatch._materialize(use_solver=True)
     payloads = [batch_variants[i % len(batch_variants)]["payload"]
                 for i in range(2 * len(devs))]

@@ -38,12 +38,21 @@ register_site(
     "export_select thunk, inside the supervisor): transient retries, "
     "terminal -> DeviceFailure degrades ONLY that window")
 from ..utils import tracing
-from ..ops.columnar import MapExtract, SeqExtract, extract_seq_container
+from ..ops.columnar import (
+    MapExtract,
+    SeqExtract,
+    chain_columns,
+    contract_chains,
+    extract_seq_container,
+)
 from ..ops.fugue_batch import (
-    SeqColumns,
-    materialize_content_batch,
+    ChainColumns,
+    _chain_merge_docs_jit,
+    _tick_rank_obs,
+    chain_merge_docs_packed,
+    pack_chain_doc_into,
+    packed_row_bytes,
     pad_bucket,
-    shard_docs,
 )
 from ..ops.lww import MapOpCols, lww_merge_doc
 from .mesh import DOC_AXIS, OP_AXIS, doc_sharding, make_mesh, replicated
@@ -152,70 +161,99 @@ def _empty_seq_np(n: int):
     )
 
 
+def text_pads(n_chains: int, n_elements: int) -> Tuple[int, int]:
+    """``(pad_c, pad_n)`` of a text batch whose largest document has
+    ``n_chains`` chains and ``n_elements`` elements.  Elements pad to a
+    power of two; chains pad so that the RING the rank walks,
+    2 * (pad_c + 1) tokens, is a power of two — the rank's cost follows
+    the ring, and a B4-sized document (about 17,500 chains) then ranks
+    65,536 tokens, the last ring the packed Pallas kernels hold (one
+    more chain slot would be 65,538 and the dual-table kernel)."""
+    return pad_bucket(n_chains + 1) - 1, pad_bucket(n_elements)
+
+
+def text_transport(pad_c: int, pad_n: int) -> str:
+    """How a chain-contracted text batch of these padded sizes reaches
+    the device: ``"packed"``, one u8 row a document
+    (``pack_chain_doc_into``: 8 * (pad_c + pad_n) bytes, one put), when
+    its 16-bit chain ids hold ``pad_c`` — 0xFFFF is the root's parent
+    there; else ``"chains"``, plain ``ChainColumns``.  Rows and contents
+    are 32-bit in both, so ``pad_n`` bounds neither.  Either way the
+    rank is chosen from the ring size (``_resolve_rank_spec``)."""
+    return "packed" if pad_c < 0xFFFF else "chains"
+
+
+def _empty_text_batch(transport: str, d_pad: int, pad_c: int, pad_n: int):
+    """The host buffer of a text batch, every document all-invalid (no
+    valid chain, no valid element: zeros say that in both layouts):
+    ``[d_pad, packed_row_bytes]`` u8 rows, or ``ChainColumns`` of
+    ``[d_pad, pad_c]`` / ``[d_pad, pad_n]`` arrays."""
+    if transport == "packed":
+        return np.zeros((d_pad, packed_row_bytes(pad_c, pad_n)), np.uint8)
+    c, n = (d_pad, pad_c), (d_pad, pad_n)
+    return ChainColumns(
+        c_parent=np.zeros(c, np.int32), c_side=np.zeros(c, np.int32),
+        c_valid=np.zeros(c, bool), head_row=np.zeros(c, np.int32),
+        chain_id=np.zeros(n, np.int32), deleted=np.zeros(n, bool),
+        content=np.zeros(n, np.int32), valid=np.zeros(n, bool),
+    )
+
+
 class Fleet:
     """Batched merge front-end bound to a device mesh."""
 
     def __init__(self, mesh=None):
         self.mesh = mesh if mesh is not None else make_mesh()
-        self._text_fn = None
 
     # ------------------------------------------------------------------
     # text / list sequence merge
     # ------------------------------------------------------------------
-    def _build_text_fn(self):
-        mesh = self.mesh
-        in_sh = NamedSharding(mesh, P(DOC_AXIS))
-        out_sh = NamedSharding(mesh, P(DOC_AXIS))
-
-        @functools.partial(
-            jax.jit,
-            in_shardings=(SeqColumns(*([in_sh] * 7)),),
-            out_shardings=(out_sh, out_sh),
-        )
-        def run(cols: SeqColumns):
-            return shard_docs(materialize_content_batch, mesh)(cols)
-
-        return run
-
     def merge_text_docs(
         self, extracts: Sequence[SeqExtract], pad_docs: Optional[int] = None
     ) -> TextMergeResult:
-        """Resolve final text for a batch of documents (one launch).
-        Documents are padded to a common bucketed element count and the
-        doc axis is padded to a multiple of the mesh's doc dimension."""
-        if self._text_fn is None:
-            self._text_fn = self._build_text_fn()
+        """Resolve final text for a batch of documents (one launch of
+        the chain-contracted merge: the device ranks each document's
+        chains, not its elements).  Documents are padded to common
+        bucketed chain and element counts (``text_pads``) and the doc
+        axis to a multiple of the mesh's doc dimension; doc-axis padding
+        rows are all-invalid documents."""
         with tracing.span("fleet.merge_text_docs", docs=len(extracts)):
-            n = pad_bucket(max(e.n for e in extracts))
+            with tracing.span("fleet.contract"):
+                chains = [contract_chains(e) for e in extracts]
+            pad_c, pad_n = text_pads(
+                max(c.n_chains for c in chains), max(e.n for e in extracts)
+            )
+            transport = text_transport(pad_c, pad_n)
             d = len(extracts)
             d_pad = pad_docs or _mesh_pad(self.mesh, d)
-            _obs_merge("text", d, sum(e.n for e in extracts), n * d_pad, (n, d_pad))
+            _obs_merge(
+                "text", d, sum(e.n for e in extracts), pad_n * d_pad,
+                (pad_n, pad_c, d_pad),
+            )
+            obs.counter("fleet.text_docs_total").inc(d, transport=transport)
+            _tick_rank_obs(d_pad, pad_c, None)
             with tracing.span("fleet.stack"):
-                cols_np = [e.to_seq_columns(pad_to=n) for e in extracts]
-                empty = SeqColumns(
-                    parent=np.full(n, -1, np.int32),
-                    side=np.zeros(n, np.int32),
-                    peer=np.zeros(n, np.int32),
-                    counter=np.zeros(n, np.int32),
-                    deleted=np.ones(n, bool),
-                    content=np.full(n, -1, np.int32),
-                    valid=np.zeros(n, bool),
-                )
-                cols_np += [empty] * (d_pad - d)
-                batched = SeqColumns(
-                    *[np.stack([getattr(c, f) for c in cols_np]) for f in SeqColumns._fields]
-                )
+                rows = _empty_text_batch(transport, d_pad, pad_c, pad_n)
+            with tracing.span("fleet.pack"):
+                for i, (e, ch) in enumerate(zip(extracts, chains)):
+                    cols = chain_columns(e, pad_n=pad_n, pad_c=pad_c, chains=ch)
+                    if transport == "packed":
+                        pack_chain_doc_into(cols, rows[i])
+                    else:
+                        for column, of_doc in zip(rows, cols):
+                            column[i] = of_doc
             sh = doc_sharding(self.mesh)
             # the upload is supervised too: a device that is gone raises
             # synchronously at device_put, and that must be a typed
             # DeviceFailure for the degradation handlers, not a raw crash
             with tracing.span("fleet.upload"):
-                batched = _sup_launch(
-                    "fleet.text",
-                    lambda: SeqColumns(*[jax.device_put(a, sh) for a in batched]),
-                )
+                batched = _sup_launch("fleet.text", lambda: jax.device_put(rows, sh))
             with tracing.span("fleet.launch"):
-                codes, counts = _sup_launch("fleet.text", lambda: self._text_fn(batched))
+                codes, counts = _sup_launch(
+                    "fleet.text",
+                    lambda: chain_merge_docs_packed(batched, pad_c, pad_n)
+                    if transport == "packed" else _chain_merge_docs_jit(batched),
+                )
             # the wait is the device's time, the fetch the host's copy:
             # kept apart, under the same guard (a device that fails
             # mid-merge surfaces at the first sync point)

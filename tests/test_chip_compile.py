@@ -194,18 +194,21 @@ def test_flagship_rank_half_compiles_with_the_kernel(one_chip, tpu_branches):
 
 
 def test_fleet_step_on_four_devices_keeps_the_kernel(mesh4, tpu_branches):
-    """``Fleet._build_text_fn`` on a doc-axis mesh of four chips, at a
-    width where the Pallas rank applies: the vmapped batch runs under
-    shard_map, so every device ranks its own documents with the kernel
-    and nothing is gathered across chips."""
-    from loro_tpu.parallel.fleet import Fleet
+    """The step ``Fleet.merge_text_docs`` launches on a doc-axis mesh of
+    four chips (``chain_merge_docs_packed`` on rows put with
+    ``doc_sharding``): the vmapped batch runs under shard_map, so every
+    device ranks its own documents with the kernel and nothing is
+    gathered across chips."""
+    from loro_tpu.parallel.fleet import text_pads, text_transport
 
-    n = 8192
-    assert fb._resolve_rank_spec(None, 2 * (n + 1))[0] == "pallas"
-    sh = NamedSharding(mesh4, P(DOC_AXIS))
+    pad_c, pad_n = text_pads(900, 2048)
+    assert text_transport(pad_c, pad_n) == "packed"
+    assert fb._resolve_rank_spec(None, fb.rank_bound(pad_c))[0] == "pallas"
+    rows = sds((8, fb.packed_row_bytes(pad_c, pad_n)), jnp.uint8,
+               NamedSharding(mesh4, P(DOC_AXIS)))
     text = compile_checked(
-        f"Fleet.text_fn:mesh4:[8,{n}]",
-        Fleet(mesh4)._build_text_fn().lower(seq_sds(8, n, sh)), True)
+        f"Fleet.text_step:mesh4:[8,{pad_n}]:c{pad_c}",
+        fb.chain_merge_docs_packed.lower(rows, pad_c, pad_n), True)
     assert "all-gather" not in text and "all-reduce" not in text
 
 
@@ -235,20 +238,33 @@ def test_flagship_step_compiles_at_real_width(one_chip, tpu_branches):
 
 
 @pytest.mark.slow
-def test_public_step_compiles_at_real_width(topo, tpu_branches):
-    """``import`` (a): the step ``Fleet.merge_text_payloads`` launches,
-    [16, 262144] — the ring is 524,290 tokens, past PALLAS_RANK_MAX_M,
-    so it is the XLA rank (no kernel) even on the chip."""
-    from loro_tpu.parallel.fleet import Fleet
-    from loro_tpu.parallel.mesh import make_mesh
+@pytest.mark.parametrize("chains,elements,pads,transport", [
+    # 16 B4-sized documents (the benchmark's): ring 65,536, packed rows
+    (17_500, 182_315, (32_767, 262_144), "packed"),
+    # the seeded trace of chip_smoke.py / bench.py: ~51,000 chains, a
+    # chain bucket past 16-bit ids, ring 131,072 = PALLAS_RANK_MAX_M
+    (51_000, 233_894, (65_535, 262_144), "chains"),
+])
+def test_public_step_compiles_at_real_width(topo, tpu_branches, chains, elements,
+                                            pads, transport):
+    """``import`` (a): the step ``Fleet.merge_text_payloads`` launches
+    for 16 documents, on arrays put with the mesh's doc sharding —
+    ``chain_merge_docs_packed`` on u8 rows, or the ``ChainColumns`` step
+    where the chain bucket outgrows them.  The Pallas rank either way:
+    the kernel IS in the program."""
+    from loro_tpu.parallel.fleet import text_pads, text_transport
+    from loro_tpu.parallel.mesh import doc_sharding, make_mesh
 
-    mesh = make_mesh([topo.devices[0]])
-    n = 262_144
-    assert fb._resolve_rank_spec(None, 2 * (n + 1)) == ("xla", "wyllie")
-    compile_checked(
-        f"Fleet.text_fn:[16,{n}]",
-        Fleet(mesh)._build_text_fn().lower(
-            seq_sds(16, n, NamedSharding(mesh, P(DOC_AXIS)))), False)
+    sh = doc_sharding(make_mesh([topo.devices[0]]))
+    pad_c, pad_n = text_pads(chains, elements)
+    assert (pad_c, pad_n) == pads and text_transport(pad_c, pad_n) == transport
+    assert fb._resolve_rank_spec(None, fb.rank_bound(pad_c)) == ("pallas", "ruling")
+    if transport == "packed":
+        lowered = fb.chain_merge_docs_packed.lower(
+            sds((16, fb.packed_row_bytes(pad_c, pad_n)), jnp.uint8, sh), pad_c, pad_n)
+    else:
+        lowered = fb._chain_merge_docs_jit.lower(chain_sds(16, pad_c, pad_n, sh))
+    compile_checked(f"Fleet.text_step:{transport}:[16,{pad_n}]:c{pad_c}", lowered, True)
 
 
 @pytest.mark.slow

@@ -90,7 +90,9 @@ def test_import_phase(variants, one_device_mesh, events, monkeypatch):
     public, flagship = chip_smoke.phase_import(
         variants, one_device_mesh, 4, 8, 4, events, pipeline_runs=2)
     assert public["padded_shape"] == [4, 2048] and public["launches"] == 1
-    assert public["ring_tokens"] == 2 * (2048 + 1)
+    # the chain bucket, not the element bucket, sets the ring
+    assert public["chains"] <= public["pad_c"] == 255 and public["ring_tokens"] == 512
+    assert public["transport"] == "packed" and public["rank_spec"] == "pallas:ruling"
     assert flagship["rank_spec"] == "pallas:ruling"
     assert flagship["docs"] == 8 and flagship["launches"] == 2
     assert flagship["tpu_custom_call"] is False  # interpret mode: no kernel text

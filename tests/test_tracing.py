@@ -315,7 +315,8 @@ FLEET_TREE = {
     "native.explode": {"fleet.decode"},
     "fleet.merge_text_docs": {"fleet.merge_text_payloads"},
     **{f"fleet.{s}": {"fleet.merge_text_docs"}
-       for s in ("stack", "upload", "launch", "device_wait", "fetch", "join")},
+       for s in ("contract", "stack", "pack", "upload", "launch", "device_wait",
+                 "fetch", "join")},
 }
 PACKED_TREE = {
     "packed.decode_one": {None},
@@ -395,16 +396,18 @@ def test_import_paths_give_their_span_trees_and_the_same_answers():
 
 
 def test_device_stage_scopes_are_in_the_lowered_programs():
-    """``jax.named_scope`` at the single dispatch points: the packed step
-    has unpack, ring, rank, place, checksum; Fleet's text function ring,
-    rank, compact — six names between them."""
+    """``jax.named_scope`` at the single dispatch points: the packed
+    stream's step has unpack, ring, rank, place, checksum; the step
+    ``Fleet``'s text entry launches unpack, ring, rank, place; the
+    uncontracted reference ring, rank, compact — six names between them."""
     from loro_tpu.ops.fugue_batch import (
         SeqColumns,
+        _merge_docs_jit,
+        chain_merge_docs_packed,
         chain_merge_docs_packed_checksum,
         packed_row_bytes,
     )
-    from loro_tpu.parallel.fleet import Fleet
-    from loro_tpu.parallel.mesh import make_mesh
+    from loro_tpu.parallel.fleet import text_pads
 
     def scopes(lowered):
         text = lowered.as_text(debug_info=True)
@@ -414,8 +417,11 @@ def test_device_stage_scopes_are_in_the_lowered_programs():
     packed = chain_merge_docs_packed_checksum.lower(
         jax.ShapeDtypeStruct((2, packed_row_bytes(128, 512)), np.uint8), 128, 512)
     assert scopes(packed) == {"unpack", "ring", "rank", "place", "checksum"}
-    fleet = Fleet(make_mesh(jax.devices()[:1]))
+    pad_c, pad_n = text_pads(40, 500)
+    fleet = chain_merge_docs_packed.lower(
+        jax.ShapeDtypeStruct((1, packed_row_bytes(pad_c, pad_n)), np.uint8), pad_c, pad_n)
+    assert scopes(fleet) == {"unpack", "ring", "rank", "place"}
     shape = lambda dt: jax.ShapeDtypeStruct((1, 64), dt)  # noqa: E731
     cols = SeqColumns(*[shape(bool if f in ("deleted", "valid") else np.int32)
                         for f in SeqColumns._fields])
-    assert scopes(fleet._build_text_fn().lower(cols)) == {"ring", "rank", "compact"}
+    assert scopes(_merge_docs_jit.lower(cols)) == {"ring", "rank", "compact"}

@@ -5,8 +5,10 @@ window, on the host's clock, read inside the program).
 
 ``params["spans"]`` names the spans summed, ``params["per"]`` the span
 whose count divides the sum (one per call, per launch round or per
-document).  Nothing to read — a program without these spans, no ``per``
-span, or a span that fell off the record's ring — leaves the metric out."""
+document).  Nothing to read — a program without the record, no ``per``
+span, none of the named spans (a parent commit from before a stage had
+its span: 0.0 would say the stage costs nothing), or a span that fell off
+the record's ring — leaves the metric out."""
 
 
 def spans_of_window() -> list | None:
@@ -43,7 +45,8 @@ def read(params: dict, run) -> float | None:
     spans = spans_of_window()
     if not spans:
         return None
+    names = set(params["spans"])
     per = sum(1 for e in spans if e["name"] == params["per"])
-    if not per:
+    if not per or not any(e["name"] in names for e in spans):
         return None
-    return self_ns(spans, set(params["spans"])) / per / 1e6
+    return self_ns(spans, names) / per / 1e6

@@ -10,6 +10,9 @@ from __future__ import annotations
 import importlib
 import json
 import os
+import shutil
+import subprocess
+import sys
 
 import pytest
 
@@ -20,6 +23,22 @@ import trace_reduce
 HERE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 MANIFEST = bench.load_json(bench.ROOT, "BENCHMARK.json")
 CELLS = [w["name"] for w in MANIFEST["workloads"]]
+
+
+def driver_of(cell: dict) -> str:
+    return bench.load_json(HERE, "traffic", cell["traffic"] + ".json")["driver"]
+
+
+def faults_of(driver: str):
+    """``faults/<driver>.py`` (``FAULTS``, ``plant(monkeypatch, fault)``):
+    the faults a cell of that driver can have and how to plant one under
+    its timed path; None for a driver that has no such file yet."""
+    try:
+        return importlib.import_module(f"faults.{driver}")
+    except ModuleNotFoundError as e:
+        if e.name not in ("faults", f"faults.{driver}"):
+            raise
+        return None
 
 
 @pytest.fixture(autouse=True)
@@ -48,10 +67,14 @@ def test_manifest_names_only_what_exists():
         body = bench.load_json(bench.ROOT, c["file"])
         assert set(c["reduced"]) == set(body["reduced"])
         assert body["guarantees"]
+    rates = [m for m in MANIFEST["end_to_end"] if m["name"] != "setup_s"]
     for w in MANIFEST["workloads"]:
         assert w["config"] in configs and w["chips"] in (1, 4)
-        traffic = bench.load_json(HERE, "traffic", w["traffic"] + ".json")
-        importlib.import_module(f"drivers.{traffic['driver']}")
+        driver = driver_of(w)
+        importlib.import_module(f"drivers.{driver}")
+        assert faults_of(driver), f"no benchmarks/tests/faults/{driver}.py"
+        # setup_s alone says nothing of the window: every cell has a rate
+        assert any(w["name"] in m.get("workloads", CELLS) for m in rates)
     for m in MANIFEST["per_layer"]:
         spec = bench.load_json(HERE, "layer_metrics", m["name"] + ".json")
         assert spec["layer"] == m["layer"] and spec["moves"] == m["moves"]
@@ -84,69 +107,18 @@ def test_control_comes_out_not_correct(capsys, cell):
     assert rc == 1 and res["correct"] is False
 
 
-def _fleet_fault(monkeypatch, fault: str):
-    from loro_tpu.obs import metrics as obs
-    from loro_tpu.parallel.fleet import Fleet
-
-    real = Fleet.merge_text_payloads
-    calls = {"n": 0}
-
-    def broken(self, payloads, cid):
-        calls["n"] += 1
-        if fault == "half_of_the_batch_left_out":
-            return real(self, payloads[: len(payloads) // 2], cid)
-        out = real(self, payloads, cid)
-        if calls["n"] < 2:  # the warm-up call stays sound
-            return out
-        if fault == "answer_altered":
-            out.texts[-1] = out.texts[-1][:-1] + "☃"
-        elif fault == "fallback_counter_moved":
-            obs.counter("fleet.host_fallback_total").inc(where="test")
-        return out
-
-    monkeypatch.setattr(Fleet, "merge_text_payloads", broken)
-
-
-def _packed_fault(monkeypatch, fault: str):
-    import numpy as np
-
-    from loro_tpu.obs import metrics as obs
-    from loro_tpu.ops import fugue_batch
-
-    real = fugue_batch.merge_text_payloads_packed
-
-    def broken(pairs, cid, pad_c, pad_n, chunk, n_docs, budget_s=float("inf")):
-        outs, done, ops, dt, nw = real(pairs, cid, pad_c, pad_n, chunk, n_docs,
-                                       budget_s)
-        if fault == "answer_altered":
-            sums, counts = outs[-1]
-            outs[-1] = (np.asarray(sums) + np.uint32(1), counts)
-        elif fault == "half_of_the_batch_left_out":
-            outs = outs[: max(1, len(outs) // 2)]  # launches counted, not made
-        elif fault == "fallback_counter_moved":
-            obs.counter("fleet.host_fallback_total").inc(where="test")
-        return outs, done, ops, dt, nw
-
-    monkeypatch.setattr(fugue_batch, "merge_text_payloads_packed", broken)
-
-
-# driver -> (how to plant a fault under the timed path, the faults that
-# cell can have).  One chip and no carried state: no exchange between
-# chips, no step that returns its state unchanged.
-FAULTS = {
-    "import_packed": (_packed_fault, ["answer_altered",
-                                      "half_of_the_batch_left_out",
-                                      "fallback_counter_moved"]),
-    "import_fleet": (_fleet_fault, ["answer_altered",
-                                    "half_of_the_batch_left_out",
-                                    "fallback_counter_moved"]),
-}
-
-
 def _fault_cases():
+    """Every cell under every fault its driver's ``faults`` module names;
+    a driver without one gives ONE case, which fails and names the file."""
+    missing = set()
     for w in MANIFEST["workloads"]:
-        driver = bench.load_json(HERE, "traffic", w["traffic"] + ".json")["driver"]
-        for fault in FAULTS[driver][1]:
+        driver = driver_of(w)
+        module = faults_of(driver)
+        if module is None and driver not in missing:
+            missing.add(driver)
+            yield pytest.param(w["name"], driver, None,
+                               id=f"{w['name']}-no_faults_module")
+        for fault in getattr(module, "FAULTS", ()):
             yield pytest.param(w["name"], driver, fault,
                                id=f"{w['name']}-{fault}")
 
@@ -154,16 +126,55 @@ def _fault_cases():
 @pytest.mark.parametrize("cell,driver,fault", list(_fault_cases()))
 def test_planted_fault_makes_correct_false(capsys, monkeypatch, cell, driver,
                                            fault):
-    FAULTS[driver][0](monkeypatch, fault)
+    if fault is None:
+        pytest.fail(f"driver {driver!r} has no planted faults: add "
+                    f"benchmarks/tests/faults/{driver}.py with FAULTS and "
+                    "plant(monkeypatch, fault)")
+    faults_of(driver).plant(monkeypatch, fault)
     rc, res = run_cell(capsys, cell)
     assert rc == 1 and res["correct"] is False
     assert any(v["value"] > v["limit"] for v in res["compared"].values())
 
 
+def test_a_driver_without_a_faults_module_is_one_failing_case(tmp_path):
+    """A copy of the benchmark whose manifest has one more cell, of a
+    driver that has no ``faults`` module: the test module still collects,
+    the six cases are there under their ids, and ONE case fails, naming
+    the file to add."""
+    shutil.copytree(HERE, tmp_path / "benchmarks", ignore=shutil.ignore_patterns(
+        "cache", "__pycache__", ".pytest_cache", "testdata"))
+    third = {**MANIFEST["workloads"][-1], "name": "b4_import.third",
+             "traffic": "third"}
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(
+        {**MANIFEST, "workloads": MANIFEST["workloads"] + [third]}))
+    (tmp_path / "benchmarks" / "traffic" / "third.json").write_text(
+        json.dumps({"driver": "import_third"}))
+    env = dict(os.environ, PYTHONPATH=bench.ROOT)  # the program, for the fixtures
+
+    def pytest_there(*args):
+        return subprocess.run(
+            [sys.executable, "-m", "pytest", "benchmarks/tests/test_benchmark.py",
+             "-q", "-p", "no:cacheprovider", "-p", "no:xdist", *args],
+            cwd=tmp_path, env=env, capture_output=True, text=True, timeout=300)
+
+    listed = pytest_there("--collect-only", "-k", "planted_fault")
+    assert listed.returncode == 0, listed.stdout + listed.stderr
+    ids = [ln.split("[", 1)[1].rstrip("]") for ln in listed.stdout.splitlines()
+           if "test_planted_fault_makes_correct_false[" in ln]
+    ours = [p.id for p in _fault_cases()]
+    assert ids == ours + ["b4_import.third-no_faults_module"]
+    ran = pytest_there("-k", "no_faults_module")
+    last = ran.stdout.splitlines()[-1]
+    assert ran.returncode == 1 and last.startswith("1 failed") \
+        and "error" not in last, ran.stdout
+    assert "benchmarks/tests/faults/import_third.py" in ran.stdout
+
+
 def test_no_chip_means_no_result(capsys):
     # without --rehearsal the CPU backend is refused before any result
     with pytest.raises(bench.BenchError):
-        bench.main(["--workload", CELLS[0], "--seed", "1", "--seconds", "0.2"])
+        bench.main(["--workload", "b4_import.fleet16", "--seed", "1",
+                    "--seconds", "0.2"])
     assert not capsys.readouterr().out.strip().endswith("}")
     with pytest.raises(bench.BenchError):
         bench.main(["--workload", "no.such_cell", "--seed", "1",
@@ -205,6 +216,57 @@ def test_trace_reduction_arithmetic():
     assert empty["busy_s"] is None  # nothing ran: no share of anything
 
 
+def test_a_gap_is_named_by_the_innermost_span_over_its_middle():
+    # two launches, 4 s of host work between them: the middle (t = 4.0)
+    # lies in the program's fleet.decode, inside the benchmark's call; the
+    # second's (t = 0.5) in no stage of the entry, so it reads the entry
+    ev = {"devices": {"/device:TPU:0": {
+        "ops": [("%a = f32[] x()", 1.0, 2.0), ("%a = f32[] x()", 6.0, 7.0)],
+        "modules": [("jit_f", 1.0, 2.0), ("jit_f", 6.0, 7.0)]}},
+        "spans": [("bench.window", 0.0, 7.0), ("bench.call", 0.0, 2.5),
+                  ("fleet.merge_text_payloads", 0.1, 2.5), ("fleet.join", 2.1, 2.5),
+                  ("bench.call", 2.5, 7.0), ("fleet.merge_text_payloads", 2.6, 7.0),
+                  ("fleet.decode", 2.7, 4.5), ("native.explode", 2.8, 3.2),
+                  ("fleet.pack", 4.5, 5.5)]}
+    t = trace_reduce.reduce_events(ev)
+    assert t["idle_gaps"] == [["fleet.decode", 4.0],
+                              ["fleet.merge_text_payloads", 1.0]]
+    assert t["busy_s"] == 2.0 and t["window_s"] == 7.0
+
+
+def test_the_launching_threads_program_spans_are_read_from_a_trace(tmp_path):
+    """A trace recorded here (host plane only): the program's spans of the
+    thread that holds ``bench.*`` are kept, a worker thread's are not."""
+    import threading
+
+    import jax.profiler as P
+
+    from loro_tpu.utils import tracing
+
+    def worker():
+        with tracing.span("packed.decode_one", doc=3):
+            pass
+
+    opts = P.ProfileOptions()
+    opts.python_tracer_level, opts.host_tracer_level = 0, 1
+    P.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        with P.TraceAnnotation("bench.window"), P.TraceAnnotation("bench.call"):
+            other = threading.Thread(target=worker)
+            other.start()
+            with tracing.span("fleet.merge_text_payloads", docs=1):
+                with tracing.span("fleet.decode", doc=0), tracing.span("native.explode"):
+                    other.join()
+    finally:
+        P.stop_trace()
+    ev = trace_reduce.load_events(trace_reduce.find_xplane(str(tmp_path)))
+    names = [n for n, _s, _e in ev["spans"]]
+    assert names == ["bench.window", "bench.call", "fleet.merge_text_payloads",
+                     "fleet.decode", "native.explode"]
+    t = trace_reduce.reduce_events(ev)  # no device plane: nothing ran
+    assert t["busy_s"] is None and t["window_s"] > 0
+
+
 def test_byte_counts_from_shapes():
     assert bytes_model.import_bytes(1000, 22, 4) == 26000.0
     assert bytes_model.roofline_pct(819e9, 819e9, 4.0) == 25.0
@@ -218,7 +280,7 @@ def test_byte_counts_from_shapes():
 # the edit script and the plain reference
 # ---------------------------------------------------------------------------
 
-CONFIG = bench.load_json(bench.ROOT, MANIFEST["configs"][0]["file"])
+CONFIG = bench.load_json(HERE, "configs", "b4_import.json")  # the script is text's
 TINY = {**CONFIG, **CONFIG["rehearsal"]}
 
 
@@ -308,4 +370,4 @@ def test_documents_off_the_sources_shape_fail_set_up(capsys, monkeypatch):
 
     monkeypatch.setattr(bench, "load_json", off)
     with pytest.raises(RuntimeError, match="the configuration states"):
-        run_cell(capsys, CELLS[0])
+        run_cell(capsys, "b4_import.fleet16")

@@ -49,6 +49,9 @@ SELF_CASES = {
     "per_document_not_per_call": (TWO_THREADS, ["call"], "stage.a", (50 + 60) / 2),
     "no_per_span": (ONE_CALL, ["stage.a"], "no.such.root", None),
     "no_spans_at_all": ([], ["stage.a"], "call", None),
+    # a parent commit from before the stage had a span: nothing, never 0.0
+    "the_per_span_but_none_of_the_named": (
+        ONE_CALL, ["stage.c", "stage.d"], "call", None),
     # what the parent commit's tracing returns: chrome events, not spans
     "a_program_without_the_record": (
         [{"name": "call", "ph": "X", "ts": 0.0, "dur": 5.0}], ["call"], "call", None),

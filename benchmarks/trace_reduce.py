@@ -8,9 +8,13 @@ The smallest reduction that gives them (read with nothing but
   device planes; idle share = 1 - busy / window;
 - the top operations by summed duration, under the names the trace gives;
 - launches: the events of line ``XLA Modules`` (one per executed program);
-- the longest idle gaps, each named by the benchmark's own
-  ``TraceAnnotation`` (``bench.*`` on the host plane's ``python`` line)
-  that covers the gap's middle, or ``outside bench spans``.
+- the longest idle gaps, each named by the innermost span of the
+  launching thread that covers the gap's middle: the benchmark's own
+  ``TraceAnnotation`` (``bench.*``) or, inside it, the program's
+  (``fleet.*``, ``packed.*``, ``native.*``: ``loro_tpu.utils.tracing``), or
+  ``outside bench spans``.  The launching thread is the host plane's line
+  that holds the ``bench.*`` spans; other threads' spans (the decode
+  workers') are busy under every gap and would name none.
 
 The window is the span of the ``bench.window`` annotation when there is
 one, else first event start to last event end.  Device and host events
@@ -25,6 +29,7 @@ import re
 DEVICE_PLANE = re.compile(r"^/device:TPU:\d+$")
 OPS_LINE, MODULES_LINE = "XLA Ops", "XLA Modules"
 HOST_PLANE, SPAN_PREFIX, WINDOW_SPAN = "/host:CPU", "bench.", "bench.window"
+PROGRAM_PREFIXES = ("fleet.", "packed.", "native.")
 MIN_GAP_S = 1e-6  # back-to-back operations leave nanoseconds: not a gap
 
 
@@ -78,10 +83,12 @@ def load_events(path: str) -> dict:
                     rec[key].append((ev.name, s, s + ev.duration_ns * 1e-9))
         elif plane.name == HOST_PLANE:
             for line in plane.lines:
-                for ev in line.events:
-                    if ev.name.startswith(SPAN_PREFIX):
-                        s = ev.start_ns * 1e-9
-                        spans.append((ev.name, s, s + ev.duration_ns * 1e-9))
+                mine = [(ev.name, ev.start_ns * 1e-9,
+                         ev.start_ns * 1e-9 + ev.duration_ns * 1e-9)
+                        for ev in line.events
+                        if ev.name.startswith((SPAN_PREFIX, *PROGRAM_PREFIXES))]
+                if any(n.startswith(SPAN_PREFIX) for n, _s, _e in mine):
+                    spans += mine  # the launching thread's line
     return {"devices": devices, "spans": sorted(spans, key=lambda x: x[1])}
 
 

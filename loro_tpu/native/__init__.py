@@ -466,32 +466,33 @@ def explode_tree_payload(payload: bytes, target_cid_index: int):
     lib = _load()
     if lib is None:
         return None
-    payload = _obs_decode("tree", payload)
-    n = lib.loro_count_tree_ops(payload, len(payload), target_cid_index)
-    if n < 0:
-        raise CodecDecodeError("native decode failed (malformed payload?)")
-    cols = {
-        "lamport": np.empty(n, np.int32),
-        "peer_idx": np.empty(n, np.int32),
-        "counter": np.empty(n, np.int32),
-        "target_peer_idx": np.empty(n, np.int32),
-        "target_ctr": np.empty(n, np.int32),
-        "flags": np.empty(n, np.int32),
-        "parent_peer_idx": np.empty(n, np.int32),
-        "parent_ctr": np.empty(n, np.int32),
-        "pos_off": np.empty(n, np.int64),
-        "pos_len": np.empty(n, np.int32),
-    }
-    wrote = lib.loro_explode_tree(
-        payload,
-        len(payload),
-        target_cid_index,
-        *[a.ctypes.data_as(ctypes.c_void_p) for a in cols.values()],
-        n,
-    )
-    if wrote != n:
-        raise CodecDecodeError("native decode failed (count mismatch)")
-    return cols
+    with _tracing.span("native.explode_tree", bytes=len(payload)):
+        payload = _obs_decode("tree", payload)
+        n = lib.loro_count_tree_ops(payload, len(payload), target_cid_index)
+        if n < 0:
+            raise CodecDecodeError("native decode failed (malformed payload?)")
+        cols = {
+            "lamport": np.empty(n, np.int32),
+            "peer_idx": np.empty(n, np.int32),
+            "counter": np.empty(n, np.int32),
+            "target_peer_idx": np.empty(n, np.int32),
+            "target_ctr": np.empty(n, np.int32),
+            "flags": np.empty(n, np.int32),
+            "parent_peer_idx": np.empty(n, np.int32),
+            "parent_ctr": np.empty(n, np.int32),
+            "pos_off": np.empty(n, np.int64),
+            "pos_len": np.empty(n, np.int32),
+        }
+        wrote = lib.loro_explode_tree(
+            payload,
+            len(payload),
+            target_cid_index,
+            *[a.ctypes.data_as(ctypes.c_void_p) for a in cols.values()],
+            n,
+        )
+        if wrote != n:
+            raise CodecDecodeError("native decode failed (count mismatch)")
+        return cols
 
 
 def explode_movable_payload(payload: bytes, target_cid_index: int):

@@ -200,10 +200,14 @@ def _empty_text_batch(transport: str, d_pad: int, pad_c: int, pad_n: int):
 
 
 class Fleet:
-    """Batched merge front-end bound to a device mesh."""
+    """Batched merge front-end bound to a device mesh.  ``tree_refused``:
+    per document of this Fleet's last tree merge on the device, the moves
+    its replay refused as cycles (None before one, and after a degraded
+    call)."""
 
     def __init__(self, mesh=None):
         self.mesh = mesh if mesh is not None else make_mesh()
+        self.tree_refused: Optional[np.ndarray] = None
 
     # ------------------------------------------------------------------
     # text / list sequence merge
@@ -591,120 +595,117 @@ class Fleet:
         from ..codec.binary import decode_changes
         from ..ops.tree_batch import extract_tree_from_payload, extract_tree_ops
 
-        extracted = []
-        for p in payloads:
+        # one trace id per call (a request's own, when it made the call)
+        trace_id = tracing.current() or tracing.new_trace_id("f")
+        with tracing.span(
+            "fleet.merge_tree_payloads", trace_id=trace_id, docs=len(payloads)
+        ):
+            extracted = []
+            for p in payloads:
+                with tracing.span("fleet.tree_decode", bytes=len(p)):
+                    try:
+                        ex = extract_tree_from_payload(p, cid)
+                    except ValueError:
+                        ex = None
+                    if ex is None:
+                        # tree ops carry no intra-payload row references,
+                        # so the Python fallback is total
+                        _obs_fallback("payload_extract")
+                        ex = extract_tree_ops(decode_changes(p), cid)
+                extracted.append(ex)
             try:
-                ex = extract_tree_from_payload(p, cid)
-            except ValueError:
-                ex = None
-            if ex is None:
-                # tree ops carry no intra-payload row references, so the
-                # Python fallback is total
-                _obs_fallback("payload_extract")
-                ex = extract_tree_ops(decode_changes(p), cid)
-            extracted.append(ex)
-        try:
-            return self._merge_tree_extracted(extracted)
-        except DeviceFailure:
-            return _host_degrade("tree", [decode_changes(p) for p in payloads], cid)
+                return self._merge_tree_extracted(extracted)
+            except DeviceFailure:
+                return _host_degrade(
+                    "tree", [decode_changes(p) for p in payloads], cid
+                )
 
-    def _merge_tree_extracted(self, extracted) -> List[dict]:
-        import jax.numpy as jnp
-
-        from ..ops.fugue_batch import pad_bucket
+    def _tree_device_merge(self, extracted, family: str, want_eff: bool):
+        """The device half of every tree merge: one packed upload
+        (`pack_tree_rows`), one launch (`tree_import_batch`: the replay
+        `replay_algo` selects, then `is_deleted_batch`), one fetch.
+        Returns i32[d_pad, n + 2] — per document the parent of every
+        alive node (TRASH: deleted, ABSENT: never created), the moves
+        refused, the walk steps — and, ``want_eff``, the moves effected
+        bool[d_pad, m]."""
         from ..ops.tree_batch import (
-            ABSENT,
-            ROOT,
-            TRASH,
-            TreeOpCols,
-            is_deleted_batch,
-            pad_tree_cols,
-            tree_merge_batch,
+            pack_tree_rows,
+            replay_algo,
+            tree_import_batch,
+            tree_pads,
         )
 
-        m = pad_bucket(max(1, max(c.target.shape[0] for c, _, _ in extracted)), floor=16)
+        label = f"fleet.{family}"
+        self.tree_refused = None
+        cols = [c for c, _, _ in extracted]
+        moves = sum(int(c.valid.sum()) for c in cols)
+        longest = max([c.target.shape[0] for c in cols] + [1])
+        m = tree_pads(longest)
         n = max(1, max(len(nodes) for _, nodes, _ in extracted))
         d = len(extracted)
         d_pad = _mesh_pad(self.mesh, d)
         _obs_merge(
-            "tree", d, sum(c.target.shape[0] for c, _, _ in extracted),
-            m * d_pad, (m, n, d_pad),
+            family, d, sum(c.target.shape[0] for c in cols), m * d_pad,
+            (m, n, d_pad),
         )
-        padded = [pad_tree_cols(c, m) for c, _, _ in extracted]
-        empty = TreeOpCols(
-            target=np.zeros(m, np.int32), parent=np.full(m, ROOT, np.int32), valid=np.zeros(m, bool)
+        algo = replay_algo(n)
+        obs.counter("fleet.tree_docs_total").inc(d)
+        obs.counter("tree.moves_total").inc(moves)
+        # the sequential steps of the launch: the scan replays its padding
+        obs.counter("tree.replay_steps").inc(
+            longest if algo == "pallas:lockstep" else m, algo=algo
         )
-        padded += [empty] * (d_pad - d)
+        with tracing.span("fleet.tree_stack"):
+            rows = pack_tree_rows(cols, d_pad)
         sh = doc_sharding(self.mesh)
-        cols = _sup_launch("fleet.tree", lambda: TreeOpCols(
-            *[jax.device_put(np.stack([getattr(c, f) for c in padded]), sh) for f in TreeOpCols._fields]
-        ))
-        parents, eff = _sup_launch(
-            "fleet.tree", lambda: tree_merge_batch(cols, n)
-        )
-        deleted = _sup_fetch(
-            "fleet.tree", _sup_launch("fleet.tree", lambda: is_deleted_batch(parents))
-        )
-        parents = _sup_fetch("fleet.tree", parents)
-        eff = _sup_fetch("fleet.tree", eff)
-        out = []
-        for i, (c, nodes, row_pos) in enumerate(extracted):
-            res = {}
-            for j, tid in enumerate(nodes):
-                p = int(parents[i, j])
-                if p == ABSENT or deleted[i, j]:
-                    continue
-                res[tid] = None if p == ROOT else nodes[p]
-            out.append(res)
-        return out
+        with tracing.span("fleet.tree_upload"):
+            batched = _sup_launch(label, lambda: jax.device_put(rows, sh))
+        with tracing.span("fleet.tree_launch"):
+            out = _sup_launch(
+                label, lambda: tree_import_batch(batched, n, want_eff)
+            )
+        with tracing.span("fleet.tree_device_wait"):
+            get_supervisor().guard(
+                lambda: jax.block_until_ready(out), label=label
+            )
+        with tracing.span("fleet.tree_fetch"):
+            if want_eff:
+                out, eff = (_sup_fetch(label, x) for x in out)
+            else:
+                out, eff = _sup_fetch(label, out), None
+        self.tree_refused = out[:d, n].copy()
+        obs.counter("tree.moves_refused_total").inc(int(self.tree_refused.sum()))
+        return out, eff
+
+    def _merge_tree_extracted(self, extracted) -> List[dict]:
+        from ..ops.tree_batch import ROOT
+
+        out, _eff = self._tree_device_merge(extracted, "tree", want_eff=False)
+        with tracing.span("fleet.tree_maps"):
+            maps = []
+            for i, (_c, nodes, _pos) in enumerate(extracted):
+                row = out[i, : len(nodes)].tolist()
+                # alive nodes only: TRASH and ABSENT are below ROOT
+                maps.append({
+                    nodes[j]: (None if p == ROOT else nodes[p])
+                    for j, p in enumerate(row) if p >= ROOT
+                })
+        return maps
 
     def merge_tree_children(self, docs_changes: Sequence[Sequence[Change]], cid) -> List[dict]:
         """Like merge_tree_changes but returns ordered children maps
         {parent|None: [child TreeIDs in (fractional-index, move-key)
         order]} — the full materialized tree shape."""
-        from ..ops.fugue_batch import pad_bucket
-        from ..ops.tree_batch import (
-            ABSENT,
-            ROOT,
-            TreeOpCols,
-            extract_tree_ops,
-            is_deleted_batch,
-            pad_tree_cols,
-            positions_of,
-            tree_merge_batch,
-        )
+        from ..ops.tree_batch import ROOT, extract_tree_ops, positions_of
 
         extracted = [extract_tree_ops(chs, cid) for chs in docs_changes]
-        m = pad_bucket(max(1, max(c.target.shape[0] for c, _, _ in extracted)), floor=16)
-        n = max(1, max(len(nodes) for _, nodes, _ in extracted))
-        d = len(extracted)
-        d_pad = _mesh_pad(self.mesh, d)
-        # distinct family: the children materialization runs extra
-        # kernels, so its shapes must not alias _merge_tree_extracted's
-        # in the jit-cache proxy
-        _obs_merge(
-            "tree_children", d, sum(c.target.shape[0] for c, _, _ in extracted),
-            m * d_pad, (m, n, d_pad),
-        )
-        padded = [pad_tree_cols(c, m) for c, _, _ in extracted]
-        empty = TreeOpCols(
-            target=np.zeros(m, np.int32), parent=np.full(m, ROOT, np.int32), valid=np.zeros(m, bool)
-        )
-        padded += [empty] * (d_pad - d)
-        sh = doc_sharding(self.mesh)
         try:
-            cols = _sup_launch("fleet.tree_children", lambda: TreeOpCols(
-                *[jax.device_put(np.stack([getattr(c, f) for c in padded]), sh) for f in TreeOpCols._fields]
-            ))
-            parents, eff = _sup_launch(
-                "fleet.tree_children", lambda: tree_merge_batch(cols, n)
+            # a family of its own: this launch also returns the moves
+            # effected, so its shapes must not alias the import's in the
+            # jit-cache proxy
+            alive, eff = self._tree_device_merge(
+                extracted, "tree_children", want_eff=True
             )
-            deleted = _sup_fetch(
-                "fleet.tree_children",
-                _sup_launch("fleet.tree_children", lambda: is_deleted_batch(parents)),
-            )
-            parents = _sup_fetch("fleet.tree_children", parents)
-            eff = _sup_fetch("fleet.tree_children", eff)
         except DeviceFailure:
             return _host_degrade("tree_children", docs_changes, cid)
         out = []
@@ -720,8 +721,8 @@ class Fleet:
                     last_eff_row[int(c.target[j])] = j
             kids: Dict = {}
             for j, tid in enumerate(nodes):
-                p = int(parents[i, j])
-                if p == ABSENT or deleted[i, j]:
+                p = int(alive[i, j])
+                if p < ROOT:
                     continue
                 parent_t = None if p == ROOT else nodes[p]
                 kids.setdefault(parent_t, []).append(

@@ -221,6 +221,28 @@ def test_a_plain_jit_on_four_devices_is_refused(mesh4, tpu_branches):
         jax.jit(fb.materialize_content_batch).lower(seq_sds(8, 8192, sh))
 
 
+@pytest.mark.parametrize("where,want_eff", [("one_chip", False), ("mesh4", True)])
+def test_tree_import_launch_compiles_with_the_fused_replay(
+        request, tpu_branches, where, want_eff):
+    """The one launch of ``Fleet``'s tree entry at upstream's bench shape
+    (256 documents, 1,000 nodes, 98,304 padded moves; benchmark cell
+    ``tree_import.fleet256``): on one chip as the payload entry launches
+    it, and under shard_map on four with the moves effected returned
+    (``merge_tree_children``).  The replay is the Pallas kernel, and no
+    device talks to another."""
+    from loro_tpu.ops import tree_batch as tb
+
+    assert tb.replay_algo(1000) == "pallas:lockstep"
+    m = tb.tree_pads(97_700)
+    place = request.getfixturevalue(where)
+    sh = place if where == "one_chip" else NamedSharding(place, P(DOC_AXIS))
+    text = compile_checked(
+        f"tree_import_batch:{where}:[256,1+{m}]:n1000:eff={want_eff}",
+        tb.tree_import_batch.lower(sds((256, 1 + m), jnp.uint32, sh), 1000, want_eff),
+        True)
+    assert "all-gather" not in text and "all-reduce" not in text
+
+
 # ---------------------------------------------------------------------------
 # whole steps at real width (slow: 20-70 s of compile each)
 # ---------------------------------------------------------------------------

@@ -57,23 +57,38 @@ def _best_of(fn, n, reps=4) -> float:
     return min(fn(n) for _ in range(reps))
 
 
+def _scaling(fn, n: int, floor: float) -> float:
+    """Time of 4x the work over the time of the work, each the minimum
+    over four repetitions; the two sizes measured turn about so that a
+    burst of load on a shared runner falls on both, and the whole measured
+    once more before a ratio over the bound stands (one tier-1 run read
+    11.2x under six xdist workers where it reads 2.6-4.8x alone)."""
+    for _attempt in range(2):
+        small, big = [], []
+        for _ in range(4):
+            small.append(fn(n))
+            big.append(fn(4 * n))
+        ratio = min(big) / max(min(small), floor)
+        if ratio < RATIO_BOUND:
+            break
+    return ratio
+
+
 @timing_guard
 def test_text_insert_not_quadratic():
     # sizes large enough that interpreter warmup noise doesn't dominate
-    small = max(_best_of(_time_text_insert, 4000), 1e-3)
-    big = _best_of(_time_text_insert, 16000)
-    assert big / small < RATIO_BOUND, (
-        f"text insert scaling {big/small:.1f}x for 4x work "
+    ratio = _scaling(_time_text_insert, 4000, 1e-3)
+    assert ratio < RATIO_BOUND, (
+        f"text insert scaling {ratio:.1f}x for 4x work "
         f"(bound {RATIO_BOUND}; widen via PERF_GUARD_RATIO if load-noise)"
     )
 
 
 @timing_guard
 def test_import_not_quadratic():
-    small = max(_best_of(_time_import, 100), 1e-4)
-    big = _best_of(_time_import, 400)
-    assert big / small < RATIO_BOUND, (
-        f"import scaling {big/small:.1f}x for 4x work "
+    ratio = _scaling(_time_import, 100, 1e-4)
+    assert ratio < RATIO_BOUND, (
+        f"import scaling {ratio:.1f}x for 4x work "
         f"(bound {RATIO_BOUND}; widen via PERF_GUARD_RATIO if load-noise)"
     )
 

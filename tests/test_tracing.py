@@ -116,17 +116,26 @@ def _check_cpu_within_wall():
     with tracing.span("sleeps"):
         with tracing.span("child"):  # the thread clock is read on roots only
             time.sleep(0.05)
-    with tracing.span("spins"):
-        t0 = time.perf_counter()
-        while time.perf_counter() - t0 < 0.05:
-            pass
+    # the spin ends when the THREAD's clock has advanced 50 ms, and is made
+    # again when its wall time was mostly another process's (six xdist
+    # workers share the cores): what is guarded is the clocks, not the host
+    for attempt in range(8):
+        with tracing.span(f"spins{attempt}"):
+            t0 = time.thread_time_ns()
+            while time.thread_time_ns() - t0 < 50_000_000:
+                pass
+        e = tracing.events()[-1]
+        if e["cpu_ns"] > 0.5 * (e["end_ns"] - e["start_ns"]):
+            break
     by = {e["name"]: e for e in tracing.events()}
     assert by.pop("child")["cpu_ns"] is None
     for e in by.values():
         wall = e["end_ns"] - e["start_ns"]
         assert 0 <= e["cpu_ns"] <= wall + 2_000_000  # the two clocks' grain
+        assert e["name"] == "sleeps" or e["cpu_ns"] >= 48_000_000
     assert by["sleeps"]["cpu_ns"] < 0.5 * (by["sleeps"]["end_ns"] - by["sleeps"]["start_ns"])
-    assert by["spins"]["cpu_ns"] > 0.5 * (by["spins"]["end_ns"] - by["spins"]["start_ns"])
+    last = by[f"spins{attempt}"]
+    assert last["cpu_ns"] > 0.5 * (last["end_ns"] - last["start_ns"])
 
 
 def _check_observer_bridge():
@@ -318,6 +327,13 @@ FLEET_TREE = {
        for s in ("contract", "stack", "pack", "upload", "launch", "device_wait",
                  "fetch", "join")},
 }
+TREE_TREE = {
+    "fleet.merge_tree_payloads": {None},
+    "native.explode_tree": {"fleet.tree_decode"},
+    **{f"fleet.tree_{s}": {"fleet.merge_tree_payloads"}
+       for s in ("decode", "stack", "upload", "launch", "device_wait",
+                 "fetch", "maps")},
+}
 PACKED_TREE = {
     "packed.decode_one": {None},
     **{f"packed.{s}": {"packed.decode_one"}
@@ -395,6 +411,56 @@ def test_import_paths_give_their_span_trees_and_the_same_answers():
     assert obs.counter("packed.launches_total").total() >= 2
 
 
+def _tree_payload(i: int):
+    """A full-history payload of two replicas that move concurrently (one
+    pair of moves makes a cycle), and the tree they converge on."""
+    a, b = LoroDoc(peer=700 + 2 * i), LoroDoc(peer=701 + 2 * i)
+    ta = a.get_tree("tree")
+    nodes = [ta.create() for _ in range(4 + i)]
+    a.commit()
+    b.import_(a.export_snapshot())
+    ta.move(nodes[0], nodes[1])
+    b.get_tree("tree").move(nodes[1], nodes[0])
+    b.get_tree("tree").move(nodes[3], nodes[2])
+    a.import_(b.export_updates(a.oplog_vv()))
+    return strip_envelope(a.export_updates({})), {n: ta.parent(n) for n in ta.nodes()}
+
+
+def test_the_tree_entry_gives_its_span_tree_and_the_same_answers():
+    from loro_tpu.core.ids import ContainerID, ContainerType
+    from loro_tpu.parallel.fleet import Fleet
+    from loro_tpu.parallel.mesh import make_mesh
+
+    cid = ContainerID.root("tree", ContainerType.Tree)
+    docs = [_tree_payload(i) for i in range(4)]
+    payloads = [p for p, _t in docs]
+    fleet = Fleet(make_mesh(jax.devices()[:1]))
+    tracing.clear()
+    refused = obs.counter("tree.moves_refused_total").total()
+    untraced = fleet.merge_tree_payloads(payloads, cid)
+    assert untraced == [t for _p, t in docs] and tracing.events() == []
+    # one of each concurrent pair of moves is refused, in every document
+    assert obs.counter("tree.moves_refused_total").total() - refused == 4
+    tracing.enable()
+    try:
+        traced_maps = fleet.merge_tree_payloads(payloads, cid)
+        spans = tracing.events()
+    finally:
+        tracing.disable()
+        tracing.clear()
+    parents, by_name = _tree(spans)
+    assert traced_maps == untraced
+    assert parents == TREE_TREE
+    # one decode a payload, in the payloads' order
+    assert [e["args"]["bytes"] for e in by_name["fleet.tree_decode"]] == list(map(len, payloads))
+    assert all(len(by_name[n]) == 1 for n in TREE_TREE
+               if n not in ("fleet.tree_decode", "native.explode_tree"))
+    assert len({e["trace_id"] for e in spans}) == 1  # one id a call
+    assert len({e["tid"] for e in spans}) == 1  # all of it on the caller's thread
+    assert obs.counter("fleet.tree_docs_total").total() >= 8
+    assert obs.counter("tree.replay_steps").total() > 0
+
+
 def test_device_stage_scopes_are_in_the_lowered_programs():
     """``jax.named_scope`` at the single dispatch points: the packed
     stream's step has unpack, ring, rank, place, checksum; the step
@@ -425,3 +491,11 @@ def test_device_stage_scopes_are_in_the_lowered_programs():
     cols = SeqColumns(*[shape(bool if f in ("deleted", "valid") else np.int32)
                         for f in SeqColumns._fields])
     assert scopes(_merge_docs_jit.lower(cols)) == {"ring", "rank", "compact"}
+    # the tree import's one launch: the replay, then the deleted nodes
+    from loro_tpu.ops.tree_batch import tree_import_batch, tree_pads
+
+    text = tree_import_batch.lower(
+        jax.ShapeDtypeStruct((2, 1 + tree_pads(40)), np.uint32), 16, False
+    ).as_text(debug_info=True)
+    assert all(f"({s})/" in text or f"/{s}/" in text
+               for s in ("tree_replay", "tree_deleted"))

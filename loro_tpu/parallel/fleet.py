@@ -17,6 +17,7 @@ import functools
 import os
 import struct
 import threading
+from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -89,6 +90,74 @@ def _obs_fallback(kind: str) -> None:
     LORO_PY_IDMAP or missing native lib) and per-payload decode
     fallbacks."""
     obs.counter("fleet.host_fallback_total").inc(kind=kind)
+
+
+# the host's decode width, merge_text_payloads_packed's own
+_POOL_WIDTH = min(8, os.cpu_count() or 1)
+
+
+def _self_contained(extract):
+    """A sequence's Python extractor whose ``KeyError`` (a row that
+    references an element outside the payload) is said as the entry's
+    ``ValueError``."""
+
+    def checked(changes, cid):
+        try:
+            return extract(changes, cid)
+        except KeyError as e:
+            raise ValueError(
+                "payload is not self-contained (references elements "
+                f"outside it: {e}); one-shot fleet merges need full-"
+                "history payloads — use DeviceDocBatch for deltas"
+            ) from e
+
+    return checked
+
+
+def _decode_payloads(family: str, span: str, payloads, cid, native, python) -> list:
+    """The per-payload host stage of a payload entry, one task a payload
+    on a pool of ``_POOL_WIDTH`` threads that lives for this call:
+    ``native(payload, cid)``, and where that refuses (an incremental
+    payload that references elements outside it) or has no library,
+    ``python(decode_changes(payload), cid)``.  Answers in the payloads'
+    order, the first exception in payload order raised as a loop would
+    raise it (tasks not yet started are cancelled), every task under the
+    caller's trace id.  The tasks are large-array numpy and the native
+    explode, which give the interpreter lock up.  A caller that is itself
+    one of many threads shares the machine's cores with its siblings'
+    pools.
+
+    The caller's thread holds ONE span ``span`` around submit-and-wait:
+    the stage's time on the call's critical path.  A task's is
+    ``<span>_one``, a root of its pool thread and a leaf: the spans under
+    it (``native.explode*``) are not recorded, or readers that sum a
+    stage by its spans' names would count the pool's threads on top of
+    the wait that holds them."""
+    from ..codec.binary import decode_changes
+
+    tasks = obs.counter("fleet.decode_tasks_total")
+
+    def one(p):
+        with tracing.span(span + "_one", leaf=True, bytes=len(p)):
+            tasks.inc(family=family)
+            try:
+                ex = native(p, cid)
+            except ValueError:
+                ex = None
+            if ex is None:
+                _obs_fallback("payload_extract")
+                ex = python(decode_changes(p), cid)
+            return ex
+
+    with tracing.span(span, docs=len(payloads), workers=_POOL_WIDTH):
+        pool = ThreadPoolExecutor(
+            _POOL_WIDTH, "fleet-pool", tracing.set_current, (tracing.current(),)
+        )
+        try:
+            futures = [pool.submit(one, p) for p in payloads]
+            return [f.result() for f in futures]
+        finally:
+            pool.shutdown(wait=True, cancel_futures=True)
 
 
 def _sup_launch(label: str, thunk):
@@ -222,6 +291,8 @@ class Fleet:
         axis to a multiple of the mesh's doc dimension; doc-axis padding
         rows are all-invalid documents."""
         with tracing.span("fleet.merge_text_docs", docs=len(extracts)):
+            # on the caller's thread: some twenty short numpy passes a
+            # document, which eight threads run slower than one (PERF.md PR 29)
             with tracing.span("fleet.contract"):
                 chains = [contract_chains(e) for e in extracts]
             pad_c, pad_n = text_pads(
@@ -307,26 +378,10 @@ class Fleet:
             trace_id=tracing.current() or tracing.new_trace_id("f"),
             docs=len(payloads),
         ):
-            extracts = []
-            for p in payloads:
-                with tracing.span("fleet.decode", bytes=len(p)):
-                    try:
-                        ex = extract_seq_from_payload(p, cid)
-                    except ValueError:
-                        # native path can't resolve (e.g. incremental payload
-                        # referencing elements outside it): python fallback
-                        ex = None
-                    if ex is None:
-                        _obs_fallback("payload_extract")
-                        try:
-                            ex = extract_seq_container(decode_changes(p), cid)
-                        except KeyError as e:
-                            raise ValueError(
-                                "payload is not self-contained (references elements "
-                                f"outside it: {e}); one-shot fleet merges need full-"
-                                "history payloads — use DeviceDocBatch for deltas"
-                            ) from e
-                extracts.append(ex)
+            extracts = _decode_payloads(
+                "text", "fleet.decode", payloads, cid,
+                extract_seq_from_payload, _self_contained(extract_seq_container),
+            )
             try:
                 return self.merge_text_docs(extracts)
             except DeviceFailure:
@@ -466,23 +521,10 @@ class Fleet:
         from ..codec.binary import decode_changes
         from ..ops.movable_batch import extract_movable, extract_movable_from_payload
 
-        extracts = []
-        for p in payloads:
-            try:
-                ex = extract_movable_from_payload(p, cid)
-            except ValueError:
-                ex = None
-            if ex is None:
-                _obs_fallback("payload_extract")
-                try:
-                    ex = extract_movable(decode_changes(p), cid)
-                except KeyError as e:
-                    raise ValueError(
-                        "payload is not self-contained (references elements "
-                        f"outside it: {e}); one-shot fleet merges need full-"
-                        "history payloads — use DeviceDocBatch for deltas"
-                    ) from e
-            extracts.append(ex)
+        extracts = _decode_payloads(
+            "movable", "fleet.movable_decode", payloads, cid,
+            extract_movable_from_payload, _self_contained(extract_movable),
+        )
         try:
             return self._merge_movable_extracted(extracts)
         except DeviceFailure:
@@ -600,19 +642,10 @@ class Fleet:
         with tracing.span(
             "fleet.merge_tree_payloads", trace_id=trace_id, docs=len(payloads)
         ):
-            extracted = []
-            for p in payloads:
-                with tracing.span("fleet.tree_decode", bytes=len(p)):
-                    try:
-                        ex = extract_tree_from_payload(p, cid)
-                    except ValueError:
-                        ex = None
-                    if ex is None:
-                        # tree ops carry no intra-payload row references,
-                        # so the Python fallback is total
-                        _obs_fallback("payload_extract")
-                        ex = extract_tree_ops(decode_changes(p), cid)
-                extracted.append(ex)
+            extracted = _decode_payloads(
+                "tree", "fleet.tree_decode", payloads, cid,
+                extract_tree_from_payload, extract_tree_ops,
+            )
             try:
                 return self._merge_tree_extracted(extracted)
             except DeviceFailure:

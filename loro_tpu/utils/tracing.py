@@ -71,7 +71,8 @@ _lock = threading.Lock()
 # replace the whole tuple under _lock (never mutate in place)
 _span_observers: Tuple[Callable[[str, float], None], ...] = ()
 _span_ids = itertools.count(1)
-_ambient = threading.local()  # .trace: ambient trace id; .span: open span id
+# .trace: ambient trace id; .span: open span id; .leaf: under a leaf span
+_ambient = threading.local()
 
 
 def _profiling() -> bool:
@@ -187,19 +188,24 @@ class span:
     """``with span(name, **small_int_args):`` — a trace span; one flag
     read when tracing is off and no observer is registered.  A span that
     stands for a request names it (``trace_id=``): while it records, that
-    id is the thread's ambient one, so its children carry it too."""
+    id is the thread's ambient one, so its children carry it too.  A
+    ``leaf=True`` span is one of many that run side by side under another
+    thread's wait: what its thread opens under it records nothing, so a
+    stage's spans sum to the waiter's time and not to the threads'."""
 
-    __slots__ = ("name", "args", "_trace", "_prev", "_obs", "_id", "_parent",
-                 "_ann", "_t0", "_cpu0")
+    __slots__ = ("name", "args", "_trace", "_leaf", "_prev", "_obs", "_id",
+                 "_parent", "_ann", "_t0", "_cpu0")
 
-    def __init__(self, name: str, trace_id: Optional[str] = None, **args):
+    def __init__(self, name: str, trace_id: Optional[str] = None,
+                 leaf: bool = False, **args):
         self.name = name
         self.args = args
         self._trace = trace_id
+        self._leaf = leaf
 
     def __enter__(self) -> "span":
         self._obs = _span_observers  # COW snapshot: stable for this span
-        if not _recording():
+        if not _recording() or getattr(_ambient, "leaf", False):
             self._id = 0
             if self._obs:
                 self._t0 = time.perf_counter_ns()
@@ -207,6 +213,8 @@ class span:
         self._id = next(_span_ids)
         self._parent = getattr(_ambient, "span", 0)
         _ambient.span = self._id
+        if self._leaf:
+            _ambient.leaf = True
         if self._trace is not None:
             self._prev = current()
             _ambient.trace = self._trace
@@ -230,6 +238,8 @@ class span:
             if self._ann is not None:
                 self._ann.__exit__(*exc)
             _ambient.span = self._parent
+            if self._leaf:
+                _ambient.leaf = False
             _append((self.name, self._id, self._parent, current(),
                      threading.get_ident(), self._t0, end, cpu, self.args))
             if self._trace is not None:
@@ -241,7 +251,7 @@ class span:
 
 def instant(name: str, **args) -> None:
     obs = _span_observers
-    if _recording():
+    if _recording() and not getattr(_ambient, "leaf", False):
         now = time.perf_counter_ns()
         _append((name, next(_span_ids), getattr(_ambient, "span", 0), current(),
                  threading.get_ident(), now, now, None, args))

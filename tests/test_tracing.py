@@ -2,6 +2,7 @@
 what a span records, the one switch, and the span trees and device
 scopes of the two import paths (docs/OBSERVABILITY.md "Spans")."""
 import json
+import os
 import sys
 import threading
 import time
@@ -190,9 +191,35 @@ def _check_many_threads_lose_nothing():
             assert e["parent_id"] == 0
 
 
+def _check_a_leaf_records_nothing_under_it():
+    """A ``leaf=True`` span silences its own thread's spans under it, for
+    as long as it is open, and no other thread's; observers still hear."""
+    fired = []
+    fn = lambda name, dur: fired.append(name)  # noqa: E731
+    other = threading.Thread(target=lambda: tracing.span("elsewhere").__enter__().__exit__())
+    tracing.add_span_observer(fn)
+    try:
+        with tracing.span("task", leaf=True, bytes=3):
+            with tracing.span("inner"):
+                with tracing.span("leaf_again", leaf=True):
+                    tracing.instant("point")
+            other.start()
+            other.join()
+        with tracing.span("after"):
+            pass
+    finally:
+        tracing.remove_span_observer(fn)
+    spans = tracing.events()
+    assert [e["name"] for e in spans] == ["elsewhere", "task", "after"]
+    assert spans[1]["args"] == {"bytes": 3} and spans[1]["cpu_ns"] is not None
+    assert all(e["parent_id"] == 0 for e in spans)
+    assert fired == ["point", "leaf_again", "inner", "elsewhere", "task", "after"]
+
+
 @pytest.mark.parametrize("check", [
     _check_parents, _check_threads, _check_trace_id, _check_cpu_within_wall,
-    _check_observer_bridge, _check_many_threads_lose_nothing],
+    _check_observer_bridge, _check_many_threads_lose_nothing,
+    _check_a_leaf_records_nothing_under_it],
     ids=lambda f: f.__name__.lstrip("_"))
 def test_what_a_span_records(traced, check):
     check()
@@ -320,8 +347,10 @@ def _tree(spans):
 
 FLEET_TREE = {
     "fleet.merge_text_payloads": {None},
+    # the caller's ONE wait a call; a payload's decode is a root of a pool
+    # thread and a leaf: its ``native.explode`` is not recorded
     "fleet.decode": {"fleet.merge_text_payloads"},
-    "native.explode": {"fleet.decode"},
+    "fleet.decode_one": {None},
     "fleet.merge_text_docs": {"fleet.merge_text_payloads"},
     **{f"fleet.{s}": {"fleet.merge_text_docs"}
        for s in ("contract", "stack", "pack", "upload", "launch", "device_wait",
@@ -329,7 +358,7 @@ FLEET_TREE = {
 }
 TREE_TREE = {
     "fleet.merge_tree_payloads": {None},
-    "native.explode_tree": {"fleet.tree_decode"},
+    "fleet.tree_decode_one": {None},
     **{f"fleet.tree_{s}": {"fleet.merge_tree_payloads"}
        for s in ("decode", "stack", "upload", "launch", "device_wait",
                  "fetch", "maps")},
@@ -344,6 +373,30 @@ PACKED_TREE = {
        for s in ("wait_decoded", "submit", "stack", "put", "dispatch")},
     "packed.drain": {None},
 }
+
+
+def _check_pool_spans(spans, by_name, tree, payloads, decode):
+    """The caller / pool split of a ``Fleet`` payload entry: ONE ``decode``
+    span a call on the caller's thread, one ``<decode>_one`` a payload on
+    pool threads and nothing under it, one trace id on all."""
+    one = decode + "_one"
+    assert all(len(by_name[n]) == 1 for n in tree if n != one)
+    assert by_name[decode][0]["args"] == {"docs": len(payloads), "workers": min(8, os.cpu_count())}
+    # one task a payload (their lengths differ); pool threads start them in any order
+    ones = by_name[one]
+    assert sorted(e["args"]["bytes"] for e in ones) == sorted(map(len, payloads))
+    assert len({e["trace_id"] for e in spans}) == 1  # one id a call, on every thread
+    caller = {e["tid"] for e in spans if e["name"] != one}
+    assert caller == {threading.get_ident()}
+    pool = {e["tid"] for e in ones}
+    assert not pool & caller and len(pool) <= len(payloads)
+    assert all(e["cpu_ns"] is not None for e in ones)  # roots of their threads
+    # leaves: a pool thread records its tasks and nothing else
+    assert {e["name"] for e in spans if e["tid"] in pool} == {one}
+    # the wait holds every task, so the stage's spans by SELF time are the wait
+    wait = by_name[decode][0]
+    assert all(wait["start_ns"] <= e["start_ns"] and e["end_ns"] <= wait["end_ns"]
+               for e in ones)
 
 
 def test_import_paths_give_their_span_trees_and_the_same_answers():
@@ -383,11 +436,7 @@ def test_import_paths_give_their_span_trees_and_the_same_answers():
     parents, by_name = _tree(fleet_spans)
     assert fleet_texts == untraced[0]
     assert parents == FLEET_TREE
-    assert [e["args"]["bytes"] for e in by_name["fleet.decode"]] == [len(p) for p in payloads]
-    assert all(len(by_name[n]) == 1 for n in FLEET_TREE
-               if n not in ("fleet.decode", "native.explode"))
-    assert len({e["trace_id"] for e in fleet_spans}) == 1  # one id a call
-    assert len({e["tid"] for e in fleet_spans}) == 1  # the caller's thread
+    _check_pool_spans(fleet_spans, by_name, FLEET_TREE, payloads, "fleet.decode")
 
     assert packed_out == untraced[1]
     parents, by_name = _tree(spans)
@@ -451,12 +500,7 @@ def test_the_tree_entry_gives_its_span_tree_and_the_same_answers():
     parents, by_name = _tree(spans)
     assert traced_maps == untraced
     assert parents == TREE_TREE
-    # one decode a payload, in the payloads' order
-    assert [e["args"]["bytes"] for e in by_name["fleet.tree_decode"]] == list(map(len, payloads))
-    assert all(len(by_name[n]) == 1 for n in TREE_TREE
-               if n not in ("fleet.tree_decode", "native.explode_tree"))
-    assert len({e["trace_id"] for e in spans}) == 1  # one id a call
-    assert len({e["tid"] for e in spans}) == 1  # all of it on the caller's thread
+    _check_pool_spans(spans, by_name, TREE_TREE, payloads, "fleet.tree_decode")
     assert obs.counter("fleet.tree_docs_total").total() >= 8
     assert obs.counter("tree.replay_steps").total() > 0
 

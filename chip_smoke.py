@@ -12,8 +12,8 @@ against the plain host engine (``LoroDoc``):
   the chain-contracted merge (packed u8 rows, or plain ``ChainColumns``
   where the chain bucket outgrows 16-bit chain ids, as the seeded
   trace's ~51,000 chains do; the rank chosen from the chain ring);
-  (b) ``flagship``, the decode -> contract -> pack -> merge pipeline of
-  bench.py's e2e phase (``merge_text_payloads_packed``) on 64 documents
+  (b) ``flagship``, the decode -> contract -> pack -> merge pipeline
+  (``merge_text_payloads_packed``, the benchmark's packed64) on 64 documents
   in 8-document launches, where the Pallas rank must be the one that
   runs;
 - ``serve`` — ``NetServer`` -> ``SyncServer`` -> ``ResidentServer`` with
@@ -76,7 +76,7 @@ SERVE_CID = ContainerID.root("t", ContainerType.Text)
 # the sizes of the run (tests/test_chip_smoke.py calls the phases tiny)
 N_DOCS, CAPACITY = 4096, 1 << 14  # resident: 34 B/row -> 2.3 GB of columns
 HOT_DOCS, ROUNDS, WRITERS = 300, 32, 4  # serve traffic: 128 pushes of 1-50 ops
-PUBLIC_DOCS, FLAGSHIP_DOCS, CHUNK = 16, 64, 8  # import: bench.py's e2e sizes
+PUBLIC_DOCS, FLAGSHIP_DOCS, CHUNK = 16, 64, 8  # import: the benchmark's sizes
 CHIPS4_PATCHES = (34_000, 34_000, 2_500, 2_500)  # rings where Pallas applies
 
 # counters that must not move: each is a place where a host engine or a

@@ -6,7 +6,7 @@ routes through DeviceSupervisor", keyed-blake2b-never-``hash()``
 placement, the typed-error discipline).  This package turns them into
 CI failures instead of post-mortems:
 
-- **tpulint** (``python -m loro_tpu.analysis.lint loro_tpu bench.py chip_smoke.py``):
+- **tpulint** (``python -m loro_tpu.analysis.lint loro_tpu chip_smoke.py``):
   an AST-based rule registry (``rules.py``) with per-line
   ``# tpulint: disable=RULE(reason)`` pragmas and a checked-in
   baseline; the tier-1 gate in tests/test_analysis.py fails on any

@@ -5,9 +5,9 @@ applies per-line pragmas and the baseline, and exits non-zero on any
 active finding.  Pure stdlib — no jax import — so it runs in
 milliseconds as a pre-commit hook or the tier-1 gate test.
 
-    python -m loro_tpu.analysis.lint loro_tpu bench.py chip_smoke.py
+    python -m loro_tpu.analysis.lint loro_tpu chip_smoke.py
     python -m loro_tpu.analysis.lint --format=json loro_tpu
-    python -m loro_tpu.analysis.lint --write-baseline loro_tpu bench.py
+    python -m loro_tpu.analysis.lint --write-baseline loro_tpu chip_smoke.py
 
 Every active finding feeds the obs registry
 (``analysis.findings_total{rule=...}`` / ``analysis.suppressed_total``)
@@ -32,7 +32,7 @@ from .core import (
 )
 
 # repo root = parent of the loro_tpu package: scope predicates match
-# repo-relative posix paths ("loro_tpu/sync/server.py", "bench.py")
+# repo-relative posix paths ("loro_tpu/sync/server.py", "chip_smoke.py")
 _REPO_ROOT = os.path.dirname(
     os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 )
@@ -43,7 +43,7 @@ DEFAULT_BASELINE = os.path.join(os.path.dirname(os.path.abspath(__file__)),
 def _relpath(path: str) -> str:
     """Repo-relative posix path for scope matching.  Files outside the
     repo root re-anchor at their last ``loro_tpu`` component (or a
-    ``bench.py`` basename) so linting a DIFFERENT checkout of this
+    ``chip_smoke.py`` basename) so linting a DIFFERENT checkout of this
     project still applies every rule — a silent all-scopes-miss
     "clean" on a foreign tree would be worse than any finding."""
     ap = os.path.abspath(path)
@@ -56,8 +56,8 @@ def _relpath(path: str) -> str:
         if "loro_tpu" in parts:
             last = len(parts) - 1 - parts[::-1].index("loro_tpu")
             return "/".join(parts[last:])
-        if parts[-1] == "bench.py":
-            return "bench.py"
+        if parts[-1] == "chip_smoke.py":
+            return "chip_smoke.py"
         rel = path
     return rel.replace(os.sep, "/")
 
@@ -155,7 +155,7 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
         description="project-invariant static analysis (docs/ANALYSIS.md)",
     )
     ap.add_argument("paths", nargs="*", default=None,
-                    help="files/dirs to lint (default: loro_tpu bench.py)")
+                    help="files/dirs to lint (default: loro_tpu chip_smoke.py)")
     ap.add_argument("--format", choices=("text", "json"), default="text")
     ap.add_argument("--rules", default=None,
                     help="comma-separated rule ids (default: all)")
@@ -175,7 +175,7 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
 
     paths = args.paths or [
         os.path.join(_REPO_ROOT, "loro_tpu"),
-        os.path.join(_REPO_ROOT, "bench.py"),
+        os.path.join(_REPO_ROOT, "chip_smoke.py"),
     ]
     rules = args.rules.split(",") if args.rules else None
     res = lint_paths(paths, rules=rules, baseline_path=args.baseline)

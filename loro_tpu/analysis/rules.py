@@ -48,8 +48,8 @@ def _pkg_only(path: str) -> bool:
     return path.startswith("loro_tpu/")
 
 
-def _pkg_and_bench(path: str) -> bool:
-    return path.startswith("loro_tpu/") or path in ("bench.py", "chip_smoke.py")
+def _pkg_and_smoke(path: str) -> bool:
+    return path.startswith("loro_tpu/") or path == "chip_smoke.py"
 
 
 # ---------------------------------------------------------------------------
@@ -310,7 +310,7 @@ def _catches_broad(h: ast.ExceptHandler) -> bool:
                 "DeviceFailure -> host fallback, CodecDecodeError -> "
                 "poison isolation, PushRejected -> per-ticket failure.  A "
                 "silent broad catch eats the signal those paths key on",
-    scope=_pkg_and_bench,
+    scope=_pkg_and_smoke,
 ))
 def check_exc(mod: ModuleSource) -> Iterable[Finding]:
     for node in ast.walk(mod.tree):
@@ -363,7 +363,7 @@ def check_exc(mod: ModuleSource) -> Iterable[Finding]:
                 "between launches, a stop file) and signal only processes "
                 "that are host-only (pinned to the CPU), saying so in the "
                 "pragma",
-    scope=_pkg_and_bench,
+    scope=_pkg_and_smoke,
 ))
 def check_chip(mod: ModuleSource) -> Iterable[Finding]:
     imap = ImportMap(mod.tree)

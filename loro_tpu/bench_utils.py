@@ -1,4 +1,4 @@
-"""Editing-trace sources for benchmarks and the chip smoke.
+"""Editing-trace sources for the chip smoke and the tests.
 
 Analog of the reference's bench-utils crate (crates/bench-utils/src/
 lib.rs:27-56 get_automerge_actions): an automerge-perf style linear
@@ -21,7 +21,6 @@ one-time cost per checkout.
 """
 from __future__ import annotations
 
-import gzip
 import os
 import random
 import zipfile
@@ -183,17 +182,6 @@ def automerge_seq_extract(source: TraceSource, limit: Optional[int] = None,
     return ex, n_ops
 
 
-def automerge_final_text(source: TraceSource, limit: Optional[int] = None) -> str:
-    """Ground-truth final text by direct patch application."""
-    s = ""
-    for pos, dels, ins in source.load(limit):
-        s = s[:pos] + ins + s[pos + dels :]
-    return s
-
-
-VARIANT_CACHE_DIR = os.path.join(_ROOT, ".bench_cache_variants")
-
-
 def concurrent_trace_variant(patches: List[Patch], seed: int, v: int,
                              n_peers: int = 4, sync_every: int = 4000) -> dict:
     """One genuinely-concurrent multi-peer variant of a patch stream:
@@ -258,156 +246,3 @@ def concurrent_trace_variant(patches: List[Patch], seed: int, v: int,
     payload = strip_envelope(ref.export_updates())
     ex = extract_seq_container(ref.oplog.changes_in_causal_order(), texts[0].id)
     return {"payload": payload, "extract": ex, "text": text, "n_ops": n_applied}
-
-
-def concurrent_trace_variants(
-    source: TraceSource,
-    n_variants: int = 8,
-    n_peers: int = 4,
-    sync_every: int = 4000,
-    limit: Optional[int] = None,
-    use_cache: bool = True,
-):
-    """``n_variants`` distinct concurrent documents of one trace (see
-    ``concurrent_trace_variant``), as a list.  Results cache to disk —
-    generation replays the trace through the host engine once per
-    variant (tens of seconds each at the published length)."""
-    import pickle
-
-    tag = (f"v{n_variants}_p{n_peers}_s{sync_every}_l{limit or 'full'}"
-           f"_{source.tag()}_n3")
-    cache = os.path.join(VARIANT_CACHE_DIR, tag + ".pkl.gz") if use_cache else None
-    if cache and os.path.exists(cache):
-        with gzip.open(cache, "rb") as f:
-            return pickle.load(f)  # written by this function, below
-
-    patches = source.load(limit)
-    out = [
-        concurrent_trace_variant(patches, source.seed, v, n_peers, sync_every)
-        for v in range(n_variants)
-    ]
-
-    if cache:
-        os.makedirs(VARIANT_CACHE_DIR, exist_ok=True)
-        tmp = cache + ".tmp"
-        with gzip.open(tmp, "wb", compresslevel=6) as f:
-            pickle.dump(out, f, protocol=pickle.HIGHEST_PROTOCOL)
-        os.replace(tmp, cache)
-    return out
-
-
-RICHTEXT_KEYS = ["bold", "italic", "color", "link"]
-
-
-def richtext_bench_docs(
-    n_distinct: int = 8,
-    n_chars: int = 12288,
-    n_marks: int = 768,
-    n_peers: int = 3,
-    sync_every: int = 1024,
-    use_cache: bool = True,
-):
-    """Concurrent rich-text fleet documents for BASELINE config 4
-    (concurrent formatting spans + text edits): each distinct doc is
-    built by n_peers replicas interleaving insert/delete/mark/unmark in
-    randomized windows with periodic syncs, converged at the end.
-
-    Returns (docs, pad_n, pad_p, pad_c): per distinct doc a dict with
-      cols: padded numpy RichtextChainCols (uniform pads across docs)
-      keys/values: style dictionaries for segment reconstruction
-      oracle: host get_richtext_value() segments (the correctness gate)
-      n_ops: chars + deletes + 2*mark-anchors integrated
-    """
-    import pickle
-    import random
-
-    from .doc import LoroDoc
-    from .ops.richtext_batch import extract_richtext_chain, pad_richtext_chain_cols
-
-    tag = f"rt{n_distinct}_c{n_chars}_m{n_marks}_p{n_peers}_s{sync_every}_n2"
-    cache = os.path.join(VARIANT_CACHE_DIR, tag + ".pkl.gz") if use_cache else None
-    if cache and os.path.exists(cache):
-        with gzip.open(cache, "rb") as f:
-            return pickle.load(f)
-
-    raw = []
-    for v in range(n_distinct):
-        rng = random.Random(0x51C9 + v)
-        docs = [LoroDoc(peer=((v + 1) << 8) + i + 1) for i in range(n_peers)]
-        texts = [d.get_text("text") for d in docs]
-
-        def sync_all():
-            for d in docs[1:]:
-                docs[0].import_(d.export_updates(docs[0].oplog_vv()))
-            for d in docs[1:]:
-                d.import_(docs[0].export_updates(d.oplog_vv()))
-
-        n_ops = 0
-        chars_left, marks_left = n_chars, n_marks
-        i = 0
-        cur, window_left = 0, 0
-        while chars_left > 0 or marks_left > 0:
-            if window_left == 0:
-                cur = rng.randrange(n_peers)
-                window_left = rng.randint(16, 128)
-            window_left -= 1
-            t = texts[cur]
-            L = len(t)
-            r = rng.random()
-            if marks_left > 0 and L >= 2 and (chars_left == 0 or r < 0.12):
-                s = rng.randrange(L - 1)
-                e = rng.randint(s + 1, min(L, s + 1 + rng.randint(1, 64)))
-                k = rng.choice(RICHTEXT_KEYS)
-                if rng.random() < 0.3:
-                    t.unmark(s, e, k)
-                else:
-                    t.mark(s, e, k, rng.choice([True, "red", "blue", 7]))
-                marks_left -= 1
-                n_ops += 2  # two anchors integrated
-            elif L > 8 and r < 0.18:
-                p = rng.randrange(L - 1)
-                d = min(rng.randint(1, 4), L - p)
-                t.delete(p, d)
-                n_ops += d
-            elif chars_left > 0:
-                run = min(rng.randint(1, 8), chars_left)
-                t.insert(
-                    rng.randint(0, L),
-                    "".join(rng.choice("abcdefgh ") for _ in range(run)),
-                )
-                chars_left -= run
-                n_ops += run
-            i += 1
-            if i % sync_every == 0:
-                sync_all()
-        sync_all()
-        sync_all()
-        oracle = texts[0].get_richtext_value()
-        for t in texts[1:]:
-            assert t.get_richtext_value() == oracle, "richtext replicas diverged"
-        ref = docs[0]
-        cols, keys, values = extract_richtext_chain(
-            ref.oplog.changes_in_causal_order(), texts[0].id
-        )
-        raw.append((cols, keys, values, oracle, n_ops))
-
-    def pad_to(n: int, q: int) -> int:
-        return -(-max(n, 1) // q) * q
-
-    pad_n = pad_to(max(c[0].chain.chain_id.shape[0] for c in raw), 1024)
-    pad_c = pad_to(max(c[0].chain.c_parent.shape[0] for c in raw), 256)
-    pad_p = pad_to(max(c[0].pair_start.shape[0] for c in raw), 128)
-    out = []
-    for cols, keys, values, oracle, n_ops in raw:
-        padded = pad_richtext_chain_cols(cols, pad_n=pad_n, pad_c=pad_c, pad_p=pad_p)
-        out.append(
-            {"cols": padded, "keys": keys, "values": values, "oracle": oracle, "n_ops": n_ops}
-        )
-    result = (out, pad_n, pad_p, pad_c)
-    if cache:
-        os.makedirs(VARIANT_CACHE_DIR, exist_ok=True)
-        tmp = cache + ".tmp"
-        with gzip.open(tmp, "wb", compresslevel=6) as f:
-            pickle.dump(result, f, protocol=pickle.HIGHEST_PROTOCOL)
-        os.replace(tmp, cache)
-    return result

@@ -29,7 +29,7 @@ def configure_compile_cache() -> str:
     uses it and nothing is changed; otherwise the cache goes to
     ``<checkout>/.jax_cache`` — never a temp name, pid or time, because
     the path is part of the cache key.  Entry points (chip_smoke.py,
-    bench.py, the examples) call this before their first compile; no
+    benchmarks/run.py, the examples) call this before their first compile; no
     other code of the tree sets the cache directory."""
     import jax  # lazily: host-engine users of the package never load it
 

@@ -21,10 +21,10 @@ class CodecDecodeError(DecodeError, ValueError):
 
 
 class ConfigError(LoroError, ValueError):
-    """Invalid tuning-knob environment value (RANK_ALGO, PALLAS_RANK_ALGO,
-    PLACE_ALGO, PALLAS_RULING_K, RANK_BLOCK, ...), raised at FIRST USE
-    (trace time) with the accepted values/range spelled out — never a
-    silent fall-back to the default algorithm.
+    """Invalid configuration value (the LORO_NET_* and shard-count
+    environment knobs, a chaos plan's fields, the sharded rank's
+    ``algo``), raised at FIRST USE with the accepted values/range
+    spelled out — never a silent fall-back to a default.
 
     Subclasses ValueError so pre-existing ``except ValueError`` guards
     (and tests) keep working.

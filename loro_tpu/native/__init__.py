@@ -7,7 +7,7 @@ tree, say — has another name and is never loaded.  Library users fall
 back gracefully: every caller handles ``available() == False``
 (pure-Python paths exist for everything — the native decoder is the
 throughput path for fleet decode, reference-parity with loro's Rust
-block decode).  Measurement paths (bench.py, chip_smoke.py) call
+block decode).  Measurement paths (benchmarks/, chip_smoke.py) call
 ``require()`` instead, which makes a failed build an error.
 """
 from __future__ import annotations
@@ -248,7 +248,7 @@ def available() -> bool:
 
 def require() -> None:
     """Raise unless the native decoder is built and loaded.  For paths
-    whose numbers or checks are about the native decode (bench.py,
+    whose numbers or checks are about the native decode (benchmarks/,
     chip_smoke.py): there a Python fallback would be a different
     program, so a failed build is an error and says why."""
     if _load() is None:

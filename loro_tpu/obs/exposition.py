@@ -7,8 +7,8 @@ snapshot, the bench sidecar object, and an optional scrape server.
   ``_count`` series, uniques export as gauges.
 - ``snapshot_json()`` — the registry snapshot as a JSON string (the
   same dict ``metrics.snapshot()`` returns; report.py renders either).
-- ``sidecar()`` — the compact flat dict bench.py embeds in its one
-  JSON output line: counters/gauges/uniques as plain numbers (bare
+- ``sidecar()`` — a compact flat dict for one-line JSON records:
+  counters/gauges/uniques as plain numbers (bare
   name = cross-label total, ``name{k=v}`` per label set), histograms
   as ``{count, sum, mean, p50, p99}`` summaries.
 - ``serve(port)`` — a daemon-thread HTTP server exposing ``/metrics``
@@ -83,10 +83,8 @@ def snapshot_json(registry: Optional[_m.Registry] = None, indent: Optional[int] 
 
 
 def sidecar(registry: Optional[_m.Registry] = None) -> dict:
-    """Flat metrics object for one-line JSON records (bench.py).  Keys
-    are metric names; labeled counters additionally emit per-label-set
-    entries so BENCH_r*.json trajectories can diff e.g. pad waste per
-    family across rounds."""
+    """Flat metrics object for one-line JSON records.  Keys are metric
+    names; labeled counters additionally emit per-label-set entries."""
     reg = registry or _m.registry()
     out: dict = {}
     for m in reg.metrics():

@@ -125,48 +125,10 @@ def doc_batch_jit(fn):
     return entry
 
 
-RANK_ALGOS = ("wyllie", "ruling", "blocked", "coalesced")
-
-
 # Device stages carry a ``jax.named_scope`` at their single dispatch
 # point — ring, rank, compact, place, unpack, checksum — so the profile's
 # device ops keep a stage name whatever XLA numbers its fusions
 # (metadata only: no operation, shape or fusion changes).
-
-
-def _rank_algo() -> str:
-    """XLA ranking algorithm (RANK_ALGO): "wyllie" (default), "ruling"
-    (two-level ruling-set; ~2x fewer gather rows in expectation),
-    "blocked" (phase-A block-local doubling + phase-B weighted ruling
-    over the exit graph) or "coalesced" (run-coalesce the ring, rank
-    the contracted super-node ring, expand by cumsum/scatter).  Read at
-    TRACE time: set it before the first merge call of the process
-    (already-jitted kernels do not retrace on env changes)."""
-    from ..errors import ConfigError
-
-    algo = os.environ.get("RANK_ALGO", "wyllie")
-    if algo not in RANK_ALGOS:
-        raise ConfigError("RANK_ALGO", algo, "|".join(RANK_ALGOS))
-    return algo
-
-
-def _rank_block() -> int:
-    """Block size (tokens) for the blocked two-level rank (RANK_BLOCK,
-    default 1024): phase A ranks inside blocks of this many tokens with
-    block-local gathers only.  Power of two, multiple of 128, in
-    [128, 65536] (the 128-lane alignment the pallas twin needs)."""
-    from ..errors import ConfigError
-
-    raw = os.environ.get("RANK_BLOCK", "1024")
-    try:
-        b = int(raw)
-    except ValueError:
-        b = -1
-    if not (128 <= b <= 65536) or (b & (b - 1)) != 0:
-        raise ConfigError(
-            "RANK_BLOCK", raw, "a power of two in [128, 65536]"
-        )
-    return b
 
 
 def _double(T: jax.Array, n_steps: int) -> jax.Array:
@@ -295,228 +257,6 @@ def make_ring_rank_sharded(mesh, m: int, algo: str = "wyllie"):
     )
 
 
-def _ruling_dist(succ: jax.Array, k: int = 8) -> jax.Array:
-    """Distance-to-terminal via a two-level ruling set.
-
-    Rulers are the statically-chosen token indices i % k == 0 (so the
-    dense ruler ring has a static size m//k + 1 with no compaction
-    sort).  Phase 1 doubles pointers that STOP at rulers/terminals —
-    adaptive rounds, ~log2(k·ln m) on ring orders without adversarial
-    ruler gaps, never more than the plain-Wyllie round count.  Phase 2
-    runs weighted pointer doubling on the dense ruler ring (m/k rows).
-    Phase 3 recombines with one gather.  Exact same output as
-    _wyllie_dist (self-loops are terminals; unreachable pads self-loop
-    and keep dist 0)."""
-    m = succ.shape[0]
-    tok = jnp.arange(m, dtype=jnp.int32)
-    d0 = jnp.where(succ == tok, 0, 1).astype(jnp.int32)
-    return _ruling_dist_from(d0, succ, k=k)
-
-
-def _ruling_dist_from(d0: jax.Array, t0: jax.Array, k: int = 8) -> jax.Array:
-    """Ruling-set ranking from a generic WEIGHTED pointer state:
-    dist(i) = d0[i] + dist(t0[i]), terminal nodes are self-loops with
-    d0 == 0.  This is the ruling machinery the blocked and coalesced
-    paths compose with (their phase-A / contraction output is exactly
-    such a weighted state); _ruling_dist is the unit-weight wrapper.
-    The phase-1 round cap stays exact for arbitrary states: after
-    ceil(log2(m)) doublings every pointer rests on a terminal."""
-    m = t0.shape[0]
-    tok = jnp.arange(m, dtype=jnp.int32)
-    succ = t0
-    is_term = succ == tok
-    is_ruler = (tok % k) == 0
-    is_stop = is_ruler | is_term
-
-    T0 = jnp.stack([d0.astype(jnp.int32), succ], axis=1)  # (dist, target)
-    frozen0 = is_term | is_stop[succ]
-    max_rounds = max(1, int(np.ceil(np.log2(max(m, 2)))))
-
-    def cond(carry):
-        i, T, frozen = carry
-        return (i < max_rounds) & ~frozen.all()
-
-    def body(carry):
-        i, T, frozen = carry
-        g = jnp.take(T, T[:, 1], axis=0)  # (d[t], t[t]) in one row gather
-        d = jnp.where(frozen, T[:, 0], T[:, 0] + g[:, 0])
-        t = jnp.where(frozen, T[:, 1], g[:, 1])
-        return i + 1, jnp.stack([d, t], axis=1), is_term | is_stop[t]
-
-    _, T, _ = jax.lax.while_loop(cond, body, (jnp.int32(0), T0, frozen0))
-    d1, t1 = T[:, 0], T[:, 1]
-
-    # dense ruler ring: slot r <-> token r*k; slot mr = terminal sink
-    mr = (m + k - 1) // k
-
-    def dense(t):
-        # frozen targets are rulers or terminals; terminals sink to mr
-        return jnp.where(is_term[t], mr, t // k).astype(jnp.int32)
-
-    # (terminal rulers already have d1 == 0 and dense(t1) == mr from
-    # phase 1, so no special-casing here)
-    r_tok = jnp.arange(mr, dtype=jnp.int32) * k  # (mr-1)*k <= m-1 always
-    rD0 = d1[r_tok]
-    rT0 = dense(t1[r_tok])
-    R = jnp.stack(
-        [jnp.append(rD0, jnp.int32(0)), jnp.append(rT0, jnp.int32(mr))], axis=1
-    )  # [mr+1, 2]
-    R = _double(R, max(1, int(np.ceil(np.log2(max(mr + 1, 2))))))
-    return d1 + R[:, 0][dense(t1)]
-
-
-def _blocked_dist(succ: jax.Array, block: Optional[int] = None) -> jax.Array:
-    """Blocked two-level ranking (the XLA twin of the pallas blocked
-    kernel; RANK_ALGO=blocked).
-
-    Phase A collapses every in-block pointer chain by doubling that
-    FREEZES at block exits: a pointer composes with its target only
-    while the target sits in the same `block`-token block, so every
-    gather is a within-block take_along_axis on the [n_blocks, block]
-    reshape (contiguous block-local rows — never a random full-ring
-    HBM gather).  After ceil(log2(block)) rounds each token holds
-    (d, t) with t its first out-of-block stop or an in-block terminal.
-
-    Phase B ranks the resulting weighted exit graph with the ruling-set
-    machinery (_ruling_dist_from); its round cap keeps the result exact
-    on rings with no block locality (the exit graph then is nearly the
-    original ring).  O(n log b) block-local + O(adaptive·n + (n/k)
-    log(n/k)) global gather rows vs O(n log n) global for Wyllie."""
-    m = succ.shape[0]
-    b = block if block is not None else _rank_block()
-    # clamp the block to the lane-padded ring: a block bigger than the
-    # ring only inflates the [nb, b] pad that phase B then pays for
-    b = min(b, max(128, -(-m // 128) * 128))
-    mp = -(-m // b) * b
-    if mp != m:
-        pad_ids = jnp.arange(m, mp, dtype=jnp.int32)
-        succ = jnp.concatenate([succ.astype(jnp.int32), pad_ids])
-    nb = mp // b
-    tok2 = jnp.arange(mp, dtype=jnp.int32).reshape(nb, b)
-    base = (jnp.arange(nb, dtype=jnp.int32) * b)[:, None]
-    T = succ.reshape(nb, b)
-    D = jnp.where(T == tok2, 0, 1).astype(jnp.int32)
-    n_a = max(1, int(np.ceil(np.log2(max(b, 2)))))
-
-    def body(_, carry):
-        D, T = carry
-        lt = T - base
-        in_blk = (lt >= 0) & (lt < b)
-        active = in_blk & (T != tok2)
-        lt = jnp.clip(lt, 0, b - 1)
-        gd = jnp.take_along_axis(D, lt, axis=1)
-        gt = jnp.take_along_axis(T, lt, axis=1)
-        return jnp.where(active, D + gd, D), jnp.where(active, gt, T)
-
-    D, T = jax.lax.fori_loop(0, n_a, body, (D, T))
-    return _ruling_dist_from(D.reshape(mp), T.reshape(mp))[:m]
-
-
-def ring_run_heads(succ: jax.Array) -> Tuple[jax.Array, jax.Array]:
-    """(is_head bool[m], n_runs): maximal index-consecutive successor
-    runs.  Token j is absorbed into its predecessor's run iff
-    succ[j-1] == j, j is j's ONLY predecessor, and j is not a terminal
-    self-loop — which guarantees (a) runs are index intervals and (b) a
-    run tail's successor is always some run's head, so the contracted
-    super-node ring is well formed.  The slot-numbered Euler ring
-    (_order_core) is laid out so that real traces produce long runs
-    here (leaf ENTER->EXIT pairs, sibling groups, chained pads)."""
-    m = succ.shape[0]
-    tok = jnp.arange(m, dtype=jnp.int32)
-    indeg = jnp.zeros(m, jnp.int32).at[succ].add(1)
-    is_term = succ == tok
-    absorbed = (
-        jnp.concatenate([jnp.zeros(1, bool), succ[:-1] == tok[1:]])
-        & (indeg == 1)
-        & ~is_term
-    )
-    is_head = ~absorbed
-    return is_head, is_head.sum().astype(jnp.int32)
-
-
-def _coalesced_dist(
-    succ: jax.Array,
-    r_pad: Optional[int] = None,
-    use_pallas: bool = False,
-) -> jax.Array:
-    """Run-coalesced ranking (RANK_ALGO=coalesced): contract maximal
-    successor runs into super-nodes, rank the contracted ring (weighted
-    ruling set, or the weighted pallas kernel when use_pallas), then
-    expand ranks back to tokens with one scatter + one cumsum — no
-    per-token gather.
-
-    `r_pad` is the STATIC contracted-ring budget.  The default (r_pad =
-    m, rounded to lanes) is always safe (n_runs <= m) but saves only
-    round count; callers that know their ring statistics (bench does,
-    via rank_model.ring_stats) pass a tight budget for the full
-    gather-row reduction.  OVERFLOW IS NOT DETECTED HERE: with
-    r_pad < n_runs the result is garbage — callers passing a tight
-    budget own the check (ring_run_heads / host ring_stats), exactly
-    like the c_pad/n_chains contract of chain_contract_materialize_u."""
-    m = succ.shape[0]
-    r = r_pad if r_pad is not None else m
-    r = max(128, -(-r // 128) * 128)
-    tok = jnp.arange(m, dtype=jnp.int32)
-    is_head, n_runs = ring_run_heads(succ)
-    run_id = jnp.cumsum(is_head.astype(jnp.int32)) - 1  # token -> run
-    # compact head/tail token tables [r] (+ sink slot r for the ruling
-    # sub-rank: terminal runs edge to it, matching its dense-ring idiom)
-    rid_clip = jnp.where(is_head, jnp.minimum(run_id, r), r)
-    head_tok = (
-        jnp.full(r + 1, 0, jnp.int32).at[rid_clip].set(tok, mode="drop")[:r]
-    )
-    ridx = jnp.arange(r, dtype=jnp.int32)
-    valid_run = ridx < n_runs
-    nxt_head = jnp.concatenate([head_tok[1:], jnp.array([m], jnp.int32)])
-    tail_tok = jnp.where(ridx + 1 < n_runs, nxt_head, m) - 1
-    tail_tok = jnp.where(valid_run, tail_tok, head_tok)
-    succ_tail = succ[jnp.clip(tail_tok, 0, m - 1)]
-    is_term_run = succ_tail == tail_tok
-    w = jnp.where(
-        valid_run,
-        (tail_tok - head_tok) + jnp.where(is_term_run, 0, 1),
-        0,
-    ).astype(jnp.int32)
-    t = jnp.where(
-        valid_run & ~is_term_run,
-        run_id[jnp.clip(succ_tail, 0, m - 1)],
-        jnp.where(valid_run, r, ridx),  # terminal runs -> sink; pads self
-    ).astype(jnp.int32)
-    w1 = jnp.concatenate([w, jnp.zeros(1, jnp.int32)])
-    t1 = jnp.concatenate([t, jnp.array([r], jnp.int32)])  # sink self-loop
-    if use_pallas:
-        from .pallas_rank import PALLAS_RANK_MAX_M, _LANES, wyllie_rank
-
-        # contracted ring is r+1 tokens (sink slot): lane-pad must stay
-        # within the VMEM cap (the default budget r = round128(m) makes
-        # r+1 overflow it for m at the cap itself) — fall back to the
-        # XLA weighted ruling rather than raise for a ring the
-        # applicability gate approved
-        if -(-(r + 1) // _LANES) * _LANES > PALLAS_RANK_MAX_M:
-            use_pallas = False
-    if use_pallas:
-        # dist_bound = m: contracted distances are pre-contraction step
-        # counts, so a short super-node ring from a long ring must still
-        # take the wide (i32) kernel
-        D = wyllie_rank(t1, weights=w1, dist_bound=m)[:r]
-    else:
-        D = _ruling_dist_from(w1, t1)[:r]
-    # expansion: dist[tok] = D[run] - (tok - head_tok[run]); runs are
-    # index intervals with ascending ids, so one telescoped scatter at
-    # head tokens + a cumsum reconstructs D[run] + head_tok[run] per
-    # token exactly (int32 wraparound-safe, same trick as
-    # _place_by_chain_sort) — no per-token gather.
-    val = jnp.where(valid_run, D + head_tok, 0)
-    prev = jnp.concatenate([jnp.zeros(1, jnp.int32), val[:-1]])
-    delta = jnp.where(valid_run, val - prev, 0)
-    seg = (
-        jnp.zeros(m + 1, jnp.int32)
-        .at[jnp.where(valid_run, head_tok, m)]
-        .add(delta, mode="drop")[:m]
-    )
-    return jnp.cumsum(seg) - tok
-
-
 def fugue_order(cols: SeqColumns) -> jax.Array:
     """Return rank i32[N]: a key whose ascending order is the in-order
     position of each element in the Fugue traversal (keys may have gaps;
@@ -530,73 +270,28 @@ def fugue_order(cols: SeqColumns) -> jax.Array:
     return _order_core(cols.parent, cols.side, cols.valid)
 
 
-def _resolve_rank_spec(rank_impl: Optional[str], m: int) -> Tuple[str, str]:
-    """(backend, algo) for a ring of m tokens.  `rank_impl` accepts the
-    legacy "pallas" / "xla" (algo from the PALLAS_RANK_ALGO / RANK_ALGO
-    env) plus explicit "<backend>:<algo>" specs — phased bench runs and
-    differential tests need several algorithms jitted in ONE process,
-    and env knobs bake at trace time.  Precedence with rank_impl=None
-    (auto): pallas when applicable and the XLA algo knob is untouched
-    (an explicit RANK_ALGO keeps algo comparisons honest), but an
-    explicit PALLAS_RANK=1 beats everything."""
-    from ..errors import ConfigError
-    from .pallas_rank import PALLAS_RANK_ALGOS, pallas_rank_applicable
+def _resolve_rank_spec(rank_impl: None, m: int) -> Tuple[str, str]:
+    """(backend, algo) for a ring of m tokens, from the platform and the
+    ring's length alone: the Pallas kernels on a TPU while the ring fits
+    VMEM (``pallas_rank_applicable``), else the XLA pointer doubling.
+    ``rank_impl`` is always None: benchmarks/drivers/import_packed.py
+    passes it, and a PR that may edit the benchmark drops it there."""
+    from .pallas_rank import pallas_rank_applicable
 
-    if rank_impl is not None and ":" in rank_impl:
-        backend, algo = rank_impl.split(":", 1)
-        ok = (backend == "xla" and algo in RANK_ALGOS) or (
-            backend == "pallas" and algo in PALLAS_RANK_ALGOS + ("coalesced",)
-        )
-        if not ok:
-            raise ValueError(
-                f"rank_impl spec must be xla:{{{'|'.join(RANK_ALGOS)}}} or "
-                f"pallas:{{{'|'.join(PALLAS_RANK_ALGOS + ('coalesced',))}}}, "
-                f"got {rank_impl!r}"
-            )
-        return backend, algo
-    if rank_impl == "pallas":
-        from .pallas_rank import _pallas_rank_algo
-
-        return "pallas", _pallas_rank_algo()
-    if rank_impl == "xla":
-        return "xla", _rank_algo()
     if rank_impl is not None:
-        raise ValueError(
-            f"rank_impl must be pallas|xla|<backend>:<algo>|None, got {rank_impl!r}"
-        )
-    algo = _rank_algo()
-    explicit_pallas = os.environ.get("PALLAS_RANK", "") not in ("", "0")
-    if pallas_rank_applicable(m) and (algo == "wyllie" or explicit_pallas):
-        if algo == "coalesced":
-            # coalesced + PALLAS_RANK=1: pallas sub-rank of the
-            # contracted ring
-            return "pallas", "coalesced"
-        # the pallas kernel's own algo knob picks the kernel variant
-        from .pallas_rank import _pallas_rank_algo
-
-        return "pallas", _pallas_rank_algo()
-    return "xla", algo
+        raise ValueError(f"rank_impl must be None, got {rank_impl!r}")
+    return ("pallas", "ruling") if pallas_rank_applicable(m) else ("xla", "wyllie")
 
 
 @jax.named_scope("rank")
-def _rank_dist(
-    succ: jax.Array,
-    backend: str,
-    algo: str,
-    ring_budget: Optional[int] = None,
-) -> jax.Array:
-    """Distance-to-terminal of a successor ring under a resolved
-    (backend, algo) spec — the single ranking dispatch point."""
-    if algo == "coalesced":
-        return _coalesced_dist(succ, ring_budget, use_pallas=backend == "pallas")
+def _rank_dist(succ: jax.Array) -> jax.Array:
+    """Distance-to-terminal of a successor ring — the single ranking
+    dispatch point."""
+    backend, _ = _resolve_rank_spec(None, int(succ.shape[0]))
     if backend == "pallas":
         from .pallas_rank import wyllie_rank
 
-        return wyllie_rank(succ, algo=algo)
-    if algo == "ruling":
-        return _ruling_dist(succ)
-    if algo == "blocked":
-        return _blocked_dist(succ)
+        return wyllie_rank(succ)
     return _wyllie_dist(succ)
 
 
@@ -675,9 +370,8 @@ def _ring_and_anchors(
     # traces then put consecutive ring steps at consecutive token
     # indices (a leaf run ENTER(c1)..EXIT(ck) walks slots s, s+1, ...
     # on the way in and mirrored indices on the way out; invalid
-    # elements all sort into one contiguous slot range and chain below)
-    # — exactly the index-adjacency ring_run_heads contracts.  Any
-    # bijective numbering yields the same ORDER (ranks are compared,
+    # elements all sort into one contiguous slot range and chain below).
+    # Any bijective numbering yields the same ORDER (ranks are compared,
     # never interpreted), so correctness is layout-free.
     m = 2 * n1
     slot = jnp.zeros(n1, jnp.int32).at[order].set(jnp.arange(n1, dtype=jnp.int32))
@@ -699,8 +393,8 @@ def _ring_and_anchors(
         [succ_enter[order], jnp.flip(succ_exit[order])]
     ).astype(jnp.int32)
 
-    # invalid elements: chain their tokens by index (one coalescable
-    # run per contiguous range instead of per-token self-loops; their
+    # invalid elements: chain their tokens by index (one run per
+    # contiguous range instead of per-token self-loops; their
     # distances are never read — ranks of invalid rows are overwritten
     # below).  The ring-proper tokens keep their successors.
     tok_valid = jnp.concatenate([valid[order], jnp.flip(valid[order])])
@@ -724,8 +418,6 @@ def _order_core(
     side_in: jax.Array,
     valid_in: jax.Array,
     sib_keys: Optional[Tuple[jax.Array, ...]] = None,
-    rank_impl: Optional[str] = None,
-    ring_budget: Optional[int] = None,
 ) -> jax.Array:
     """Euler-tour in-order ranking over generic node arrays (element- or
     chain-level).  Without `sib_keys`, rows must obey the (peer, counter)
@@ -739,8 +431,7 @@ def _order_core(
     succ, anchor = _ring_and_anchors(parent_in, side_in, valid_in, sib_keys)
 
     # -- list ranking: distance to terminal ---------------------------
-    backend, algo = _resolve_rank_spec(rank_impl, int(succ.shape[0]))
-    dist = _rank_dist(succ, backend, algo, ring_budget)
+    dist = _rank_dist(succ)
 
     anchor_dist = dist[anchor]
     rank = anchor_dist[root] - anchor_dist[:n]  # monotone along the traversal
@@ -844,36 +535,6 @@ class ChainColumns(NamedTuple):
     valid: jax.Array  # bool[N]
 
 
-def _place_algo() -> str:
-    """Element placement: "sort" (default — one stable sort; measured
-    ~2x the scatter formulation on v5e, where random HBM access costs
-    ~100M rows/s but a [8, 188k] sort is ~10 ms) or "scatter" (the
-    histogram + gather + positional-scatter formulation).  Read at
-    TRACE time: set it before the first merge call of the process
-    (already-jitted kernels do not retrace on env changes)."""
-    from ..errors import ConfigError
-
-    algo = os.environ.get("PLACE_ALGO", "sort")
-    if algo not in ("sort", "scatter"):
-        raise ConfigError("PLACE_ALGO", algo, "sort|scatter")
-    return algo
-
-
-@jax.named_scope("place")
-def _place_by_chain(
-    crank: jax.Array,
-    c_valid: jax.Array,
-    chain_id: jax.Array,
-    head_row: jax.Array,
-    visible: jax.Array,
-    content: jax.Array,
-) -> Tuple[jax.Array, jax.Array]:
-    """Shared element placement for both chain paths (PLACE_ALGO)."""
-    if _place_algo() == "sort":
-        return _place_by_chain_sort(crank, c_valid, head_row, visible, content)
-    return _place_by_chain_scatter(crank, c_valid, chain_id, head_row, visible, content)
-
-
 def chain_positions(
     crank: jax.Array,
     c_valid: jax.Array,
@@ -925,6 +586,7 @@ def _place_by_chain_scatter(
     return codes, count
 
 
+@jax.named_scope("place")
 def _place_by_chain_sort(
     crank: jax.Array,
     c_valid: jax.Array,
@@ -963,78 +625,31 @@ def _place_by_chain_sort(
     return codes, count
 
 
-def chain_materialize(
-    cols: ChainColumns,
-    rank_impl: Optional[str] = None,
-    ring_budget: Optional[int] = None,
-) -> Tuple[jax.Array, jax.Array]:
+def chain_materialize(cols: ChainColumns) -> Tuple[jax.Array, jax.Array]:
     """Merge via chain contraction: rank C chains (C << N), then place
-    all N elements via _place_by_chain (default: rank expansion by
-    C-scatter + N-cumsum, then one stable N-row sort; PLACE_ALGO=scatter
-    selects the histogram + gather + positional-scatter formulation) —
-    the gather-heavy ranking runs on the contracted tree only.
-    `ring_budget` is the static coalesced-ring budget (see
-    _coalesced_dist: callers passing a tight budget own the n_runs
-    check; None is always safe).
+    all N elements by rank expansion (C-scatter + N-cumsum) and one
+    stable N-row sort (_place_by_chain_sort) — the gather-heavy ranking
+    runs on the contracted tree only.
     Returns (codes i32[N] padded with -1, visible count)."""
-    c = cols.c_parent.shape[0]
-    crank = _order_core(
-        cols.c_parent,
-        cols.c_side,
-        cols.c_valid,
-        rank_impl=rank_impl,
-        ring_budget=ring_budget,
-    )  # i32[C]
+    crank = _order_core(cols.c_parent, cols.c_side, cols.c_valid)  # i32[C]
     visible = cols.valid & ~cols.deleted
-    chain_id = jnp.where(cols.valid, cols.chain_id, c)
-    return _place_by_chain(
-        crank, cols.c_valid, chain_id, cols.head_row, visible, cols.content
+    return _place_by_chain_sort(
+        crank, cols.c_valid, cols.head_row, visible, cols.content
     )
 
 
 chain_materialize_batch = jax.vmap(chain_materialize)
 
 
-def _tick_rank_obs(
-    n_docs: int,
-    n_nodes: int,
-    rank_impl: Optional[str],
-    ring_budget: Optional[int] = None,
-) -> None:
-    """rank.* obs counters (docs/OBSERVABILITY.md) from the analytic
-    gather model — ticked at host-level jit entry points only (inside a
-    trace the counts would be trace-time noise), with the caller's
-    ring_budget and the live k/block knob values threaded through so
-    budgeted/tuned runs are priced as scheduled.  Never raises: the
-    merge path must not depend on the obs package."""
-    try:
-        m = 2 * (n_nodes + 1)
-        backend, algo = _resolve_rank_spec(rank_impl, m)
-        from ..obs import metrics as obs_m
+def _tick_rank_obs(n_docs: int, n_nodes: int) -> None:
+    """rank.ring_tokens{algo} (docs/OBSERVABILITY.md) — ticked at
+    host-level jit entry points only (inside a trace the count would be
+    trace-time noise)."""
+    from ..obs import metrics as obs_m
 
-        from .rank_model import gather_model
-
-        kw = {}
-        if algo == "coalesced":
-            kw["r_pad"] = ring_budget
-        if algo == "blocked":
-            kw["block"] = _rank_block()
-        if backend == "pallas" and algo in ("ruling", "blocked", "coalesced"):
-            # coalesced's pallas sub-rank rides the same kernel knob
-            kw["k"] = int(os.environ.get("PALLAS_RULING_K", "8"))
-        mdl = gather_model(m, algo, **kw)
-        label = f"{backend}:{algo}"
-        obs_m.counter("rank.ring_tokens").inc(n_docs * m, algo=label)
-        obs_m.counter("rank.rounds_total").inc(n_docs * mdl["rounds"], algo=label)
-        obs_m.counter("rank.gather_rows_total").inc(
-            n_docs * mdl["global_rows"], algo=label, kind="global"
-        )
-        if mdl.get("local_rows"):
-            obs_m.counter("rank.gather_rows_total").inc(
-                n_docs * mdl["local_rows"], algo=label, kind="local"
-            )
-    except Exception:  # tpulint: disable=LT-EXC(gather-ledger metrics are an estimate; accounting must never break the merge)
-        pass
+    m = rank_bound(n_nodes)
+    label = ":".join(_resolve_rank_spec(None, m))
+    obs_m.counter("rank.ring_tokens").inc(n_docs * m, algo=label)
 
 
 @doc_batch_jit
@@ -1044,7 +659,7 @@ def _chain_merge_docs_jit(cols: ChainColumns) -> Tuple[jax.Array, jax.Array]:
 
 def chain_merge_docs(cols: ChainColumns) -> Tuple[jax.Array, jax.Array]:
     """One launch: chain-contracted merge for a doc batch ([D,C]/[D,N])."""
-    _tick_rank_obs(cols.c_parent.shape[0], cols.c_parent.shape[1], None)
+    _tick_rank_obs(cols.c_parent.shape[0], cols.c_parent.shape[1])
     return _chain_merge_docs_jit(cols)
 
 
@@ -1065,83 +680,8 @@ def _chain_merge_docs_checksum_jit(cols: ChainColumns) -> Tuple[jax.Array, jax.A
 
 
 def chain_merge_docs_checksum(cols: ChainColumns) -> Tuple[jax.Array, jax.Array]:
-    _tick_rank_obs(cols.c_parent.shape[0], cols.c_parent.shape[1], None)
+    _tick_rank_obs(cols.c_parent.shape[0], cols.c_parent.shape[1])
     return _chain_merge_docs_checksum_jit(cols)
-
-
-@doc_batch_jit
-def _chain_merge_docs_v_jit(
-    cols: ChainColumns,
-    rank_impl: Optional[str] = None,
-    ring_budget: Optional[int] = None,
-) -> Tuple[jax.Array, jax.Array]:
-    return jax.vmap(lambda c: chain_materialize(c, rank_impl, ring_budget))(cols)
-
-
-def chain_merge_docs_v(
-    cols: ChainColumns,
-    rank_impl: Optional[str] = None,
-    ring_budget: Optional[int] = None,
-) -> Tuple[jax.Array, jax.Array]:
-    """chain_merge_docs with an explicit ranking implementation —
-    phased bench runs measure several rank paths inside ONE process
-    (env knobs bake at trace time, so this must be a static argument).
-    `rank_impl` accepts "xla" / "pallas" or explicit "<backend>:<algo>"
-    specs (e.g. "xla:coalesced"); `ring_budget` is the static
-    coalesced-ring budget (caller-checked, see _coalesced_dist)."""
-    _tick_rank_obs(cols.c_parent.shape[0], cols.c_parent.shape[1], rank_impl, ring_budget)
-    return _chain_merge_docs_v_jit(cols, rank_impl, ring_budget)
-
-
-@doc_batch_jit
-def _chain_merge_docs_checksum_v_jit(
-    cols: ChainColumns,
-    rank_impl: Optional[str] = None,
-    ring_budget: Optional[int] = None,
-) -> Tuple[jax.Array, jax.Array]:
-    codes, counts = jax.vmap(lambda c: chain_materialize(c, rank_impl, ring_budget))(
-        cols
-    )
-    return _weighted_checksum(codes), counts
-
-
-def chain_merge_docs_checksum_v(
-    cols: ChainColumns,
-    rank_impl: Optional[str] = None,
-    ring_budget: Optional[int] = None,
-) -> Tuple[jax.Array, jax.Array]:
-    _tick_rank_obs(cols.c_parent.shape[0], cols.c_parent.shape[1], rank_impl, ring_budget)
-    return _chain_merge_docs_checksum_v_jit(cols, rank_impl, ring_budget)
-
-
-@doc_batch_jit
-def _chain_rank_checksum_v_jit(
-    cols: ChainColumns,
-    rank_impl: Optional[str] = None,
-    ring_budget: Optional[int] = None,
-) -> jax.Array:
-    def one(c: ChainColumns) -> jax.Array:
-        crank = _order_core(
-            c.c_parent,
-            c.c_side,
-            c.c_valid,
-            rank_impl=rank_impl,
-            ring_budget=ring_budget,
-        )
-        return crank.astype(jnp.uint32).sum(dtype=jnp.uint32)
-
-    return jax.vmap(one)(cols)
-
-
-def chain_rank_checksum_v(
-    cols: ChainColumns,
-    rank_impl: Optional[str] = None,
-    ring_budget: Optional[int] = None,
-) -> jax.Array:
-    """Ranking phase ONLY (scalar-reduced for cheap fetches): the
-    measured-roofline bench phase times this against the full merge to
-    split rank vs placement cost on chip."""
-    return _chain_rank_checksum_v_jit(cols, rank_impl, ring_budget)
 
 
 # ---- packed single-buffer transport (ingest pipeline) ----------------
@@ -1248,8 +788,8 @@ def merge_text_payloads_packed(
     n_docs: int,
     budget_s: float = float("inf"),
 ):
-    """The end-to-end bulk-import pipeline (bench.py's e2e phase and
-    chip_smoke.py's flagship step): per document, native payload decode
+    """The end-to-end bulk-import pipeline (the benchmark's packed64
+    cell and chip_smoke.py's flagship step): per document, native payload decode
     -> chain contraction -> packed u8 row on a pool of decode threads
     (the native explode releases the GIL, so decodes overlap each other
     AND the asynchronous device merges); per ``chunk`` documents one
@@ -1416,8 +956,8 @@ def chain_contract_materialize_u(
     )  # [c_pad]
 
     visible = valid & ~cols.deleted & (cols.content >= 0)
-    codes, count = _place_by_chain(
-        crank, c_valid, chain_id, head_row, visible, cols.content
+    codes, count = _place_by_chain_sort(
+        crank, c_valid, head_row, visible, cols.content
     )
     return codes, count, n_chains
 
@@ -1428,7 +968,7 @@ def _chain_merge_docs_u_jit(cols: SeqColumnsU, c_pad: int):
 
 
 def chain_merge_docs_u(cols: SeqColumnsU, c_pad: int):
-    _tick_rank_obs(cols.parent.shape[0], c_pad, None)
+    _tick_rank_obs(cols.parent.shape[0], c_pad)
     return _chain_merge_docs_u_jit(cols, c_pad)
 
 
@@ -1504,7 +1044,7 @@ def _merge_docs_jit(cols: SeqColumns) -> Tuple[jax.Array, jax.Array]:
 def merge_docs(cols: SeqColumns) -> Tuple[jax.Array, jax.Array]:
     """One XLA launch: resolve order + materialize visible content for a
     whole batch of documents.  cols arrays are [D, N]."""
-    _tick_rank_obs(cols.parent.shape[0], cols.parent.shape[1], None)
+    _tick_rank_obs(cols.parent.shape[0], cols.parent.shape[1])
     return _merge_docs_jit(cols)
 
 
@@ -1523,5 +1063,5 @@ def merge_docs_checksum(cols: SeqColumns) -> Tuple[jax.Array, jax.Array]:
     """Merge but return only a per-doc order-sensitive checksum [D] +
     counts [D].  Used by benchmarks: the merged state stays device-
     resident (the fleet model); only O(D) scalars cross the host link."""
-    _tick_rank_obs(cols.parent.shape[0], cols.parent.shape[1], None)
+    _tick_rank_obs(cols.parent.shape[0], cols.parent.shape[1])
     return _merge_docs_checksum_jit(cols)

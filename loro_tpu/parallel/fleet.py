@@ -306,7 +306,7 @@ class Fleet:
                 (pad_n, pad_c, d_pad),
             )
             obs.counter("fleet.text_docs_total").inc(d, transport=transport)
-            _tick_rank_obs(d_pad, pad_c, None)
+            _tick_rank_obs(d_pad, pad_c)
             with tracing.span("fleet.stack"):
                 rows = _empty_text_batch(transport, d_pad, pad_c, pad_n)
             with tracing.span("fleet.pack"):

@@ -36,7 +36,7 @@ fsync and a round's epoch future resolves only after it — an acked
 round is never lost to a crash (``ResidentServer.durable_epoch``).
 
 Every outcome feeds the obs registry (``pipeline.*``) and ``report()``
-returns the compact dict bench.py banks as the ``pipeline`` sidecar.
+returns them as one compact dict.
 """
 from __future__ import annotations
 

@@ -18,8 +18,7 @@ Three pieces (docs/RESILIENCE.md has the full rules and rationale):
   differential-fuzz contract).
 
 All outcomes report through the ``obs`` registry (``resilience.*``,
-``faultinject.*``) and ``DeviceSupervisor.report()``
-feeds bench.py's ``resilience`` sidecar.
+``faultinject.*``) and ``DeviceSupervisor.report()``.
 """
 from __future__ import annotations
 

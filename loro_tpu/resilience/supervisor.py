@@ -32,7 +32,7 @@ untouched: they must reach the per-doc isolation logic or the caller's
 eyes, not the degradation logic.
 
 All outcomes feed the obs registry (``resilience.*`` metrics) and the
-``report()`` dict that bench.py banks as the ``resilience`` sidecar.
+``report()`` dict (chip_smoke.py and benchmarks/checks.py read it).
 """
 from __future__ import annotations
 
@@ -354,8 +354,7 @@ def get_supervisor() -> DeviceSupervisor:
 
 def set_supervisor(sup: Optional[DeviceSupervisor]) -> None:
     """Install a process-wide supervisor (None restores a fresh
-    default).  bench.py installs one with the run deadline; tests
-    install fake-clock instances."""
+    default).  Tests install fake-clock instances."""
     global _default
     with _default_lock:
         _default = sup

@@ -1,7 +1,7 @@
 """tpulint + lock witness (ISSUE 9): per-rule fixture snippets (one
 true positive and one clean snippet each), pragma/baseline behavior,
 the repo-wide tier-1 gate (zero unsuppressed findings over loro_tpu/ +
-bench.py), and the runtime lock-order witness — including the
+chip_smoke.py), and the runtime lock-order witness — including the
 deliberate-inversion test that proves the witness can fail."""
 import json
 import os
@@ -156,7 +156,7 @@ class TestRuleFixtures:
         assert rules_of(lint_source(ok, path="loro_tpu/sync/fixture.py")) == []
 
     @pytest.mark.parametrize("path", [
-        "loro_tpu/parallel/fixture.py", "bench.py", "chip_smoke.py",
+        "loro_tpu/parallel/fixture.py", "loro_tpu/ops/fixture.py", "chip_smoke.py",
     ])
     def test_chip_rule_flags_every_way_to_signal_a_process(self, path):
         bad = (
@@ -312,11 +312,11 @@ class TestBaseline:
 class TestRepoGate:
     def test_repo_is_lint_clean(self):
         """THE gate: zero unsuppressed findings over loro_tpu/ +
-        bench.py + chip_smoke.py, every suppression carrying a reason.
+        chip_smoke.py, every suppression carrying a reason.
         A new finding means: fix it, or pragma it with the reason a
         reviewer should read."""
         res = lint_paths(
-            [os.path.join(REPO, p) for p in ("loro_tpu", "bench.py", "chip_smoke.py")]
+            [os.path.join(REPO, p) for p in ("loro_tpu", "chip_smoke.py")]
         )
         assert res.active == [], "\n" + "\n".join(
             f.render() for f in res.active
@@ -332,7 +332,7 @@ class TestRepoGate:
         assert "analysis.suppressed_total" in side or \
             "analysis.findings_total" in side or side is not None
         # the suppression counter family exists after a repo lint
-        lint_paths([os.path.join(REPO, "bench.py")])
+        lint_paths([os.path.join(REPO, "chip_smoke.py")])
         assert "analysis.suppressed_total" in obs.sidecar()
 
     def test_errors_rooted_in_loro_error(self):

@@ -52,8 +52,8 @@ pytestmark = pytest.mark.xdist_group("chip_compile")
 HBM_BYTES = 16e9  # one v5e chip
 
 # the rings of the flagship step: the real automerge trace contracted to
-# pad_c 18,432 (m = 36,866, packed kernels); the seeded trace that
-# chip_smoke.py and bench.py generate contracts to pad_c 51,200
+# pad_c 18,432 (m = 36,866, the packed kernel); the seeded trace that
+# chip_smoke.py generates contracts to pad_c 51,200
 # (m = 102,402, past the 16-bit domain: the wide kernel)
 M_REAL, M_SEEDED = 36_866, 102_402
 PAD_C, PAD_N = 51_200, 237_568  # the seeded trace's flagship shapes
@@ -93,8 +93,6 @@ def tpu_branches(monkeypatch):
     then resolves to Pallas and the kernel leaves interpret mode, as on
     the chip."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    monkeypatch.delenv("PALLAS_RANK", raising=False)
-    monkeypatch.delenv("RANK_ALGO", raising=False)
 
 
 def compile_checked(name, lowered, expect_kernel):
@@ -147,33 +145,16 @@ def chain_sds(d, c, n, sh):
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("algo,m", [
-    ("ruling", M_REAL),    # packed ruling-set: the flagship default
-    ("wyllie", M_REAL),    # packed wyllie
-    ("blocked", M_REAL),   # blocked two-level (_vmem_gather_near)
-    ("ruling", M_SEEDED),  # 65,536 < m <= 131,072: the dual-table wide kernel
-    # small rings (DeviceDocBatch's solver starts at a 256-chain budget,
+@pytest.mark.parametrize("m", [
+    M_REAL,    # the packed ruling-set kernel: what the cells launch
+    M_SEEDED,  # 65,536 < m <= 131,072: the dual-table wide kernel
+    # a small ring (DeviceDocBatch's solver starts at a 256-chain budget,
     # m = 514): padded to two rows per table, which Mosaic needs
-    ("ruling", 514),
-    ("blocked", 514),
-    ("wyllie", 66),
+    514,
 ])
-def test_rank_kernel_compiles(one_chip, algo, m):
-    fn = jax.jit(jax.vmap(lambda s: wyllie_rank(s, interpret=False, algo=algo)))
-    compile_checked(f"wyllie_rank:{algo}:vmap8:m{m}",
-                    fn.lower(sds((8, m), jnp.int32, one_chip)), True)
-
-
-@pytest.mark.parametrize("m,r_pad", [
-    (M_REAL, 16_384),    # distances fit 16 bits: packed kernel, weighted
-    (M_SEEDED, 32_768),  # short contracted ring, dist_bound past u16: wide
-])
-def test_weighted_coalesced_entry_compiles(one_chip, tpu_branches, m, r_pad):
-    """fugue_batch._coalesced_dist(use_pallas=True): the contracted ring
-    ranked by ``wyllie_rank(weights=, dist_bound=m)`` — which picks its
-    own interpret mode, hence ``tpu_branches``."""
-    fn = jax.jit(jax.vmap(lambda s: fb._coalesced_dist(s, r_pad, use_pallas=True)))
-    compile_checked(f"coalesced:vmap8:m{m}:r{r_pad}",
+def test_rank_kernel_compiles(one_chip, m):
+    fn = jax.jit(jax.vmap(lambda s: wyllie_rank(s, interpret=False)))
+    compile_checked(f"wyllie_rank:vmap8:m{m}",
                     fn.lower(sds((8, m), jnp.int32, one_chip)), True)
 
 
@@ -183,8 +164,12 @@ def test_flagship_rank_half_compiles_with_the_kernel(one_chip, tpu_branches):
     sort over 51,200 chains) + the kernel.  The placement half is a
     237,568-row sort whose compile alone takes ~25 s: see the slow case."""
     assert fb._resolve_rank_spec(None, 2 * (PAD_C + 1)) == ("pallas", "ruling")
-    lowered = fb._chain_rank_checksum_v_jit.lower(
-        chain_sds(8, PAD_C, 8, one_chip), None, None)
+
+    def rank_half(c: fb.ChainColumns):
+        crank = fb._order_core(c.c_parent, c.c_side, c.c_valid)
+        return crank.astype(jnp.uint32).sum(dtype=jnp.uint32)
+
+    lowered = jax.jit(jax.vmap(rank_half)).lower(chain_sds(8, PAD_C, 8, one_chip))
     compile_checked(f"chain_rank_checksum:[8,c{PAD_C}]", lowered, True)
 
 
@@ -263,7 +248,7 @@ def test_flagship_step_compiles_at_real_width(one_chip, tpu_branches):
 @pytest.mark.parametrize("chains,elements,pads,transport", [
     # 16 B4-sized documents (the benchmark's): ring 65,536, packed rows
     (17_500, 182_315, (32_767, 262_144), "packed"),
-    # the seeded trace of chip_smoke.py / bench.py: ~51,000 chains, a
+    # the seeded trace of chip_smoke.py: ~51,000 chains, a
     # chain bucket past 16-bit ids, ring 131,072 = PALLAS_RANK_MAX_M
     (51_000, 233_894, (65_535, 262_144), "chains"),
 ])

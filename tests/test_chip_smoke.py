@@ -57,6 +57,14 @@ def events():
     return chip_smoke.CompileEvents()  # listeners stay for the process
 
 
+def interpreted_kernel(monkeypatch):
+    """The rank rule answers as on the chip; off the chip the kernel it
+    picks runs interpreted."""
+    from loro_tpu.ops import pallas_rank
+
+    monkeypatch.setattr(pallas_rank, "use_pallas_rank", lambda: True)
+
+
 # ---------------------------------------------------------------------------
 # the phases, tiny
 # ---------------------------------------------------------------------------
@@ -86,7 +94,7 @@ def test_serve_phase_notices_a_wrong_document(workload, one_device_mesh, tmp_pat
 
 
 def test_import_phase(variants, one_device_mesh, events, monkeypatch):
-    monkeypatch.setenv("PALLAS_RANK", "1")  # the kernel, interpreted
+    interpreted_kernel(monkeypatch)
     public, flagship = chip_smoke.phase_import(
         variants, one_device_mesh, 4, 8, 4, events, pipeline_runs=2)
     assert public["padded_shape"] == [4, 2048] and public["launches"] == 1
@@ -116,7 +124,7 @@ def test_import_phase_fails_on_a_compile_inside_the_timed_pipeline(
         jax.block_until_ready(jax.jit(lambda x: x * 3 + 1)(np.arange(7)))
         return packed(*args, **kw)
 
-    monkeypatch.setenv("PALLAS_RANK", "1")
+    interpreted_kernel(monkeypatch)
     monkeypatch.setattr(fugue_batch, "merge_text_payloads_packed", compiles_first)
     with pytest.raises(chip_smoke.SmokeFailure, match="compiled inside"):
         chip_smoke.phase_import(variants, one_device_mesh, 4, 8, 4, events)
@@ -131,7 +139,7 @@ def test_import_phase_fails_on_a_non_pallas_rank(variants, one_device_mesh, even
 
 def test_import_phase_notices_a_wrong_text(variants, one_device_mesh, events,
                                            monkeypatch):
-    monkeypatch.setenv("PALLAS_RANK", "1")
+    interpreted_kernel(monkeypatch)
     wrong = [dict(v) for v in variants]
     wrong[1]["text"] = wrong[1]["text"][::-1]
     with pytest.raises(chip_smoke.SmokeFailure, match="differs from the host"):
@@ -146,7 +154,7 @@ def test_chips4_sharded_phase(workload):
 
 
 def test_chips4_mesh_phase(variants, monkeypatch):
-    monkeypatch.setenv("PALLAS_RANK", "1")
+    interpreted_kernel(monkeypatch)
     rec = chip_smoke.phase_chips4_mesh(variants[:2], variants[1:])
     assert rec["fleet"]["equal_one_device"] and rec["batch"]["equal_one_device"]
     assert rec["batch"]["device_set"] == list(range(len(jax.devices())))

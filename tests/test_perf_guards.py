@@ -2,39 +2,58 @@
 perf_import_quadratic.rs + perf_text_insert_quadratic.rs — asserting
 scaling shape, not absolute numbers).
 
-The wall-clock RATIO guards are load-sensitive on shared runners
-(ADVICE r5 finding 3): PERF_GUARD_RATIO widens the scaling bound
-(default 11; CI under heavy ambient load can export e.g. 20), and
-PERF_GUARD_SKIP=1 skips the timing-based guards entirely — the
-structural (counted, not timed) guards always run."""
-import os
+The two scaling guards count what the work costs — function calls under
+``sys.setprofile``, Python and C alike — for n and 4 n, so they read the
+same under any load (a wall-clock ratio read 2.6-4.8x alone and over 11x
+under six xdist workers).  The structural guards below count too;
+``test_checkout_bounded`` and the two ``*_floor`` guards still read the
+clock, against generous ceilings (ROADMAP D15)."""
+import sys
 import time
 
 import pytest
 
 from loro_tpu import LoroDoc
 
-# quadratic would be ~16x for 4x work; n log n with noise stays well
-# under the default 11 — overridable for noisy shared runners
-RATIO_BOUND = float(os.environ.get("PERF_GUARD_RATIO", "11"))
-
-timing_guard = pytest.mark.skipif(
-    os.environ.get("PERF_GUARD_SKIP", "0") in ("1", "true", "yes"),
-    reason="PERF_GUARD_SKIP=1: wall-clock guards disabled (noisy runner)",
-)
+# quadratic would be ~16x for 4x work; n log n reads ~4.3x
+RATIO_BOUND = 11
 
 
-def _time_text_insert(n: int) -> float:
-    doc = LoroDoc(peer=1)
-    t = doc.get_text("t")
-    t0 = time.perf_counter()
-    for i in range(n):
-        t.insert(i, "x")
-    doc.commit()
-    return time.perf_counter() - t0
+def _calls(fn) -> int:
+    """The function calls ``fn()`` makes on this thread."""
+    n = 0
+
+    def on_event(_frame, event, _arg):
+        nonlocal n
+        if event in ("call", "c_call"):
+            n += 1
+
+    sys.setprofile(on_event)
+    try:
+        fn()
+    finally:
+        sys.setprofile(None)
+    return n
 
 
-def _time_import(n_updates: int) -> float:
+def _scaling(work, n: int) -> float:
+    """Calls of 4x the work over calls of the work; ``work(n)`` sets up
+    outside the count and returns the function to count."""
+    return _calls(work(4 * n)) / _calls(work(n))
+
+
+def _text_insert(n: int):
+    def run():
+        doc = LoroDoc(peer=1)
+        t = doc.get_text("t")
+        for i in range(n):
+            t.insert(i, "x")
+        doc.commit()
+
+    return run
+
+
+def _import(n_updates: int):
     a = LoroDoc(peer=1)
     blobs = []
     t = a.get_text("t")
@@ -43,57 +62,41 @@ def _time_import(n_updates: int) -> float:
         t.insert(len(t), f"w{i} ")
         a.commit()
         blobs.append(a.export_updates(vv))
-    b = LoroDoc(peer=2)
-    t0 = time.perf_counter()
-    for blob in blobs:
-        b.import_(blob)
-    return time.perf_counter() - t0
+
+    def run():
+        b = LoroDoc(peer=2)
+        for blob in blobs:
+            b.import_(blob)
+
+    return run
 
 
-def _best_of(fn, n, reps=4) -> float:
-    # minimum over repetitions: the least load-contention-sensitive
-    # statistic for a CPU-bound loop (this guard flaked under parallel
-    # system load with medians)
-    return min(fn(n) for _ in range(reps))
+def _quadratic_stand_in(n: int):
+    """What the guards are there to catch: every step walks all before it."""
+    def run():
+        seen = []
+        for i in range(n):
+            for _ in seen:
+                len(seen)
+            seen.append(i)
+
+    return run
 
 
-def _scaling(fn, n: int, floor: float) -> float:
-    """Time of 4x the work over the time of the work, each the minimum
-    over four repetitions; the two sizes measured turn about so that a
-    burst of load on a shared runner falls on both, and the whole measured
-    once more before a ratio over the bound stands (one tier-1 run read
-    11.2x under six xdist workers where it reads 2.6-4.8x alone)."""
-    for _attempt in range(2):
-        small, big = [], []
-        for _ in range(4):
-            small.append(fn(n))
-            big.append(fn(4 * n))
-        ratio = min(big) / max(min(small), floor)
-        if ratio < RATIO_BOUND:
-            break
-    return ratio
-
-
-@timing_guard
 def test_text_insert_not_quadratic():
-    # sizes large enough that interpreter warmup noise doesn't dominate
-    ratio = _scaling(_time_text_insert, 4000, 1e-3)
-    assert ratio < RATIO_BOUND, (
-        f"text insert scaling {ratio:.1f}x for 4x work "
-        f"(bound {RATIO_BOUND}; widen via PERF_GUARD_RATIO if load-noise)"
-    )
+    ratio = _scaling(_text_insert, 4000)
+    assert ratio < RATIO_BOUND, f"text insert: {ratio:.1f}x the calls for 4x work"
 
 
-@timing_guard
 def test_import_not_quadratic():
-    ratio = _scaling(_time_import, 100, 1e-4)
-    assert ratio < RATIO_BOUND, (
-        f"import scaling {ratio:.1f}x for 4x work "
-        f"(bound {RATIO_BOUND}; widen via PERF_GUARD_RATIO if load-noise)"
-    )
+    ratio = _scaling(_import, 100)
+    assert ratio < RATIO_BOUND, f"import: {ratio:.1f}x the calls for 4x work"
 
 
-@timing_guard
+def test_scaling_guard_bites_on_quadratic_work():
+    assert _scaling(_quadratic_stand_in, 100) > RATIO_BOUND
+
+
 def test_checkout_bounded():
     """Checkout cost stays proportional to history, not history^2."""
     doc = LoroDoc(peer=1)
@@ -243,7 +246,6 @@ def test_diff_delta_vs_fullscan_equivalence():
         )
 
 
-@timing_guard
 def test_native_order_engine_floor():
     """Resident-fleet host ceiling guard (tests/soak_fleet.py measures
     ~3M rows/s/core isolated): the native order engine must stay above
@@ -265,18 +267,18 @@ def test_native_order_engine_floor():
         else:
             rows.append((rng.randrange(i) if i else -1, rng.choice([0, 1]), 7, i))
 
-    def one(_n):
+    def one():
         eng = eng_factory()
         t0 = time.perf_counter()
         eng.append_rows(rows, 0)
         return time.perf_counter() - t0
 
-    best = _best_of(one, k, reps=5)
+    # the minimum: the least load-sensitive statistic of a CPU-bound loop
+    best = min(one() for _ in range(5))
     rate = k / best
     assert rate > 500_000, f"native order engine at {rate/1e6:.2f}M rows/s (< 0.5M floor)"
 
 
-@timing_guard
 def test_resident_ingest_floor():
     """Full resident ingest floor (r5 host-funnel rebuild measured
     ~1.1M rows/s/core steady at 768-row epochs): order maintenance +

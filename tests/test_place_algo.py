@@ -1,7 +1,7 @@
-"""The two element-placement formulations (PLACE_ALGO=sort, the
-default, and PLACE_ALGO=scatter) must produce identical (codes, count)
-on real merged docs — the scatter path is the documented fallback for
-algo comparisons and must not rot."""
+"""The sort placement the merge launches (``_place_by_chain_sort``)
+against the histogram + scatter formulation (``_place_by_chain_scatter``,
+whose core ``chain_positions`` the richtext batch uses): identical
+(codes, count) on real merged docs."""
 import random
 
 import numpy as np

@@ -69,6 +69,7 @@ from loro_tpu.bench_utils import (
 from loro_tpu.core.ids import ContainerID, ContainerType
 from loro_tpu.doc import strip_envelope
 from loro_tpu.errors import LoroError
+from loro_tpu.ops.text_codes import text_from_codes
 
 IMPORT_CID = ContainerID.root("text", ContainerType.Text)
 SERVE_CID = ContainerID.root("t", ContainerType.Text)
@@ -705,7 +706,7 @@ def phase_chips4_mesh(fleet_variants, batch_variants) -> dict:
         t0 = time.perf_counter()
         codes, counts = batch._materialize(use_solver=True)
         rec[f"batch_{name}_s"] = time.perf_counter() - t0
-        texts[name] = ["".join(map(chr, codes[i, : counts[i]]))
+        texts[name] = [text_from_codes(codes[i], counts[i])
                        for i in range(len(payloads))]
         if name == "mesh":
             rec["batch"] = {

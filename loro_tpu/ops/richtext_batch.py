@@ -29,6 +29,7 @@ from .fugue_batch import (
     fugue_order,
     rank_bound,
 )
+from .text_codes import text_from_codes
 
 NEG = jnp.int32(-(2**31) + 1)
 
@@ -302,8 +303,7 @@ def segments_from_device(codes, count, bounds, win, keys, values):
     """Reconstruct Quill-style [{insert, attributes?}] segments from one
     doc's device outputs — the comparison form against the host's
     TextState.get_richtext_value() (differential tests + bench gates)."""
-    count = int(count)
-    text = "".join(chr(c) for c in np.asarray(codes)[:count])
+    text = text_from_codes(codes, count)
     bounds = np.asarray(bounds)
     win = np.asarray(win)
     segs = []

@@ -56,6 +56,7 @@ from ..ops.fugue_batch import (
     pad_bucket,
 )
 from ..ops.lww import MapOpCols, lww_merge_doc
+from ..ops.text_codes import text_from_codes
 from .mesh import DOC_AXIS, OP_AXIS, doc_sharding, make_mesh, replicated
 
 
@@ -339,10 +340,8 @@ class Fleet:
             with tracing.span("fleet.fetch"):
                 codes = _sup_fetch("fleet.text", codes)
                 counts = _sup_fetch("fleet.text", counts)
-            with tracing.span("fleet.join"):
-                texts = [
-                    "".join(map(chr, codes[i, : counts[i]])) for i in range(d)
-                ]
+            with tracing.span("fleet.join", chars=int(counts[:d].sum())):
+                texts = [text_from_codes(codes[i], counts[i]) for i in range(d)]
         return TextMergeResult(texts)
 
     def merge_text_changes(
@@ -478,7 +477,7 @@ class Fleet:
             return _host_degrade("richtext", docs_changes, cid)
         results = []
         for i, (_, keys, values) in enumerate(extracts):
-            text = "".join(map(chr, codes[i, : counts[i]]))
+            text = text_from_codes(codes[i], counts[i])
             segs: List[dict] = []
             for r in range(bounds.shape[1] - 1):
                 lo, hi = int(bounds[i, r]), int(bounds[i, r + 1])
@@ -2055,7 +2054,7 @@ class DeviceDocBatch:
     def texts(self, use_solver: bool = False) -> List[str]:
         """Materialize every doc (one launch)."""
         codes, counts = self._materialize(use_solver)
-        return ["".join(map(chr, codes[i, : counts[i]])) for i in range(self.n_docs)]
+        return [text_from_codes(codes[i], counts[i]) for i in range(self.n_docs)]
 
     def values(self, use_solver: bool = False) -> List[list]:
         """Materialize value lists (as_text=False batches)."""

@@ -48,9 +48,17 @@ def movable_merge_doc(cols: MovableCols, n_elems: int) -> Tuple[jax.Array, jax.A
     slot by XLA scatter semantics.  Callers must size/assert n_elems
     host-side (see extract_movable's elems list)."""
     seq = cols.seq
-    s = seq.parent.shape[0]
     elem = jnp.where(seq.valid, seq.content, n_elems)  # pads -> dump elem
+    visible, value = _winners(cols, elem, n_elems)
+    return _place(fugue_order(seq), visible, value)
 
+
+@jax.named_scope("movable_winners")
+def _winners(cols: MovableCols, elem: jax.Array, n_elems: int):
+    """The two LWW folds, per slot: (visible bool[S] — the slot is its
+    element's last move and not tombstoned —, value i32[S] — its
+    element's last set)."""
+    seq = cols.seq
     # winning slot per element: max (lamport, peer); tie-break by peer is
     # safe because slot ids are unique per (lamport, peer)
     lam = jnp.where(seq.valid, cols.lamport, NEG)
@@ -80,7 +88,14 @@ def movable_merge_doc(cols: MovableCols, n_elems: int) -> Tuple[jax.Array, jax.A
 
     # visible slots: the element's winning slot, not tombstoned
     visible = is_win_slot & ~seq.deleted & (win_deleted[elem] == 0)
-    rank = fugue_order(seq)
+    return visible, win_value[jnp.clip(elem, 0, n_elems)]
+
+
+@jax.named_scope("movable_place")
+def _place(rank: jax.Array, visible: jax.Array, value: jax.Array):
+    """The visible slots' values in rank order: (i32[S] padded with -1,
+    count)."""
+    s = rank.shape[0]
     m = rank_bound(s)
     rk = jnp.clip(rank, 0, m - 1)
     hist = jnp.zeros(m, jnp.int32).at[jnp.where(visible, rk, m - 1)].add(
@@ -90,7 +105,7 @@ def movable_merge_doc(cols: MovableCols, n_elems: int) -> Tuple[jax.Array, jax.A
     pos = pos_of_rank[rk]
     count = visible.sum().astype(jnp.int32)
     out = jnp.full(s, -1, jnp.int32).at[jnp.where(visible, pos, s)].set(
-        win_value[jnp.clip(elem, 0, n_elems)], mode="drop"
+        value, mode="drop"
     )
     return out, count
 

@@ -211,26 +211,6 @@ def _batch_export_select(batch, family: str, index, requests, sup=None):
         return sup.launch(thunk, label=f"fleet.export.{family}")
 
 
-def _empty_seq_np(n: int):
-    """All-invalid numpy SeqColumns of n rows (doc-axis padding filler)."""
-    import numpy as _np
-
-    from ..ops.fugue_batch import SeqColumns, pad_seq_columns
-
-    return pad_seq_columns(
-        SeqColumns(
-            parent=_np.zeros(0, _np.int32),
-            side=_np.zeros(0, _np.int32),
-            peer=_np.zeros(0, _np.int32),
-            counter=_np.zeros(0, _np.int32),
-            deleted=_np.zeros(0, bool),
-            content=_np.zeros(0, _np.int32),
-            valid=_np.zeros(0, bool),
-        ),
-        n,
-    )
-
-
 def text_pads(n_chains: int, n_elements: int) -> Tuple[int, int]:
     """``(pad_c, pad_n)`` of a text batch whose largest document has
     ``n_chains`` chains and ``n_elements`` elements.  Elements pad to a
@@ -240,6 +220,20 @@ def text_pads(n_chains: int, n_elements: int) -> Tuple[int, int]:
     65,536 tokens, the last ring the packed Pallas kernels hold (one
     more chain slot would be 65,538 and the dual-table kernel)."""
     return pad_bucket(n_chains + 1) - 1, pad_bucket(n_elements)
+
+
+def movable_pads(n_slots: int, n_sets: int, n_elems: int) -> Tuple[int, int, int]:
+    """``(pad_s, pad_k, pad_e)`` of a movable-list batch whose largest
+    document has ``n_slots`` position slots, ``n_sets`` set rows and
+    ``n_elems`` elements: each to a power of two, so the RING the rank
+    walks, 2 * (pad_s + 1) tokens, is two past one (32,768 slots rank
+    65,538 tokens, 65,536 rank 131,074).  ``text_pads`` buckets its ring
+    instead; here that is a ``perf_opt``'s to measure size by size, not
+    this rule's to assume: on the chip the XLA loop ranks 64 documents of
+    ring 262,146 in 218 ms a round and of ring 262,144 in 270, and a ring
+    bucket pays at 30,000 slots and costs at 50,000 (PERF.md, PR 32)."""
+    return (pad_bucket(n_slots), pad_bucket(n_sets, floor=16),
+            pad_bucket(n_elems, floor=16))
 
 
 def text_transport(pad_c: int, pad_n: int) -> str:
@@ -266,6 +260,27 @@ def _empty_text_batch(transport: str, d_pad: int, pad_c: int, pad_n: int):
         c_valid=np.zeros(c, bool), head_row=np.zeros(c, np.int32),
         chain_id=np.zeros(n, np.int32), deleted=np.zeros(n, bool),
         content=np.zeros(n, np.int32), valid=np.zeros(n, bool),
+    )
+
+
+def _empty_movable_batch(d_pad: int, pad_s: int, pad_k: int):
+    """The host buffers of a movable-list batch, every document
+    all-invalid: ``MovableCols`` of ``[d_pad, pad_s]`` slot columns and
+    ``[d_pad, pad_k]`` set columns."""
+    from ..ops.fugue_batch import SeqColumns
+    from ..ops.movable_batch import MovableCols
+
+    s, k = (d_pad, pad_s), (d_pad, pad_k)
+    return MovableCols(
+        seq=SeqColumns(
+            parent=np.full(s, -1, np.int32), side=np.zeros(s, np.int32),
+            peer=np.zeros(s, np.int32), counter=np.zeros(s, np.int32),
+            deleted=np.ones(s, bool), content=np.full(s, -1, np.int32),
+            valid=np.zeros(s, bool),
+        ),
+        lamport=np.zeros(s, np.int32), set_elem=np.zeros(k, np.int32),
+        set_lamport=np.zeros(k, np.int32), set_peer=np.zeros(k, np.int32),
+        set_value=np.zeros(k, np.int32), set_valid=np.zeros(k, bool),
     )
 
 
@@ -520,29 +535,37 @@ class Fleet:
         from ..codec.binary import decode_changes
         from ..ops.movable_batch import extract_movable, extract_movable_from_payload
 
-        extracts = _decode_payloads(
-            "movable", "fleet.movable_decode", payloads, cid,
-            extract_movable_from_payload, _self_contained(extract_movable),
-        )
-        try:
-            return self._merge_movable_extracted(extracts)
-        except DeviceFailure:
-            return _host_degrade(
-                "movable", [decode_changes(p) for p in payloads], cid
+        # one trace id per call (a request's own, when it made the call)
+        with tracing.span(
+            "fleet.merge_movable_payloads",
+            trace_id=tracing.current() or tracing.new_trace_id("f"),
+            docs=len(payloads),
+        ):
+            extracts = _decode_payloads(
+                "movable", "fleet.movable_decode", payloads, cid,
+                extract_movable_from_payload, _self_contained(extract_movable),
             )
+            try:
+                return self._merge_movable_extracted(extracts)
+            except DeviceFailure:
+                return _host_degrade(
+                    "movable", [decode_changes(p) for p in payloads], cid
+                )
 
     def _merge_movable_extracted(self, extracts) -> List[list]:
-        import jax.numpy as jnp
+        """The device half of both movable-list entries: the documents'
+        columns written into one all-invalid batch (``movable_pads``), one
+        upload, one launch of ``movable_merge_batch`` (the Fugue order of
+        the slots — the rank ``_resolve_rank_spec`` gives for the ring —
+        and the two LWW folds), one fetch, the value lists."""
+        from ..ops.movable_batch import LazyPayloadValue, movable_merge_batch
 
-        from ..ops.fugue_batch import SeqColumns, pad_bucket, pad_seq_columns
-        from ..ops.movable_batch import (
-            LazyPayloadValue,
-            MovableCols,
-            movable_merge_batch,
+        label = "fleet.movable"
+        s, k, n_elems = movable_pads(
+            max(c.seq.parent.shape[0] for c, _, _ in extracts),
+            max(c.set_elem.shape[0] for c, _, _ in extracts),
+            max(len(e) for _, e, _ in extracts),
         )
-        s = pad_bucket(max(1, max(c.seq.parent.shape[0] for c, _, _ in extracts)))
-        k = pad_bucket(max(1, max(c.set_elem.shape[0] for c, _, _ in extracts)), floor=16)
-        n_elems = pad_bucket(max(1, max(len(e) for _, e, _ in extracts)), floor=16)
         d = len(extracts)
         d_pad = _mesh_pad(self.mesh, d)
         _obs_merge(
@@ -552,66 +575,36 @@ class Fleet:
             (s + k) * d_pad,
             (s, k, n_elems, d_pad),
         )
-
-        def padk(a, fill, dtype):
-            out = np.full(k, fill, dtype)
-            out[: a.shape[0]] = a
-            return out
-
-        def pads(a, fill, dtype):
-            out = np.full(s, fill, dtype)
-            out[: a.shape[0]] = a
-            return out
-
-        seq_stack = []
-        lam, se, sl, sp, sv, svd = [], [], [], [], [], []
-        for c, _, _ in extracts:
-            seq_stack.append(pad_seq_columns(c.seq, s))
-            lam.append(pads(c.lamport, 0, np.int32))
-            se.append(padk(c.set_elem, 0, np.int32))
-            sl.append(padk(c.set_lamport, 0, np.int32))
-            sp.append(padk(c.set_peer, 0, np.int32))
-            sv.append(padk(c.set_value, 0, np.int32))
-            svd.append(padk(c.set_valid, False, bool))
-        empty_seq = _empty_seq_np(s)
-        while len(seq_stack) < d_pad:
-            seq_stack.append(empty_seq)
-            lam.append(np.zeros(s, np.int32))
-            se.append(np.zeros(k, np.int32))
-            sl.append(np.zeros(k, np.int32))
-            sp.append(np.zeros(k, np.int32))
-            sv.append(np.zeros(k, np.int32))
-            svd.append(np.zeros(k, bool))
+        with tracing.span("fleet.movable_stack"):
+            rows = _empty_movable_batch(d_pad, s, k)
+            columns = jax.tree_util.tree_leaves(rows)
+            for i, (c, _, _) in enumerate(extracts):
+                for column, of_doc in zip(columns, jax.tree_util.tree_leaves(c)):
+                    column[i, : of_doc.shape[0]] = of_doc
         sh = doc_sharding(self.mesh)
-        cols = _sup_launch("fleet.movable", lambda: MovableCols(
-            seq=SeqColumns(
-                *[
-                    jax.device_put(np.stack([getattr(q, f) for q in seq_stack]), sh)
-                    for f in SeqColumns._fields
-                ]
-            ),
-            lamport=jax.device_put(np.stack(lam), sh),
-            set_elem=jax.device_put(np.stack(se), sh),
-            set_lamport=jax.device_put(np.stack(sl), sh),
-            set_peer=jax.device_put(np.stack(sp), sh),
-            set_value=jax.device_put(np.stack(sv), sh),
-            set_valid=jax.device_put(np.stack(svd), sh),
-        ))
-        out, counts = _sup_launch(
-            "fleet.movable", lambda: movable_merge_batch(cols, n_elems)
-        )
-        out = _sup_fetch("fleet.movable", out)
-        counts = _sup_fetch("fleet.movable", counts)
-        results = []
-        for i, (_, _, values) in enumerate(extracts):
-            idxs = out[i, : counts[i]]
-            row = []
-            for j in idxs:
-                v = values[j] if j >= 0 else None
-                if isinstance(v, LazyPayloadValue):
-                    v = v.get()  # winners only ever decode
-                row.append(v)
-            results.append(row)
+        with tracing.span("fleet.movable_upload"):
+            cols = _sup_launch(label, lambda: jax.device_put(rows, sh))
+        with tracing.span("fleet.movable_launch"):
+            out = _sup_launch(label, lambda: movable_merge_batch(cols, n_elems))
+        # the wait is the device's time, the fetch the host's copy: kept
+        # apart, under the same guard (merge_text_docs)
+        with tracing.span("fleet.movable_device_wait"):
+            get_supervisor().guard(lambda: jax.block_until_ready(out), label=label)
+        # ticked once the device has ranked the rings: a call that fails
+        # over to the host engine on the way here counts none
+        _tick_rank_obs(d_pad, s)
+        with tracing.span("fleet.movable_fetch"):
+            out, counts = (_sup_fetch(label, x) for x in out)
+        with tracing.span("fleet.movable_values"):
+            results = []
+            for i, (_, _, values) in enumerate(extracts):
+                row = []
+                for j in out[i, : counts[i]].tolist():
+                    v = values[j] if j >= 0 else None
+                    if isinstance(v, LazyPayloadValue):
+                        v = v.get()  # winners only ever decode
+                    row.append(v)
+                results.append(row)
         return results
 
     # ------------------------------------------------------------------

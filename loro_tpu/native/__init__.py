@@ -9,6 +9,15 @@ back gracefully: every caller handles ``available() == False``
 throughput path for fleet decode, reference-parity with loro's Rust
 block decode).  Measurement paths (benchmarks/, chip_smoke.py) call
 ``require()`` instead, which makes a failed build an error.
+
+Beside the explode entries (wire bytes -> columns, one a container
+family) and the order / id-map engines, two entries take the text
+paths' columns the rest of the way to the upload: ``contract_chains``
+(element table -> right-spine chains) and ``pack_chain_row`` (chains +
+element columns -> one padded u8 row of the packed transport).  Like
+every entry they run with the interpreter lock released, tick
+``codec.native_*_calls_total{fn}``, and have a numpy twin
+(ops/columnar.py) that answers without the library.
 """
 from __future__ import annotations
 
@@ -189,6 +198,15 @@ def _load() -> Optional[ctypes.CDLL]:
             ctypes.c_longlong,
             ctypes.c_int,
         ] + [ctypes.c_void_p] * 15 + [ctypes.c_longlong] * 3 + [ctypes.c_void_p] * 2
+        lib.loro_contract_chains.restype = ctypes.c_longlong
+        lib.loro_contract_chains.argtypes = (
+            [ctypes.c_void_p] * 2 + [ctypes.c_longlong] + [ctypes.c_void_p] * 4
+        )
+        lib.loro_pack_chain_row.restype = ctypes.c_longlong
+        lib.loro_pack_chain_row.argtypes = (
+            [ctypes.c_void_p] * 4 + [ctypes.c_longlong] + [ctypes.c_void_p] * 4
+            + [ctypes.c_longlong] * 3 + [ctypes.c_void_p]
+        )
         lib.loro_order_new.restype = ctypes.c_void_p
         lib.loro_order_new.argtypes = []
         lib.loro_order_free.restype = None
@@ -619,6 +637,78 @@ def explode_movable_delta_payload(payload: bytes, target_cid_index: int):
     slots["ext_peer_idx"] = ext_peer
     slots["ext_counter"] = ext_ctr
     return {"slots": slots, "sets": sets, "dels": dels}
+
+
+def _ptr(a: np.ndarray, dtype) -> tuple:
+    """``(array, pointer)`` of ``a`` as a C-contiguous ``dtype`` array (a
+    copy only where ``a`` is not one already; a bool column is its own
+    bytes).  The array keeps the pointer alive."""
+    if dtype is np.uint8 and a.dtype == np.bool_:
+        a = a.view(np.uint8)
+    a = np.ascontiguousarray(a, dtype)
+    return a, a.ctypes.data_as(ctypes.c_void_p)
+
+
+def contract_chains(parent, side):
+    """Right-spine chain contraction of one element table (the rule of
+    ``ops/columnar.contract_chains``, whose numpy body is the reference):
+    ``(chain_id i32[N], head_row i32[C], c_parent i32[C], c_side i32[C])``,
+    or None when the native library is unavailable or a parent lies past
+    the table.  One call, the interpreter lock released for all of it."""
+    lib = _load()
+    if lib is None:
+        return None
+    _obs.counter("codec.native_chain_calls_total").inc(fn="contract")
+    parent, p_parent = _ptr(parent, np.int32)
+    side, p_side = _ptr(side, np.int32)
+    n = parent.shape[0]
+    if side.shape != (n,):
+        raise ValueError("parent and side of unequal lengths")
+    chain_id = np.empty(n, np.int32)
+    per_chain = np.empty((3, n), np.int32)  # a chain a row at most
+    c = lib.loro_contract_chains(
+        p_parent, p_side, n, chain_id.ctypes.data_as(ctypes.c_void_p),
+        *[row.ctypes.data_as(ctypes.c_void_p) for row in per_chain],
+    )
+    if c < 0:
+        return None
+    head_row, c_parent, c_side = per_chain[:, :c]
+    return chain_id, head_row, c_parent, c_side
+
+
+def pack_chain_row(c_parent, c_side, c_valid, head_row, chain_id, content,
+                   deleted, valid, pad_c: int, pad_n: int, out_row) -> bool:
+    """Write one document's packed u8 row (layout: ops/fugue_batch.py,
+    above ``packed_row_bytes``) into ``out_row``, a C-contiguous
+    ``u8[8 * (pad_c + pad_n)]``, from its UNPADDED chain columns (``C``
+    entries) and element columns (``N`` entries), the pads filled as
+    ``chain_columns`` fills them.  False when the native library is
+    unavailable (nothing written); ValueError where the document does
+    not fit its pads.  One call, the interpreter lock released."""
+    lib = _load()
+    if lib is None:
+        return False
+    n_chains, n = c_parent.shape[0], chain_id.shape[0]
+    if not (out_row.dtype == np.uint8 and out_row.flags.c_contiguous
+            and out_row.shape == (8 * (pad_c + pad_n),)):
+        raise ValueError(f"out_row is not a contiguous u8[{8 * (pad_c + pad_n)}]")
+    if not (c_side.shape[0] == c_valid.shape[0] == head_row.shape[0] == n_chains
+            and content.shape[0] == deleted.shape[0] == valid.shape[0] == n):
+        raise ValueError("chain columns or element columns of unequal lengths")
+    _obs.counter("codec.native_chain_calls_total").inc(fn="pack")
+    per_chain = [_ptr(c_parent, np.int32), _ptr(c_side, np.int32),
+                 _ptr(c_valid, np.uint8), _ptr(head_row, np.int32)]
+    per_row = [_ptr(chain_id, np.int32), _ptr(content, np.int32),
+               _ptr(deleted, np.uint8), _ptr(valid, np.uint8)]
+    rc = lib.loro_pack_chain_row(
+        *[p for _a, p in per_chain], n_chains, *[p for _a, p in per_row], n,
+        pad_c, pad_n, out_row.ctypes.data_as(ctypes.c_void_p),
+    )
+    if rc < 0:
+        raise ValueError(
+            f"a document of {n_chains} chains and {n} elements does not fit "
+            f"pads ({pad_c}, {pad_n})")
+    return True
 
 
 class NativeShadowOrder:

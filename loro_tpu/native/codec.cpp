@@ -1140,6 +1140,94 @@ long long loro_explode_movable_delta(const uint8_t* buf, long long len, int targ
                       n_dels, s_extpeer, s_extctr);
 }
 
+// ---------------------------------------------------------------------------
+// Chain contraction and the packed row: the two host stages between the
+// seq explode and the upload (ops/columnar.contract_chains and
+// pack_chain_row, whose numpy bodies are the differential reference).
+
+// Right-spine chains of an element table in (peer, counter) row order.
+// Row i links to row i-1 iff parent[i] == i-1, side Right (1), row i-1
+// has exactly one child and no left child, and row i has no left child.
+// chain_id holds n entries; head_row, c_parent and c_side hold up to n
+// (one a chain).  Returns the chain count, or -1 for a parent >= n.
+long long loro_contract_chains(const int32_t* parent, const int32_t* side,
+                               long long n, int32_t* chain_id,
+                               int32_t* head_row, int32_t* c_parent,
+                               int32_t* c_side) {
+  // per row: children (saturating at 2) and whether one is a left child
+  std::vector<uint8_t> kids(n, 0), left(n, 0);
+  for (long long i = 0; i < n; i++) {
+    int32_t p = parent[i];
+    if (p < 0) continue;
+    if (p >= n) return -1;
+    if (kids[p] < 2) kids[p]++;
+    if (side[i] == 0) left[p] = 1;
+  }
+  long long c = 0;
+  for (long long i = 0; i < n; i++) {
+    int32_t p = parent[i];
+    bool link = p >= 0 && p == i - 1 && side[i] == 1 && kids[p] == 1 &&
+                !left[p] && !left[i];
+    if (!link) {
+      head_row[c] = (int32_t)i;
+      c_side[c] = side[i];
+      c++;
+    }
+    chain_id[i] = (int32_t)(c - 1);
+  }
+  // a chain's parent only now: in (peer, counter) order a parent typed
+  // by a higher-ranked peer sits BELOW its child, so its chain id is
+  // not known while the chains are being numbered
+  for (long long k = 0; k < c; k++) {
+    int32_t p = parent[head_row[k]];
+    c_parent[k] = p >= 0 ? chain_id[p] : -1;
+  }
+  return c;
+}
+
+// One document's packed u8 row (layout: ops/fugue_batch.py, above
+// packed_row_bytes) from its unpadded chain and element columns, the
+// pads filled as chain_columns fills them.  `out` holds
+// 8 * (pad_c + pad_n) bytes at any alignment.  Returns 0, or -1 when
+// the document does not fit its pads.
+long long loro_pack_chain_row(const int32_t* c_parent, const int32_t* c_side,
+                              const uint8_t* c_valid, const int32_t* head_row,
+                              long long n_chains, const int32_t* chain_id,
+                              const int32_t* content, const uint8_t* deleted,
+                              const uint8_t* valid, long long n,
+                              long long pad_c, long long pad_n, uint8_t* out) {
+  if (n_chains < 0 || n < 0 || n_chains > pad_c || n > pad_n) return -1;
+  const size_t C = (size_t)n_chains, N = (size_t)n;
+  const size_t tail_c = (size_t)pad_c - C, tail_n = (size_t)pad_n - N;
+  auto narrow16 = [&](const int32_t* src, size_t k, size_t tail, int fill) {
+    for (size_t i = 0; i < k; i++) {
+      uint16_t v = (uint16_t)src[i];
+      std::memcpy(out + 2 * i, &v, 2);
+    }
+    std::memset(out + 2 * k, fill, 2 * tail);
+    out += 2 * (k + tail);
+  };
+  auto narrow8 = [&](const int32_t* src, size_t k, size_t tail) {
+    for (size_t i = 0; i < k; i++) out[i] = (uint8_t)src[i];
+    std::memset(out + k, 0, tail);
+    out += k + tail;
+  };
+  auto copy = [&](const void* src, size_t width, size_t k, size_t tail, int fill) {
+    std::memcpy(out, src, width * k);
+    std::memset(out + width * k, fill, width * tail);
+    out += width * (k + tail);
+  };
+  narrow16(c_parent, C, tail_c, 0xFF);  // -1 root == 0xFFFF
+  narrow16(chain_id, N, tail_n, 0);
+  copy(head_row, 4, C, tail_c, 0);
+  copy(content, 4, N, tail_n, 0xFF);  // -1 == invisible
+  narrow8(c_side, C, tail_c);
+  copy(c_valid, 1, C, tail_c, 0);
+  copy(deleted, 1, N, tail_n, 1);
+  copy(valid, 1, N, tail_n, 0);
+  return 0;
+}
+
 }  // extern "C"
 
 // ---------------------------------------------------------------------------

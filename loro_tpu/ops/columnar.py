@@ -17,7 +17,7 @@ import numpy as np
 from ..core.change import Change, MapSet, SeqDelete, SeqInsert, StyleAnchor
 from ..core.ids import ContainerID
 from ..oplog.oplog import _RunCont
-from .fugue_batch import SeqColumns
+from .fugue_batch import SeqColumns, pack_chain_doc_into
 
 
 @dataclass
@@ -371,10 +371,27 @@ def chain_columns(
 
 
 def contract_chains(ex: SeqExtract) -> ChainExtract:
+    """The chains of ``ex`` (rule: ``ChainExtract``): one native call
+    where the library is there (``native.contract_chains``: three linear
+    passes, no interpreter lock), else the numpy body below, which is
+    also the differential reference (``_contract_chains_numpy``)."""
+    from ..native import contract_chains as native_contract
+
+    out = native_contract(ex.parent, ex.side)
+    if out is None:
+        return _contract_chains_numpy(ex)
+    chain_id, head_row, c_parent, c_side = out
+    return ChainExtract(
+        parent=c_parent,
+        side=c_side,
+        valid=np.ones(len(head_row), bool),
+        head_row=head_row,
+        chain_id=chain_id,
+    )
+
+
+def _contract_chains_numpy(ex: SeqExtract) -> ChainExtract:
     n = ex.n
-    if n == 0:
-        z = np.zeros(0, np.int32)
-        return ChainExtract(z, z, np.zeros(0, bool), z, z)
     parent, side = ex.parent, ex.side
     pp = np.maximum(parent, 0)
     cc = np.bincount(parent[parent >= 0], minlength=n)
@@ -400,3 +417,20 @@ def contract_chains(ex: SeqExtract) -> ChainExtract:
         head_row=head_row,
         chain_id=chain_id.astype(np.int32),
     )
+
+
+def pack_chain_row(
+    ex: SeqExtract, chains: ChainExtract, pad_c: int, pad_n: int, out_row: np.ndarray
+) -> None:
+    """One document's packed u8 row (``packed_row_bytes(pad_c, pad_n)``
+    bytes, layout above it) straight from the unpadded extract and its
+    chains: byte for byte ``pack_chain_doc_into(chain_columns(ex, pad_n,
+    pad_c, chains=chains), out_row)``, which it is where the native
+    library is absent, in one native call and no padded copy between."""
+    from ..native import pack_chain_row as native_pack
+
+    if not native_pack(
+        chains.parent, chains.side, chains.valid, chains.head_row, chains.chain_id,
+        ex.content, ex.deleted, ex.valid, pad_c, pad_n, out_row,
+    ):
+        pack_chain_doc_into(chain_columns(ex, pad_n=pad_n, pad_c=pad_c, chains=chains), out_row)

@@ -809,7 +809,7 @@ def merge_text_payloads_packed(
 
     from ..obs import metrics as obs
     from ..utils import tracing
-    from .columnar import chain_columns, contract_chains, extract_seq_from_payload
+    from .columnar import contract_chains, extract_seq_from_payload, pack_chain_row
 
     if n_docs % chunk:
         raise ValueError(f"n_docs={n_docs} is not a multiple of chunk={chunk}")
@@ -826,9 +826,8 @@ def merge_text_payloads_packed(
             row = np.empty(row_w, np.uint8)
             with tracing.span("packed.contract"):
                 chains = contract_chains(exd)
-            with tracing.span("packed.pack"):  # padding to the row's widths + the u8 row
-                cols = chain_columns(exd, pad_n=pad_n, pad_c=pad_c, chains=chains)
-                pack_chain_doc_into(cols, row)
+            with tracing.span("packed.pack"):  # the u8 row, padded to the row's widths
+                pack_chain_row(exd, chains, pad_c, pad_n, row)
             return row, p_ops
 
     n_workers = min(8, os.cpu_count() or 1)

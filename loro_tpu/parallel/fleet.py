@@ -26,6 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from .. import native
 from ..core.change import Change
 from ..core.ids import ContainerID
 from ..errors import DeviceFailure
@@ -45,13 +46,13 @@ from ..ops.columnar import (
     chain_columns,
     contract_chains,
     extract_seq_container,
+    pack_chain_row,
 )
 from ..ops.fugue_batch import (
     ChainColumns,
     _chain_merge_docs_jit,
     _tick_rank_obs,
     chain_merge_docs_packed,
-    pack_chain_doc_into,
     packed_row_bytes,
     pad_bucket,
 )
@@ -307,8 +308,11 @@ class Fleet:
         axis to a multiple of the mesh's doc dimension; doc-axis padding
         rows are all-invalid documents."""
         with tracing.span("fleet.merge_text_docs", docs=len(extracts)):
-            # on the caller's thread: some twenty short numpy passes a
-            # document, which eight threads run slower than one (PERF.md PR 29)
+            # a loop on the caller's thread: one native call a document
+            # (about a millisecond at B4's size, PERF.md PR 33); without
+            # the library, numpy's twenty passes, and a counted fallback
+            if not native.available():
+                _obs_fallback("chain_contract")
             with tracing.span("fleet.contract"):
                 chains = [contract_chains(e) for e in extracts]
             pad_c, pad_n = text_pads(
@@ -327,10 +331,10 @@ class Fleet:
                 rows = _empty_text_batch(transport, d_pad, pad_c, pad_n)
             with tracing.span("fleet.pack"):
                 for i, (e, ch) in enumerate(zip(extracts, chains)):
-                    cols = chain_columns(e, pad_n=pad_n, pad_c=pad_c, chains=ch)
                     if transport == "packed":
-                        pack_chain_doc_into(cols, rows[i])
+                        pack_chain_row(e, ch, pad_c, pad_n, rows[i])
                     else:
+                        cols = chain_columns(e, pad_n=pad_n, pad_c=pad_c, chains=ch)
                         for column, of_doc in zip(rows, cols):
                             column[i] = of_doc
             sh = doc_sharding(self.mesh)

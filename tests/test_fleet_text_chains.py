@@ -12,15 +12,18 @@ import jax.monitoring
 import numpy as np
 import pytest
 
-from loro_tpu import LoroDoc
+from loro_tpu import LoroDoc, native
 from loro_tpu.core.ids import ContainerID, ContainerType
 from loro_tpu.doc import strip_envelope
 from loro_tpu.obs import metrics as obs
 from loro_tpu.ops import fugue_batch as fb
 from loro_tpu.ops.columnar import (
     SeqExtract,
+    _contract_chains_numpy,
+    chain_columns,
     contract_chains,
     extract_seq_from_payload,
+    pack_chain_row,
 )
 from loro_tpu.parallel import fleet as fleet_mod
 from loro_tpu.parallel.fleet import Fleet, text_pads, text_transport
@@ -287,6 +290,50 @@ def test_a_device_failure_still_degrades_to_the_host_engine(
         faultinject.clear()
     assert got.texts == [want]
     assert degraded.get(family="text") == n0 + 1
+
+
+@pytest.mark.parametrize("name", SCENARIOS)
+def test_native_contraction_and_row_equal_numpys_on_every_scenario(name):
+    """What ``Fleet`` ships is what it shipped: per document the native
+    chains are numpy's and the packed row, at the batch's pads, is
+    ``pack_chain_doc_into(chain_columns(...))``'s byte for byte."""
+    assert native.available()
+    _payloads, extracts, _want = scenario(name)
+    chains = [contract_chains(e) for e in extracts]
+    pad_c, pad_n = text_pads(max(c.n_chains for c in chains), max(e.n for e in extracts))
+    for e, got in zip(extracts, chains):
+        ref = _contract_chains_numpy(e)
+        for f in ("parent", "side", "valid", "head_row", "chain_id"):
+            a, b = getattr(got, f), getattr(ref, f)
+            assert a.dtype == b.dtype and np.array_equal(a, b), f
+        row, want = np.empty((2, fb.packed_row_bytes(pad_c, pad_n)), np.uint8)
+        pack_chain_row(e, got, pad_c, pad_n, row)
+        fb.pack_chain_doc_into(chain_columns(e, pad_n=pad_n, pad_c=pad_c, chains=got), want)
+        assert row.tobytes() == want.tobytes()
+
+
+@pytest.mark.parametrize("entry", ["merge_text_payloads", "merge_text_docs"])
+def test_without_the_library_fleet_contracts_in_numpy_and_says_so(entry, monkeypatch):
+    payloads, extracts, want = scenario("children_of_a_middle")
+    fallbacks = obs.counter("fleet.host_fallback_total")
+    native_calls = obs.counter("codec.native_chain_calls_total")
+    fleet = Fleet(make_mesh(jax.devices()[:1]))
+
+    def call():
+        if entry == "merge_text_payloads":
+            return fleet.merge_text_payloads(payloads, CID).texts
+        return fleet.merge_text_docs(extracts).texts
+
+    f0, n0 = fallbacks.get(kind="chain_contract"), native_calls.total()
+    assert call() == want
+    # the library there: a native call a document and a stage, no fallback
+    assert fallbacks.get(kind="chain_contract") == f0
+    assert native_calls.total() - n0 == 2 * len(extracts)
+    monkeypatch.setattr(native, "_lib", None)
+    monkeypatch.setattr(native, "_build_failed", True)
+    assert call() == want and call() == want
+    assert fallbacks.get(kind="chain_contract") == f0 + 2  # one a call
+    assert native_calls.total() - n0 == 2 * len(extracts)
 
 
 def test_fleet_holds_no_uncontracted_text_program():

@@ -353,6 +353,50 @@ class TestNativeTreeMovable:
             assert got_native[i] == want, f"seed {seed} doc {i}"
 
 
+class TestChainEntries:
+    """The contraction and the row pack on raw columns (their callers'
+    differential cases: tests/test_packed_transport.py)."""
+
+    @pytest.mark.parametrize("seed", range(4))
+    def test_decoded_payloads_contract_and_pack_as_numpy_does(self, seed):
+        from loro_tpu import native
+        from loro_tpu.ops.columnar import _contract_chains_numpy, chain_columns
+        from loro_tpu.ops.fugue_batch import pack_chain_doc_into, packed_row_bytes
+
+        rng = random.Random(100 + seed)
+        docs = [LoroDoc(peer=rng.getrandbits(50) + 1) for _ in range(4)]
+        for _ in range(90):
+            d = rng.choice(docs)
+            t = d.get_text("t")
+            if len(t) and rng.random() < 0.3:
+                pos = rng.randint(0, len(t) - 1)
+                t.delete(pos, min(rng.randint(1, 4), len(t) - pos))
+            else:
+                t.insert(rng.randint(0, len(t)), rng.choice(["typed run ", "ç", "☃x"]))
+            if rng.random() < 0.25:
+                src, dst = rng.sample(docs, 2)
+                dst.import_(src.export_updates(dst.oplog_vv()))
+        for src in docs:
+            for dst in docs:
+                if src is not dst:
+                    dst.import_(src.export_updates(dst.oplog_vv()))
+        ex = extract_seq_from_payload(_payload(docs[0]), docs[0].get_text("t").id)
+        ref = _contract_chains_numpy(ex)
+        chain_id, head_row, c_parent, c_side = native.contract_chains(ex.parent, ex.side)
+        for got, want in ((chain_id, ref.chain_id), (head_row, ref.head_row),
+                          (c_parent, ref.parent), (c_side, ref.side)):
+            assert got.dtype == want.dtype == np.int32
+            np.testing.assert_array_equal(got, want)
+        assert 1 < ref.n_chains < ex.n  # some runs contracted, not all one
+        pad_c, pad_n = ref.n_chains + 11, ex.n + 6
+        row, want = np.empty((2, packed_row_bytes(pad_c, pad_n)), np.uint8)
+        assert native.pack_chain_row(
+            c_parent, c_side, ref.valid, head_row, chain_id, ex.content,
+            ex.deleted, ex.valid, pad_c, pad_n, row)
+        pack_chain_doc_into(chain_columns(ex, pad_n=pad_n, pad_c=pad_c, chains=ref), want)
+        assert row.tobytes() == want.tobytes()
+
+
 class TestRowTableFallback:
     """The direct-address RowTable fast path falls back to the
     open-addressing IdMap when counters are too sparse for its budget;

@@ -460,6 +460,55 @@ def test_import_paths_give_their_span_trees_and_the_same_answers():
     assert obs.counter("packed.launches_total").total() >= 2
 
 
+def test_contract_and_pack_keep_their_spans_and_count_their_native_calls():
+    """``import_host_contract_ms``, ``stream_decode_contract_ms`` and
+    ``stream_decode_pack_ms`` read these four spans by name: one
+    ``fleet.contract`` and one ``fleet.pack`` a call (around every
+    document's), one ``packed.contract`` and one ``packed.pack`` a
+    document; under them one native call a document and a stage."""
+    from loro_tpu.core.ids import ContainerID, ContainerType
+    from loro_tpu.ops.fugue_batch import merge_text_payloads_packed
+    from loro_tpu.parallel.fleet import Fleet
+    from loro_tpu.parallel.mesh import make_mesh
+
+    cid = ContainerID.root("text", ContainerType.Text)
+    payloads = [_payload(i)[0] for i in range(4)]
+    fleet = Fleet(make_mesh(jax.devices()[:1]))
+    calls = obs.counter("codec.native_chain_calls_total")
+    fallbacks = obs.counter("fleet.host_fallback_total")
+
+    def counts():
+        return calls.get(fn="contract"), calls.get(fn="pack"), fallbacks.total()
+
+    def names(spans):
+        return [e["name"] for e in spans]
+
+    tracing.clear()
+    tracing.enable()
+    try:
+        c0 = counts()
+        for _ in range(2):
+            fleet.merge_text_payloads(payloads, cid)
+        fleet_names, c1 = names(tracing.events()), counts()
+        tracing.clear()
+        merge_text_payloads_packed([(p, 1) for p in payloads], cid, 128, 512, 2, 4)
+        packed, c2 = tracing.events(), counts()
+    finally:
+        tracing.disable()
+        tracing.clear()
+    assert fleet_names.count("fleet.contract") == fleet_names.count("fleet.pack") == 2
+    assert (c1[0] - c0[0], c1[1] - c0[1]) == (8, 8) and c1[2] == c0[2]
+    packed_names = names(packed)
+    assert packed_names.count("packed.contract") == packed_names.count("packed.pack") == 4
+    assert (c2[0] - c1[0], c2[1] - c1[1]) == (4, 4) and c2[2] == c0[2]
+    # each a child of its own document's decode, on that document's thread
+    by_id = {e["span_id"]: e for e in packed}
+    for stage in ("packed.contract", "packed.pack"):
+        ups = [by_id[e["parent_id"]] for e in packed if e["name"] == stage]
+        assert sorted(u["args"]["doc"] for u in ups) == [0, 1, 2, 3]
+        assert all(u["name"] == "packed.decode_one" for u in ups)
+
+
 def _tree_payload(i: int):
     """A full-history payload of two replicas that move concurrently (one
     pair of moves makes a cycle), and the tree they converge on."""

@@ -1157,19 +1157,21 @@ class DeviceDocBatch:
         )
         with self._dev_lock:
             sh = doc_sharding(self.mesh)
-            blk_dev = {f: jax.device_put(v, sh) for f, v in blk.items()}
-            blk_dev["key_hi"] = jax.device_put(key_blk_hi, sh)
-            blk_dev["key_lo"] = jax.device_put(key_blk_lo, sh)
-            packed = _scatter_rows(
-                (self.cols, self.key_hi, self.key_lo),
-                blk_dev,
-                jax.device_put(
-                    np.asarray(offsets, np.int32), replicated(self.mesh)
-                ),
-            )
-            self.cols, self.key_hi, self.key_lo = packed
-            if renumbered:
-                self._upload_renumbered_keys(list(renumbered), key_snap)
+            with tracing.span("resident.upload"):
+                blk_dev = {f: jax.device_put(v, sh) for f, v in blk.items()}
+                blk_dev["key_hi"] = jax.device_put(key_blk_hi, sh)
+                blk_dev["key_lo"] = jax.device_put(key_blk_lo, sh)
+            with tracing.span("resident.scatter", renumbered=len(renumbered)):
+                packed = _scatter_rows(
+                    (self.cols, self.key_hi, self.key_lo),
+                    blk_dev,
+                    jax.device_put(
+                        np.asarray(offsets, np.int32), replicated(self.mesh)
+                    ),
+                )
+                self.cols, self.key_hi, self.key_lo = packed
+                if renumbered:
+                    self._upload_renumbered_keys(list(renumbered), key_snap)
 
     def _upload_renumbered_keys(self, renumbered, key_snap=None) -> None:
         """Renumbered docs: re-upload whole key rows in ONE jitted
@@ -1718,18 +1720,20 @@ class DeviceDocBatch:
                 sum(n_new), family="text" if self.as_text else "list"
             )
             blk_shape = (self.d, max_new)
-            blk = {
-                "parent": np.full(blk_shape, -1, np.int32),
-                "side": np.zeros(blk_shape, np.int32),
-                "peer_hi": np.zeros(blk_shape, np.uint32),
-                "peer_lo": np.zeros(blk_shape, np.uint32),
-                "counter": np.zeros(blk_shape, np.int32),
-                "deleted": np.ones(blk_shape, bool),
-                "content": np.full(blk_shape, -1, np.int32),
-                "valid": np.zeros(blk_shape, bool),
-            }
-            key_blk_hi = np.full(blk_shape, 0xFFFFFFFF, np.uint32)
-            key_blk_lo = np.full(blk_shape, 0xFFFFFFFF, np.uint32)
+            # 34 B a row: the eight columns (26 B) and the two key words
+            with tracing.span("resident.stage", bytes=34 * self.d * max_new):
+                blk = {
+                    "parent": np.full(blk_shape, -1, np.int32),
+                    "side": np.zeros(blk_shape, np.int32),
+                    "peer_hi": np.zeros(blk_shape, np.uint32),
+                    "peer_lo": np.zeros(blk_shape, np.uint32),
+                    "counter": np.zeros(blk_shape, np.int32),
+                    "deleted": np.ones(blk_shape, bool),
+                    "content": np.full(blk_shape, -1, np.int32),
+                    "valid": np.zeros(blk_shape, bool),
+                }
+                key_blk_hi = np.full(blk_shape, 0xFFFFFFFF, np.uint32)
+                key_blk_lo = np.full(blk_shape, 0xFFFFFFFF, np.uint32)
             offsets = np.zeros(self.d, np.int32)
             renumbered: List[int] = []
 
@@ -1791,17 +1795,19 @@ class DeviceDocBatch:
                 else 1,
                 max(1, len(active)),
             )
-            if n_threads > 1:
-                from concurrent.futures import ThreadPoolExecutor
+            with tracing.span("resident.order", docs=len(active),
+                              workers=n_threads):
+                if n_threads > 1:
+                    from concurrent.futures import ThreadPoolExecutor
 
-                with ThreadPoolExecutor(max_workers=n_threads) as pool:
-                    for di, renum in zip(active, pool.map(_ingest_doc, active)):
-                        if renum:
+                    with ThreadPoolExecutor(max_workers=n_threads) as pool:
+                        for di, renum in zip(active, pool.map(_ingest_doc, active)):
+                            if renum:
+                                renumbered.append(di)
+                else:
+                    for di in active:
+                        if _ingest_doc(di):
                             renumbered.append(di)
-            else:
-                for di in active:
-                    if _ingest_doc(di):
-                        renumbered.append(di)
             if self._defer is not None:
                 # coalesced group: stash the block; flush_coalesce ships
                 # every round's segments in one merged scatter
@@ -1851,6 +1857,14 @@ class DeviceDocBatch:
             raise
 
     def _append_payloads_staged(self, per_doc_payloads, cid) -> None:
+        with tracing.span("resident.decode"):
+            staged = self._decode_payloads_staged(per_doc_payloads, cid)
+        self._commit_rows(*staged)
+
+    def _decode_payloads_staged(self, per_doc_payloads, cid) -> tuple:
+        """The per-document half of the native ingest: tables, the C++
+        delta explode, id-map staging and lookups.  Returns
+        ``_commit_rows``' arguments."""
         from ..codec.binary import decode_changes, read_tables
         from ..native import (
             decode_value_at,
@@ -1962,7 +1976,7 @@ class DeviceDocBatch:
                     di, decode_changes(payload), cid, rows, overlay, del_pairs,
                     stage, vstage,
                 )
-        self._commit_rows(rows_per_doc, overlays, del_pairs, anchor_stages, value_stages)
+        return rows_per_doc, overlays, del_pairs, anchor_stages, value_stages
 
     def mark_deleted(self, pairs) -> None:
         """Tombstone (doc, rows) entries (delete ops referencing earlier
@@ -1977,10 +1991,12 @@ class DeviceDocBatch:
         acked (which would let compact() reclaim a never-propagated
         delete).  Runs after all ingest validation, so a failed append
         leaves the clock untouched."""
-        from ..ops.fugue_batch import pad_bucket
-
         if not pairs:
             return
+        with tracing.span("resident.tombstone"):
+            self._mark_deleted(pairs)
+
+    def _mark_deleted(self, pairs) -> None:
         self.epoch += 1
         d_parts: List[np.ndarray] = []
         r_parts: List[np.ndarray] = []
@@ -2005,6 +2021,9 @@ class DeviceDocBatch:
         n = len(d_all)
         if not n:
             return
+        obs.counter("fleet.resident_tombstones_total").inc(
+            n, family="text" if self.as_text else "list"
+        )
         # date the tombstones: compact() may reclaim them once every
         # replica has acked this epoch
         self.tomb_epoch[d_all, r_all] = self.epoch
@@ -2037,8 +2056,12 @@ class DeviceDocBatch:
 
         obs.counter("fleet.device_launches_total").inc(family="resident_materialize")
         if not use_solver:
-            codes, counts = materialize_by_key(self.cols, self.key_hi, self.key_lo)
-            return np.asarray(codes), np.asarray(counts)
+            with tracing.span("resident.materialize", docs=self.d, rows=self.cap):
+                codes, counts = jax.block_until_ready(
+                    materialize_by_key(self.cols, self.key_hi, self.key_lo)
+                )
+            with tracing.span("resident.fetch", bytes=codes.nbytes):
+                return np.asarray(codes), np.asarray(counts)
         while True:
             codes, counts, n_chains = chain_merge_docs_u(self.cols, self._c_pad)
             max_chains = int(np.asarray(n_chains).max()) if self.d else 0

@@ -338,6 +338,18 @@ class ResidentServer:
         entries host-side instead of mis-routing the change lists
         through the payload path (where a TypeError escaped the
         per-doc fallback)."""
+        # one trace id a round, and the count of its documents, only
+        # where a record is kept: a round with tracing off pays one flag
+        told = {}
+        if tracing.is_enabled():
+            told = {
+                "trace_id": tracing.current() or tracing.new_trace_id("s"),
+                "docs": sum(1 for u in per_doc_updates if u is not None),
+            }
+        with tracing.span("server.ingest", **told):
+            return self._ingest_round(per_doc_updates, cid)
+
+    def _ingest_round(self, per_doc_updates: Sequence, cid) -> int:
         if getattr(self, "_durable_closed", False):
             from ..errors import PersistError
 
@@ -532,11 +544,15 @@ class ResidentServer:
                 # committing thread (the pipeline/fan-in set it from
                 # the round-leading push) and the leader wall clock —
                 # a follower turns the stamp into measured apply lag
-                self._durable.append_round(
-                    epoch, cid, frozen,
-                    trace=tracing.current(),
-                    stamp_us=int(self._wall() * 1e6),
-                )
+                told = {}
+                if tracing.is_enabled():
+                    told = {"bytes": sum(len(u) for u in frozen if u is not None)}
+                with tracing.span("server.journal", epoch=epoch, **told):
+                    self._durable.append_round(
+                        epoch, cid, frozen,
+                        trace=tracing.current(),
+                        stamp_us=int(self._wall() * 1e6),
+                    )
             except BaseException as e:
                 from ..errors import FencedLeader, PersistError
 
@@ -586,7 +602,8 @@ class ResidentServer:
         if self._durable is None:
             return 0
         try:
-            n = self._durable.sync()
+            with tracing.span("server.fsync"):
+                n = self._durable.sync()
         except BaseException as e:
             from ..errors import PersistError
 

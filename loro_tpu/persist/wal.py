@@ -547,6 +547,9 @@ class WriteAheadLog:
             buckets=_BYTE_BUCKETS,
         ).observe(len(payload))
         obs.counter("persist.wal_records_total").inc(rtype=rtype)
+        obs.counter(
+            "persist.wal_bytes_appended_total", "WAL frame bytes appended"
+        ).inc(_FRAME_HDR + len(payload), rtype=rtype)
         a = self._active
         a.size = a.good_bytes = a.good_bytes + _FRAME_HDR + len(payload)
         a.n_records += 1

@@ -1,5 +1,5 @@
 """Tier-1 entry for the benchmark's own tests (``benchmarks/tests``, CPU
-rehearsals at tiny sizes; ROADMAP D2): every case of its four modules is
+rehearsals at tiny sizes; ROADMAP D2): every case of its five modules is
 collected here under a class of its module's name, so that the tier-1
 command (``pytest tests/``) runs them and counts each.  The modules stay
 where the benchmark keeps them and still run on their own
@@ -34,3 +34,4 @@ TestBenchmark = _cases_of("test_benchmark")
 TestSpanReaders = _cases_of("test_span_readers")
 TestTree = _cases_of("test_tree")
 TestMovable = _cases_of("test_movable")
+TestResident = _cases_of("test_resident")

@@ -1072,10 +1072,12 @@ class DeviceDocBatch:
         tombstone launch.  Per-doc row segments across rounds are
         contiguous (appends only ever extend the tail), so the merged
         block is each doc's concatenated segments at its group-start
-        offset.  Never grows: a grow here would race the next group's
-        host staging (epoch arrays repack) — a merged window that
-        outgrew capacity by bucket rounding falls back to per-round
-        scatters, each already validated at stage time."""
+        offset — one block row per document of the UNION of the
+        documents the group's rounds name (``_scatter_rows``' layout).
+        Never grows: a grow here would race the next group's host
+        staging (epoch arrays repack) — a merged window that outgrew
+        capacity by bucket rounding falls back to per-round scatters,
+        each already validated at stage time."""
         from ..ops.fugue_batch import pad_bucket
 
         if d is None:
@@ -1083,28 +1085,25 @@ class DeviceDocBatch:
         with self._dev_lock:
             if d.rounds:
                 total = np.zeros(self.d, np.int64)
-                for _blk, _kh, _kl, n_new in d.rounds:
-                    total += np.asarray(n_new, np.int64)
+                for _blk, _kh, _kl, docs, r_new in d.rounds:
+                    total[docs] += r_new
+                named = np.flatnonzero(total)
                 width = pad_bucket(int(total.max()), floor=16)
-                need = max(
-                    (int(d.base0[di]) + width
-                     for di in range(self.d) if total[di]),
-                    default=0,
-                )
+                need = int((d.base0[named] + width).max())
                 if need > self.cap:
                     off = d.base0.astype(np.int64).copy()
-                    for blk, kh, kl, n_new in d.rounds:
+                    for blk, kh, kl, docs, r_new in d.rounds:
                         self._device_commit_block(
-                            blk, kh, kl, off.astype(np.int32), n_new,
+                            blk, kh, kl, docs, off[docs], int(r_new.sum()),
                             renumbered=(),
                         )
-                        off += np.asarray(n_new, np.int64)
+                        off[docs] += r_new
                     if d.renumbered:
                         self._upload_renumbered_keys(
                             sorted(d.renumbered), d.key_snap
                         )
                 else:
-                    blk_shape = (self.d, width)
+                    blk_shape = (_named_bucket(len(named)), width)
                     blk = {
                         f: np.full(blk_shape, fill,
                                    dtype=d.rounds[0][0][f].dtype)
@@ -1112,20 +1111,21 @@ class DeviceDocBatch:
                     }
                     khc = np.full(blk_shape, 0xFFFFFFFF, np.uint32)
                     klc = np.full(blk_shape, 0xFFFFFFFF, np.uint32)
-                    pos = np.zeros(self.d, np.int64)
-                    for rblk, rkh, rkl, n_new in d.rounds:
-                        for di, k in enumerate(n_new):
-                            if not k:
-                                continue
-                            p = int(pos[di])
+                    row_of = np.zeros(self.d, np.int64)
+                    row_of[named] = np.arange(len(named))
+                    pos = np.zeros(len(named), np.int64)
+                    for rblk, rkh, rkl, docs, r_new in d.rounds:
+                        for j, k in enumerate(r_new):
+                            i = int(row_of[docs[j]])
+                            p = int(pos[i])
                             for f in blk:
-                                blk[f][di, p : p + k] = rblk[f][di, :k]
-                            khc[di, p : p + k] = rkh[di, :k]
-                            klc[di, p : p + k] = rkl[di, :k]
-                            pos[di] += k
+                                blk[f][i, p : p + k] = rblk[f][j, :k]
+                            khc[i, p : p + k] = rkh[j, :k]
+                            klc[i, p : p + k] = rkl[j, :k]
+                            pos[i] += k
                     self._device_commit_block(
-                        blk, khc, klc, d.base0.astype(np.int32), total,
-                        sorted(d.renumbered), d.key_snap,
+                        blk, khc, klc, named, d.base0[named],
+                        int(total.sum()), sorted(d.renumbered), d.key_snap,
                     )
                 obs.counter("pipeline.coalesced_rounds_total").inc(
                     len(d.rounds), family="text" if self.as_text else "list"
@@ -1142,32 +1142,43 @@ class DeviceDocBatch:
         """Synchronous close-and-commit of the open group."""
         self.commit_detached(self.detach_coalesce())
 
-    def _device_commit_block(self, blk, key_blk_hi, key_blk_lo, offsets,
-                             n_new, renumbered, key_snap=None) -> None:
-        """The device tail of an append: one block scatter (+ whole-row
-        key re-uploads for renumbered docs).  Shared by the immediate
-        path and commit_detached."""
-        width = blk["valid"].shape[1]
+    def _device_commit_block(self, blk, key_blk_hi, key_blk_lo, named,
+                             offsets, n_rows, renumbered, key_snap=None) -> None:
+        """The device tail of an append: one scatter of the block of the
+        documents the round names (+ whole-row key re-uploads for
+        renumbered docs).  Block row ``j`` holds document ``named[j]``'s
+        new rows for ``offsets[j]``; the block's rows past ``len(named)``
+        are padding, which goes up under the document index -1 and is
+        dropped by ``_scatter_rows``; ``n_rows`` is the block's live
+        rows.  Shared by the immediate path and commit_detached.  On a
+        mesh of several devices the block goes up replicated — the named
+        documents lie on any of them — and each device writes those it
+        holds."""
+        k_pad, width = blk["valid"].shape
+        d_idx = np.full(k_pad, -1, np.int32)
+        d_idx[: len(named)] = named
+        off = np.zeros(k_pad, np.int32)
+        off[: len(named)] = offsets
         obs.counter("fleet.pad_waste_rows_total").inc(
-            int(self.d * width - int(np.sum(n_new))), family="resident_seq"
+            int(k_pad * width - n_rows), family="resident_seq"
         )
         obs.counter("fleet.device_launches_total").inc(family="resident_seq")
         obs.unique("fleet.padded_shapes_distinct").add(
-            ("resident_seq", self.d, width, self.cap)
+            ("resident_seq", k_pad, width, self.cap)
         )
         with self._dev_lock:
-            sh = doc_sharding(self.mesh)
-            with tracing.span("resident.upload"):
-                blk_dev = {f: jax.device_put(v, sh) for f, v in blk.items()}
-                blk_dev["key_hi"] = jax.device_put(key_blk_hi, sh)
-                blk_dev["key_lo"] = jax.device_put(key_blk_lo, sh)
+            rep = replicated(self.mesh)
+            with tracing.span("resident.upload", docs=len(named)):
+                blk_dev = {f: jax.device_put(v, rep) for f, v in blk.items()}
+                blk_dev["key_hi"] = jax.device_put(key_blk_hi, rep)
+                blk_dev["key_lo"] = jax.device_put(key_blk_lo, rep)
             with tracing.span("resident.scatter", renumbered=len(renumbered)):
                 packed = _scatter_rows(
                     (self.cols, self.key_hi, self.key_lo),
                     blk_dev,
-                    jax.device_put(
-                        np.asarray(offsets, np.int32), replicated(self.mesh)
-                    ),
+                    jax.device_put(d_idx, rep),
+                    jax.device_put(off, rep),
+                    self.mesh,
                 )
                 self.cols, self.key_hi, self.key_lo = packed
                 if renumbered:
@@ -1719,9 +1730,14 @@ class DeviceDocBatch:
             obs.counter("fleet.resident_rows_total").inc(
                 sum(n_new), family="text" if self.as_text else "list"
             )
-            blk_shape = (self.d, max_new)
+            # the block holds the documents the round NAMES: row j is
+            # document active[j]'s, the rows past them keep the fills
+            active = np.flatnonzero(n_new)
+            blk_new = np.asarray(n_new, np.int64)[active]
+            blk_shape = (_named_bucket(len(active)), max_new)
             # 34 B a row: the eight columns (26 B) and the two key words
-            with tracing.span("resident.stage", bytes=34 * self.d * max_new):
+            with tracing.span("resident.stage", docs=len(active),
+                              bytes=34 * blk_shape[0] * max_new):
                 blk = {
                     "parent": np.full(blk_shape, -1, np.int32),
                     "side": np.zeros(blk_shape, np.int32),
@@ -1734,15 +1750,16 @@ class DeviceDocBatch:
                 }
                 key_blk_hi = np.full(blk_shape, 0xFFFFFFFF, np.uint32)
                 key_blk_lo = np.full(blk_shape, 0xFFFFFFFF, np.uint32)
-            offsets = np.zeros(self.d, np.int32)
-            renumbered: List[int] = []
+            offsets = np.zeros(len(active), np.int32)
 
-            def _ingest_doc(di: int) -> bool:
-                """Per-doc host work (block fill + order append): writes
-                touch doc-disjoint slices/state only, and the native
-                order engine's ctypes call releases the GIL, so docs
-                shard across threads.  Returns True when the doc's keys
-                were renumbered (caller re-uploads the whole key row)."""
+            def _ingest_doc(j: int) -> bool:
+                """Per-doc host work (block fill + order append) for
+                block row ``j``: writes touch doc-disjoint slices/state
+                only, and the native order engine's ctypes call releases
+                the GIL, so docs shard across threads.  Returns True
+                when the doc's keys were renumbered (caller re-uploads
+                the whole key row)."""
+                di = int(active[j])
                 rows = rows_per_doc[di]
                 base = int(self.counts[di])
                 if isinstance(rows, dict):
@@ -1758,14 +1775,14 @@ class DeviceDocBatch:
                     pu = np.asarray([r[4] for r in rows], np.uint64)
                     parent, side_a = arr[:, 0], arr[:, 1]
                     ctr_a, content_a = arr[:, 2], arr[:, 3]
-                blk["parent"][di, :k] = parent
-                blk["side"][di, :k] = side_a
-                blk["peer_hi"][di, :k] = (pu >> np.uint64(32)).astype(np.uint32)
-                blk["peer_lo"][di, :k] = (pu & np.uint64(0xFFFFFFFF)).astype(np.uint32)
-                blk["counter"][di, :k] = ctr_a
-                blk["deleted"][di, :k] = False
-                blk["content"][di, :k] = content_a
-                blk["valid"][di, :k] = True
+                blk["parent"][j, :k] = parent
+                blk["side"][j, :k] = side_a
+                blk["peer_hi"][j, :k] = (pu >> np.uint64(32)).astype(np.uint32)
+                blk["peer_lo"][j, :k] = (pu & np.uint64(0xFFFFFFFF)).astype(np.uint32)
+                blk["counter"][j, :k] = ctr_a
+                blk["deleted"][j, :k] = False
+                blk["content"][j, :k] = content_a
+                blk["valid"][j, :k] = True
                 self.row_epoch[di, base : base + k] = self.epoch
                 keys = self.order[di].append_arrays(
                     parent, side_a, pu, ctr_a, base
@@ -1773,13 +1790,12 @@ class DeviceDocBatch:
                 renum = keys is None
                 if not renum:
                     kh, kl = split_keys(np.asarray(keys, np.int64))
-                    key_blk_hi[di, :k] = kh
-                    key_blk_lo[di, :k] = kl
-                offsets[di] = base
+                    key_blk_hi[j, :k] = kh
+                    key_blk_lo[j, :k] = kl
+                offsets[j] = base
                 self.counts[di] += k
                 return renum
 
-            active = [di for di, k in enumerate(n_new) if k]
             # thread fan-out only pays when the order engine is the
             # native one (ctypes releases the GIL); the Python
             # ShadowOrder fallback would serialize through the GIL and
@@ -1801,23 +1817,21 @@ class DeviceDocBatch:
                     from concurrent.futures import ThreadPoolExecutor
 
                     with ThreadPoolExecutor(max_workers=n_threads) as pool:
-                        for di, renum in zip(active, pool.map(_ingest_doc, active)):
-                            if renum:
-                                renumbered.append(di)
+                        renums = list(pool.map(_ingest_doc, range(len(active))))
                 else:
-                    for di in active:
-                        if _ingest_doc(di):
-                            renumbered.append(di)
+                    renums = [_ingest_doc(j) for j in range(len(active))]
+                renumbered = [int(di) for di, renum in zip(active, renums) if renum]
             if self._defer is not None:
                 # coalesced group: stash the block; flush_coalesce ships
                 # every round's segments in one merged scatter
                 self._defer.rounds.append(
-                    (blk, key_blk_hi, key_blk_lo, list(n_new))
+                    (blk, key_blk_hi, key_blk_lo, active, blk_new)
                 )
                 self._defer.renumbered.update(renumbered)
             else:
                 self._device_commit_block(
-                    blk, key_blk_hi, key_blk_lo, offsets, n_new, renumbered
+                    blk, key_blk_hi, key_blk_lo, active, offsets,
+                    int(blk_new.sum()), renumbered,
                 )
         self.mark_deleted(del_pairs)
 
@@ -3585,6 +3599,9 @@ class _DeferredSeqDevice:
 
     def __init__(self, base0: np.ndarray):
         self.base0 = base0          # per-doc counts at group start
+        # DeviceDocBatch: (blk, key_hi, key_lo, docs, rows) a round — the
+        # named block, the document and the live rows of each of its
+        # rows; DeviceTreeBatch: (blk, n_new), a row a slot
         self.rounds: List[tuple] = []
         self.renumbered: set = set()
         self.del_d: List[np.ndarray] = []
@@ -3651,20 +3668,83 @@ def _release_rows(arrays, di, fills):
     )
 
 
-@functools.partial(jax.jit, donate_argnums=(0,))
-def _scatter_rows(state, blk, offsets):
-    """Write each doc's new-row block at its per-doc offset (donated
-    update — the old buffer is reused, no [D, N] copy).  `state` is
-    (SeqColumnsU, key_hi, key_lo)."""
+# smallest block of named documents (as the key rows of
+# ``_upload_renumbered_keys``): bounds the programs a table compiles
+_NAMED_FLOOR = 4
+
+
+def _named_bucket(n_named: int) -> int:
+    """Rows of the scatter block of a round that names ``n_named``
+    documents."""
+    from ..ops.fugue_batch import pad_bucket
+
+    return pad_bucket(n_named, floor=_NAMED_FLOOR)
+
+
+@functools.partial(jax.jit, donate_argnums=(0,), static_argnums=(4,))
+def _scatter_rows(state, blk, d_idx, offsets, mesh):
+    """Write the block of the documents a round NAMES into the resident
+    table (donated update — the old buffers are reused).  `state` is
+    (SeqColumnsU, key_hi, key_lo), each ``[d, capacity]``; ``blk`` holds
+    the same ten arrays as ``[k_pad, max_new]``, row ``j`` being document
+    ``d_idx[j]``'s new rows for the window ``[offsets[j], offsets[j] +
+    max_new)``, replaced under ``valid`` (padding rows of a window keep
+    the window's previous values, as ``_windowed_scatter_field``).
+
+    One loop over the block's rows, each a ``dynamic_update_slice`` at
+    ``(d_idx[j], offsets[j])`` of every array: the bytes moved follow
+    ``k_pad * max_new`` — a named row touches its own document's window
+    and nothing else, and there is no ``[d, capacity]`` temporary.  One
+    compiled program per ``(k_pad, max_new, capacity)`` bucket.
+
+    **Pad entries** (``len(named)`` is not always at a bucket) carry the
+    document index -1, a document NO device holds, and are DROPPED: such
+    a row keeps nothing of the block (``valid & held`` is all false), so
+    what it writes is the window it has just read, at the clamped slot,
+    in its own turn of a SEQUENTIAL loop — it can never put an old
+    window over a real row's new values (what a pad row that repeated a
+    named document with ``valid`` all false would do in a parallel
+    scatter).
+
+    On a mesh of several devices the table is doc-sharded and the block,
+    ``d_idx`` and ``offsets`` are replicated: under ``shard_map`` each
+    device drops, by the same test, the rows of documents it does not
+    hold and writes its own."""
     cols, key_hi, key_lo = state
-    out = {}
-    for f in cols._fields:
-        out[f] = jax.vmap(_windowed_scatter_field)(
-            getattr(cols, f), blk[f], blk["valid"], offsets
+    fields = cols._fields
+    arrays = tuple(getattr(cols, f) for f in fields) + (key_hi, key_lo)
+    news = tuple(blk[f] for f in fields) + (blk["key_hi"], blk["key_lo"])
+    sharded = mesh.size > 1
+
+    def on_device(arrays, news, valid, d_idx, offsets):
+        d_local = arrays[0].shape[0]
+        lo = jax.lax.axis_index(DOC_AXIS) * d_local if sharded else 0
+        width = valid.shape[1]
+
+        def write_row(j, arrays):
+            di = d_idx[j] - lo
+            held = (di >= 0) & (di < d_local)
+            at = (jnp.clip(di, 0, d_local - 1), offsets[j])
+            keep = (valid[j] & held)[None]
+            out = []
+            for a, nb in zip(arrays, news):
+                window = jax.lax.dynamic_slice(a, at, (1, width))
+                row = jax.lax.dynamic_slice(nb, (j, 0), (1, width))
+                out.append(jax.lax.dynamic_update_slice(
+                    a, jnp.where(keep, row, window), at))
+            return tuple(out)
+
+        return jax.lax.fori_loop(0, d_idx.shape[0], write_row, arrays)
+
+    if sharded:
+        docs, rep = P(DOC_AXIS), P()
+        on_device = jax.shard_map(
+            on_device, mesh=mesh, in_specs=(docs, rep, rep, rep, rep),
+            out_specs=docs, check_vma=False,
         )
-    new_hi = jax.vmap(_windowed_scatter_field)(key_hi, blk["key_hi"], blk["valid"], offsets)
-    new_lo = jax.vmap(_windowed_scatter_field)(key_lo, blk["key_lo"], blk["valid"], offsets)
-    return type(cols)(**out), new_hi, new_lo
+    out = on_device(arrays, news, blk["valid"], d_idx, offsets)
+    n = len(fields)
+    return type(cols)(**dict(zip(fields, out[:n]))), out[n], out[n + 1]
 
 
 class DeviceMovableBatch:

@@ -274,6 +274,38 @@ def test_public_step_compiles_at_real_width(topo, tpu_branches, chains, elements
     compile_checked(f"Fleet.text_step:{transport}:[16,{pad_n}]:c{pad_c}", lowered, True)
 
 
+@pytest.mark.parametrize("devices,d,cap,k_pad,width", [
+    (1, 144, 262_144, 16, 262_144),   # b4_resident.coldstart16's round
+    (1, 4096, 16_384, 1024, 16),      # keystroke-sized rounds, a served table
+    (4, 144, 262_144, 16, 262_144),   # the table doc-sharded, the block replicated
+], ids=["coldstart16", "keystrokes", "mesh4"])
+def test_resident_scatter_moves_the_named_block_and_no_table(
+        topo, devices, d, cap, k_pad, width):
+    """``_scatter_rows`` (ISSUE 36): one loop over the block's rows, every
+    table buffer updated in place — the program's temporaries are smaller
+    than ONE table column, so there is no ``[d, capacity]`` copy and no
+    ``[k_pad, capacity]`` gather — and on four devices no all-gather."""
+    from loro_tpu.parallel.fleet import _scatter_rows
+
+    mesh = Mesh(np.array(topo.devices[:devices]).reshape(devices, 1),
+                (DOC_AXIS, OP_AXIS))
+    sh, rep = NamedSharding(mesh, P(DOC_AXIS)), NamedSharding(mesh, P())
+    state = (sequ_sds(d, cap, sh), sds((d, cap), jnp.uint32, sh),
+             sds((d, cap), jnp.uint32, sh))
+    blk = dict(zip(fb.SeqColumnsU._fields, sequ_sds(k_pad, width, rep)))
+    blk["key_hi"] = blk["key_lo"] = sds((k_pad, width), jnp.uint32, rep)
+    idx = sds((k_pad,), jnp.int32, rep)
+    compiled = _scatter_rows.lower(state, blk, idx, idx, mesh).compile()
+    mem, text = compiled.memory_analysis(), compiled.as_text()
+    table = 34 * (d // devices) * cap  # a device's share of the table
+    print(json.dumps({"compiled": f"_scatter_rows:{devices}:[{d},{cap}]:[{k_pad},{width}]",
+                      "temp_bytes": mem.temp_size_in_bytes,
+                      "alias_bytes": mem.alias_size_in_bytes, "table_bytes": table}))
+    assert mem.alias_size_in_bytes >= table  # donated: written in place
+    assert mem.temp_size_in_bytes < (d // devices) * cap  # under one bool column
+    assert "all-gather" not in text and text.count(" while(") == 1
+
+
 @pytest.mark.slow
 def test_resident_materialise_compiles_at_real_size(one_chip):
     """``serve``: materialize_by_key over the 4096 x 16,384 resident

@@ -1475,3 +1475,206 @@ class TestResidentErrorSurface:
         # the batch stays usable with the correct feeding order
         batch.append_changes([a.oplog.changes_in_causal_order()], a.get_list("l").id)
         assert batch.values() == [a.get_list("l").get_value()]
+
+
+def _device_state(batch):
+    """Every device array of a DeviceDocBatch on the host: the eight
+    columns and both key words."""
+    out = {f: np.asarray(getattr(batch.cols, f)) for f in batch.cols._fields}
+    out["key_hi"], out["key_lo"] = np.asarray(batch.key_hi), np.asarray(batch.key_lo)
+    return out
+
+
+def _one_device():
+    import jax
+
+    from loro_tpu.parallel.mesh import make_mesh
+
+    return make_mesh(jax.devices()[:1])
+
+
+def _all_devices():
+    from loro_tpu.parallel.mesh import make_mesh
+
+    return make_mesh()  # conftest: eight CPU devices, the table doc-sharded
+
+
+MESHES = {"one_device": _one_device, "mesh8": _all_devices}
+
+
+class TestNamedBlock:
+    """ISSUE 36: the resident round's scatter block holds the documents
+    the round NAMES (``[pad_bucket(named), pad_bucket(longest)]``), row
+    ``j`` being document ``d_idx[j]``'s — not a row a slot of the table.
+    Counts and equalities only."""
+
+    SLOTS = 8
+
+    def _docs(self):
+        docs = [LoroDoc(peer=101 + i) for i in range(self.SLOTS)]
+        return docs, docs[0].get_text("t").id
+
+    def _payload_round(self, docs, marks, named, edit):
+        from loro_tpu.doc import strip_envelope
+
+        updates = [None] * self.SLOTS
+        for j, di in enumerate(named):
+            edit(docs[di].get_text("t"), j)
+            docs[di].commit()
+            updates[di] = strip_envelope(docs[di].export_updates(marks[di]))
+            marks[di] = docs[di].oplog_vv()
+        return updates
+
+    @pytest.mark.parametrize("mesh", list(MESHES))
+    @pytest.mark.parametrize(
+        "named", [(2,), (0, 3, 6), (1, 2, 4, 5, 7), tuple(range(8))],
+        ids=["one", "three", "five_off_bucket", "all"])
+    def test_named_rounds_read_right_and_leave_unnamed_slots_bit_identical(
+            self, named, mesh):
+        docs, cid = self._docs()
+        batch = DeviceDocBatch(self.SLOTS, 256, mesh=MESHES[mesh]())
+        marks = [{} for _ in docs]
+        # every slot holds rows before the named rounds, so an unnamed
+        # slot has something to lose
+        batch.append_payloads(self._payload_round(
+            docs, marks, range(self.SLOTS),
+            lambda t, j: t.insert(0, "base%d " % j * (1 + j % 3))), cid)
+        unnamed = [di for di in range(self.SLOTS) if di not in named]
+        # twice: the second round lands at non-zero offsets of rows the
+        # first one wrote
+        for rnd in range(2):
+            before = _device_state(batch)
+            counts = batch.counts.copy()
+            batch.append_payloads(self._payload_round(
+                docs, marks, named,
+                lambda t, j: (t.insert(len(t) // 2, "xyz"[: 1 + j % 3] * (2 + j + rnd)),
+                              t.delete(0, 1))), cid)
+            after = _device_state(batch)
+            assert batch.texts() == [d.get_text("t").to_string() for d in docs]
+            for f in before:
+                assert np.array_equal(before[f][unnamed], after[f][unnamed]), f
+            # a named slot keeps every row it had (deletes only mark)
+            for di in named:
+                k = int(counts[di])
+                assert int(batch.counts[di]) > k
+                for f in before:
+                    if f not in ("deleted", "key_hi", "key_lo"):
+                        assert np.array_equal(before[f][di, :k], after[f][di, :k]), f
+
+    @pytest.mark.parametrize("mesh", list(MESHES))
+    def test_pad_rows_never_put_an_old_window_back(self, mesh):
+        """Three named documents make a block of four rows: one pad row.
+        The FIRST named document gets the longest update (the whole
+        window is its new rows) at a non-zero offset — a pad row that
+        named it again with ``valid`` all false would write the window
+        as it was before the round over them."""
+        docs, cid = self._docs()
+        batch = DeviceDocBatch(self.SLOTS, 256, mesh=MESHES[mesh]())
+        marks = [d.oplog_vv() for d in docs]
+
+        def changes(named, edit):
+            out = [None] * self.SLOTS
+            for j, di in enumerate(named):
+                edit(docs[di].get_text("t"), j)
+                docs[di].commit()
+                out[di] = _changes_between(docs[di], marks[di])
+                marks[di] = docs[di].oplog_vv()
+            return out
+
+        batch.append_changes(
+            changes(range(self.SLOTS), lambda t, j: t.insert(0, "seed")), cid)
+        named = (1, 4, 6)
+        batch.append_changes(changes(
+            named, lambda t, j: t.insert(2, "L" * 64 if j == 0 else "s")), cid)
+        assert batch.texts() == [d.get_text("t").to_string() for d in docs]
+        assert batch.texts()[1] == "se" + "L" * 64 + "ed"
+
+    def test_the_block_is_as_large_as_what_the_round_names(self):
+        """A 2-of-8 round: ``resident.stage``'s ``bytes`` and the pad
+        waste counter are the block's — 34 B a row of
+        ``pad_bucket(named) x pad_bucket(longest)`` — and both spans say
+        how many documents were named."""
+        from loro_tpu.obs import metrics as obs
+        from loro_tpu.ops.fugue_batch import pad_bucket
+        from loro_tpu.parallel.fleet import _named_bucket
+        from loro_tpu.utils import tracing
+
+        docs, cid = self._docs()
+        batch = DeviceDocBatch(self.SLOTS, 256, mesh=_one_device())
+        marks = [{} for _ in docs]
+        batch.append_payloads(self._payload_round(
+            docs, marks, range(self.SLOTS), lambda t, j: t.insert(0, "base")), cid)
+        waste = obs.counter("fleet.pad_waste_rows_total")
+        rows = obs.counter("fleet.resident_rows_total")
+        w0, r0 = waste.get(family="resident_seq"), rows.get(family="text")
+        updates = self._payload_round(
+            docs, marks, (2, 5), lambda t, j: t.insert(1, "q" * (20 if j else 3)))
+        tracing.clear()
+        tracing.enable()
+        try:
+            batch.append_payloads(updates, cid)
+            spans = {e["name"]: e["args"] for e in tracing.events()}
+        finally:
+            tracing.disable()
+            tracing.clear()
+        k_pad = _named_bucket(2)
+        width = pad_bucket(20, floor=16)
+        assert (k_pad, width) == (4, 32)
+        assert spans["resident.stage"]["bytes"] == 34 * k_pad * width
+        assert spans["resident.stage"]["docs"] == 2
+        assert spans["resident.upload"]["docs"] == 2
+        assert rows.get(family="text") - r0 == 23
+        assert waste.get(family="resident_seq") - w0 == k_pad * width - 23
+        assert batch.texts() == [d.get_text("t").to_string() for d in docs]
+
+    @pytest.mark.parametrize("mesh", list(MESHES))
+    def test_scatter_rows_against_a_numpy_walk(self, mesh):
+        """``_scatter_rows`` alone, held bit for bit to the plain
+        statement of what it does: for each named block row, the window
+        at the row's offset of that document, replaced under ``valid``;
+        nothing else moves.  The block has pad rows (document -1) whose
+        contents would be seen if they were written."""
+        import jax
+
+        from loro_tpu.ops.fugue_batch import SeqColumnsU
+        from loro_tpu.parallel.fleet import _named_bucket, _scatter_rows
+        from loro_tpu.parallel.mesh import doc_sharding, replicated
+
+        m = MESHES[mesh]()
+        rng = np.random.default_rng(36)
+        d, cap, width = 16, 128, 32
+        fields = SeqColumnsU._fields + ("key_hi", "key_lo")
+        table = {}
+        for f in fields:
+            dt = bool if f in ("deleted", "valid") else (
+                np.int32 if f in ("parent", "side", "counter", "content") else np.uint32)
+            table[f] = rng.integers(0, 2 if dt is bool else 1 << 20, (d, cap)).astype(dt)
+        named = [13, 2, 7, 8, 3]  # not at a bucket, not sorted: 3 pad rows
+        d_idx = np.full(_named_bucket(len(named)), -1, np.int32)
+        d_idx[: len(named)] = named
+        blk = {f: rng.integers(0, 2 if a.dtype == bool else 1 << 20,
+                               (len(d_idx), width)).astype(a.dtype)
+               for f, a in table.items()}
+        n_rows = [32, 1, 17, 5, 30]
+        blk["valid"][:] = True  # pad rows too: they must be dropped by index
+        for j, k in enumerate(n_rows):
+            blk["valid"][j, k:] = False
+        offsets = np.zeros(len(d_idx), np.int32)
+        offsets[: len(named)] = [cap - width, 0, 40, 96, 11]
+        want = {f: a.copy() for f, a in table.items()}
+        for j, di in enumerate(named):
+            sl = slice(int(offsets[j]), int(offsets[j]) + n_rows[j])
+            for f in fields:
+                want[f][di, sl] = blk[f][j, : n_rows[j]]
+        sh, rep = doc_sharding(m), replicated(m)
+        put = lambda a, s: jax.device_put(a, s)
+        cols = SeqColumnsU(**{f: put(table[f], sh) for f in SeqColumnsU._fields})
+        out_cols, hi, lo = _scatter_rows(
+            (cols, put(table["key_hi"], sh), put(table["key_lo"], sh)),
+            {f: put(a, rep) for f, a in blk.items()},
+            put(d_idx, rep), put(offsets, rep), m)
+        got = {f: np.asarray(getattr(out_cols, f)) for f in SeqColumnsU._fields}
+        got["key_hi"], got["key_lo"] = np.asarray(hi), np.asarray(lo)
+        for f in fields:
+            assert np.array_equal(got[f], want[f]), f
+        assert out_cols.valid.sharding.is_equivalent_to(sh, 2)

@@ -308,3 +308,85 @@ def _serial_epochs(rounds, cid):
     rounds (the ack-parity oracle for the pipelined path)."""
     srv = ResidentServer("text", 1, capacity=1 << 12)
     return [srv.ingest(list(r), cid) for r in rounds]
+
+
+class TestCoalescedNamedBlocks:
+    """ISSUE 36: a coalesced group's merged block is over the UNION of
+    the documents its rounds name, and its over-capacity fallback ships
+    each round's own named block.  Both against the serial path, array
+    for array."""
+
+    SLOTS = 8
+    # (slot, characters) a round: disjoint sets, then overlapping ones
+    # (slot 1 three times, slot 3 twice), then a slot alone.  Slot 1's
+    # 35 rows merge into a window of 64 at offset 5: over a capacity of
+    # 64, where each round's own window of 32 fits
+    ROUNDS = [
+        [(0, 20), (1, 3)],
+        [(4, 7), (6, 2)],
+        [(1, 20), (3, 5), (4, 1)],
+        [(1, 12), (3, 20)],
+        [(7, 9)],
+    ]
+
+    def _rounds(self, docs, marks):
+        out = []
+        for rnd in self.ROUNDS:
+            changes = [None] * self.SLOTS
+            for di, n in rnd:
+                t = docs[di].get_text("t")
+                t.insert(len(t) // 2, "abcdefghijklmnopqrst"[:n])
+                if len(t) > 6:
+                    t.delete(1, 2)
+                docs[di].commit()
+                # payload bytes: a live change list would grow with the
+                # document's later edits
+                changes[di] = strip_envelope(docs[di].export_updates(marks[di]))
+                marks[di] = docs[di].oplog_vv()
+            out.append(changes)
+        return out
+
+    @pytest.mark.parametrize("capacity,launches", [(256, 1), (64, 5)],
+                             ids=["merged", "per_round_fallback"])
+    @pytest.mark.parametrize("mesh", ["one_device", "mesh8"])
+    def test_group_over_disjoint_and_overlapping_documents(
+            self, mesh, capacity, launches):
+        import jax
+        import numpy as np
+
+        from loro_tpu.parallel.fleet import DeviceDocBatch
+        from loro_tpu.parallel.mesh import make_mesh
+
+        m = make_mesh(jax.devices()[:1] if mesh == "one_device" else None)
+        docs = [LoroDoc(peer=61 + i) for i in range(self.SLOTS)]
+        cid = docs[0].get_text("t").id
+        for di, d in enumerate(docs):
+            d.get_text("t").insert(0, "0123456789"[: 4 + di])
+            d.commit()
+        base = [strip_envelope(d.export_updates({})) for d in docs]
+        marks = [d.oplog_vv() for d in docs]
+        rounds = self._rounds(docs, marks)
+        serial = DeviceDocBatch(self.SLOTS, capacity, mesh=m)
+        grouped = DeviceDocBatch(self.SLOTS, capacity, mesh=m)
+        for b in (serial, grouped):
+            b.append_payloads(base, cid)
+        for r in rounds:
+            serial.append_payloads(r, cid)
+        c = obs.counter("fleet.device_launches_total")
+        n0 = c.get(family="resident_seq")
+        grouped.begin_coalesce()
+        for r in rounds:
+            grouped.append_payloads(r, cid)
+        pending = grouped.detach_coalesce()
+        assert [list(r[3]) for r in pending.rounds] == [
+            [di for di, _n in r] for r in self.ROUNDS]
+        grouped.commit_detached(pending)
+        assert c.get(family="resident_seq") - n0 == launches
+        assert grouped.texts() == serial.texts() == [
+            d.get_text("t").to_string() for d in docs]
+        for f in serial.cols._fields:
+            assert np.array_equal(np.asarray(getattr(grouped.cols, f)),
+                                  np.asarray(getattr(serial.cols, f))), f
+        assert np.array_equal(np.asarray(grouped.key_hi), np.asarray(serial.key_hi))
+        assert np.array_equal(np.asarray(grouped.key_lo), np.asarray(serial.key_lo))
+        assert grouped.export_state() == serial.export_state()

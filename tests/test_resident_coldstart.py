@@ -176,6 +176,43 @@ def test_every_span_once_a_round_under_one_trace_id_and_the_counters_tick(
     assert n_rounds == 2 and fsyncs == 2 and wal_bytes > payload_bytes
 
 
+@pytest.mark.parametrize("slots", [SLOTS, 3 * SLOTS])
+def test_a_rounds_block_follows_the_documents_it_names_not_the_table(
+        tmp_path, mesh, documents, slots):
+    """ISSUE 36: what a round stages, uploads and scatters is
+    ``pad_bucket(named) x pad_bucket(longest)`` rows of 34 B whatever the
+    table holds — the same count in a table three times as large."""
+    from loro_tpu.ops.fugue_batch import pad_bucket
+    from loro_tpu.parallel.fleet import _named_bucket
+
+    fed, want = documents[SEEDS[0]]
+    server = ResidentServer("text", slots, mesh=mesh, capacity=CAPACITY,
+                            durable_dir=str(tmp_path), durable_fsync="group")
+    waste = obs.counter("fleet.pad_waste_rows_total")
+    tracing.clear()
+    tracing.enable()
+    try:
+        w0 = waste.get(family="resident_seq")
+        updates = [None] * slots
+        for s in range(K):
+            updates[s] = fed[s % len(fed)]["payload"]
+        server.ingest(updates, CID)
+        args = {e["name"]: e["args"] for e in tracing.events()}
+        texts = server.texts()
+    finally:
+        tracing.disable()
+        tracing.clear()
+        server.close()
+    assert texts[:K] == [want[s % len(want)] for s in range(K)]
+    assert texts[K:] == [""] * (slots - K)
+    k_pad = _named_bucket(K)
+    width = pad_bucket(TINY["insert_patches"], floor=16)
+    assert args["resident.stage"] == {"docs": K, "bytes": 34 * k_pad * width}
+    assert args["resident.upload"] == {"docs": K}
+    assert waste.get(family="resident_seq") - w0 == (
+        k_pad * width - K * TINY["insert_patches"])
+
+
 def test_without_the_library_the_answers_are_the_same_and_the_fallbacks_tick(
         tmp_path, mesh, documents, monkeypatch):
     fed, want = documents[SEEDS[1]]

@@ -22,11 +22,13 @@ every entry they run with the interpreter lock released, tick
 from __future__ import annotations
 
 import ctypes
+import functools
 import glob
 import hashlib
 import os
 import subprocess
 import threading
+import time
 from typing import Optional
 
 import numpy as np
@@ -101,6 +103,28 @@ def _obs_decode(fn: str, payload: bytes) -> bytes:
     _obs.counter("codec.native_decode_calls_total").inc(fn=fn)
     _obs.counter("codec.native_decode_bytes_total").inc(len(payload), fn=fn)
     return payload
+
+
+def _decode_ns(fn: str):
+    """Decorator of an explode entry: the wall time of every call that
+    decoded (the library there, no error) into
+    ``codec.native_decode_ns_total{fn}``, beside ``_obs_decode``'s calls
+    and bytes — a run, traced or not, says ns a byte, and what of a span
+    around the entry is the C++ decode."""
+
+    def wrap(entry):
+        @functools.wraps(entry)
+        def timed(*args, **kw):
+            t0 = time.perf_counter_ns()
+            out = entry(*args, **kw)
+            if out is not None:
+                _obs.counter("codec.native_decode_ns_total").inc(
+                    time.perf_counter_ns() - t0, fn=fn)
+            return out
+
+        return timed
+
+    return wrap
 
 
 def _load() -> Optional[ctypes.CDLL]:
@@ -276,6 +300,7 @@ def require() -> None:
         )
 
 
+@_decode_ns("seq")
 def explode_seq_payload(payload: bytes, target_cid_index: int):
     """Parse a binary updates payload and return the element table of
     the target sequence container as numpy columns
@@ -314,6 +339,7 @@ def explode_seq_payload(payload: bytes, target_cid_index: int):
         return parent, side, peer, counter, deleted.astype(bool), content
 
 
+@_decode_ns("seq_delta")
 def explode_seq_delta_payload(payload: bytes, target_cid_index: int):
     """Incremental decode: element rows whose cross-payload parents come
     back as (peer_idx, counter) for host resolution (out_parent == -2),
@@ -372,6 +398,7 @@ def explode_seq_delta_payload(payload: bytes, target_cid_index: int):
     }
 
 
+@_decode_ns("seq_anchor")
 def explode_seq_anchor_meta(payload: bytes, target_cid_index: int):
     """Style-anchor metadata in the same row numbering as
     explode_seq_delta_payload (host pairs anchors to device rows by the
@@ -409,6 +436,7 @@ def explode_seq_anchor_meta(payload: bytes, target_cid_index: int):
     return {"row": row, "key_idx": key, "voffset": voff, "lamport": lam, "flags": flags}
 
 
+@_decode_ns("map")
 def explode_map_payload(payload: bytes):
     """All MapSet/MapDel rows of a payload, or None when the native
     library is unavailable.  Returns a dict with numpy columns
@@ -476,6 +504,7 @@ def decode_value_at(payload: bytes, offset: int, cids):
     return _read_value(r, cids)
 
 
+@_decode_ns("tree")
 def explode_tree_payload(payload: bytes, target_cid_index: int):
     """All TreeMove rows of one container (wire order) as numpy
     columns, or None when the native library is unavailable.  Peer
@@ -513,6 +542,7 @@ def explode_tree_payload(payload: bytes, target_cid_index: int):
         return cols
 
 
+@_decode_ns("movable")
 def explode_movable_payload(payload: bytes, target_cid_index: int):
     """Slots / sets / delete spans of one MovableList container, or
     None when unavailable.  Raises ValueError on malformed input or
@@ -573,6 +603,7 @@ def explode_movable_payload(payload: bytes, target_cid_index: int):
     return {"slots": slots, "sets": sets, "dels": dels}
 
 
+@_decode_ns("movable_delta")
 def explode_movable_delta_payload(payload: bytes, target_cid_index: int):
     """Delta variant of explode_movable_payload: slot parents that don't
     resolve inside the payload come back as parent == -2 with
@@ -789,11 +820,12 @@ class NativeIdMap:
     Bit-compatible drop-in for the per-doc id2row dicts — the per-row
     Python dict traffic was the r4 host-funnel cost center."""
 
-    __slots__ = ("_lib", "_h")
+    __slots__ = ("_lib", "_h", "_staged")
 
     def __init__(self, lib):
         self._lib = lib
         self._h = lib.loro_idmap_new()
+        self._staged = 0  # ids staged since the last commit or abort
 
     def __del__(self):
         h = getattr(self, "_h", None)
@@ -848,6 +880,7 @@ class NativeIdMap:
         )
 
     def stage_base(self, peer, ctr, base_row: int) -> None:
+        t0 = time.perf_counter_ns()
         peer = np.ascontiguousarray(peer, np.uint64)
         ctr = np.ascontiguousarray(ctr, np.int64)
         self._lib.loro_idmap_stage(
@@ -857,9 +890,12 @@ class NativeIdMap:
             ctr.ctypes.data_as(ctypes.c_void_p),
             base_row,
         )
+        self._staged += len(peer)
+        _idmap_tick("stage", len(peer), t0)
 
     def lookup(self, peer, ctr) -> np.ndarray:
         """Staged-first batch lookup; -1 = missing."""
+        t0 = time.perf_counter_ns()
         peer = np.ascontiguousarray(peer, np.uint64)
         ctr = np.ascontiguousarray(ctr, np.int64)
         out = np.empty(len(peer), np.int32)
@@ -870,13 +906,28 @@ class NativeIdMap:
             ctr.ctypes.data_as(ctypes.c_void_p),
             out.ctypes.data_as(ctypes.c_void_p),
         )
+        _idmap_tick("lookup", len(peer), t0)
         return out
 
     def commit(self) -> None:
+        t0 = time.perf_counter_ns()
         self._lib.loro_idmap_commit(self._h)
+        staged, self._staged = self._staged, 0
+        _idmap_tick("commit", staged, t0)
 
     def abort(self) -> None:
         self._lib.loro_idmap_abort(self._h)
+        self._staged = 0
+
+
+def _idmap_tick(op: str, ids: int, t0: int) -> None:
+    """The id map's boundary, always on: the ids a batch call took and the
+    ns it took them in (``fleet.idmap_ids_total{op}``,
+    ``fleet.idmap_ns_total{op}``; docs/OBSERVABILITY.md) — ns an id of
+    ``stage`` / ``lookup`` / ``commit``, in a traced run or not, without a
+    span under the spans that hold the calls."""
+    _obs.counter("fleet.idmap_ns_total").inc(time.perf_counter_ns() - t0, op=op)
+    _obs.counter("fleet.idmap_ids_total").inc(ids, op=op)
 
 
 def native_idmap():

@@ -26,6 +26,16 @@ Subcommands::
         ``-o out.json`` also writes a merged chrome trace (one
         process row per input) for side-by-side timeline viewing.
 
+    python -m loro_tpu.obs.trace rounds <dump.json>
+        A resident server's rounds, from a chrome trace: a line a
+        ``server.ingest`` (trace id, ``docs``, duration), a column a span
+        name under it holding that name's SELF time, ``unnamed`` (the
+        round's own self time: what no span covers) — the columns sum
+        to the duration — and beside them the collector's pauses inside
+        the round on its thread; then the median and the maximum by
+        column, and for the longest round the column that holds its
+        excess over the median.
+
 Exit codes: 0 ok, 2 unreadable/malformed artifact (typed ObsError
 message on stderr, never a stack trace).
 """
@@ -33,6 +43,7 @@ from __future__ import annotations
 
 import json
 import sys
+from statistics import median
 from typing import List, Optional
 
 from ..errors import ObsError
@@ -211,6 +222,93 @@ def render_merge(report: dict) -> str:
     return "\n".join(lines)
 
 
+# -- rounds (where a resident round's time went) -------------------------
+ROUND_SPAN = "server.ingest"
+
+
+def round_rows(art: dict) -> List[dict]:
+    """One row a top-level ``server.ingest`` of a chrome trace, in time
+    order: ``trace``, ``docs``, ``ms``, ``cols`` (``{span name: summed
+    self ms}`` over every span under the round, and ``unnamed``, the
+    round's own; their sum is ``ms``) and ``gc_ms`` (the ``gc.pause``
+    instants inside the round on its thread: part of whichever column
+    was open, not one more).  Read from the dump's ``span`` / ``parent``
+    args; self time = duration minus what the children cover, clipped as
+    ``benchmarks/readers/span_self_ms.py`` clips it."""
+    if art["_kind"] != "chrome":
+        raise ObsError("rounds needs a chrome trace (tracing.dump())")
+    spans = [e for e in art["traceEvents"]
+             if e.get("ph") == "X" and "span" in e.get("args", {})]
+    children: dict = {}
+    for e in spans:
+        children.setdefault(e["args"]["parent"], []).append(e)
+    pauses = [e for e in art["traceEvents"] if e.get("name") == "gc.pause"]
+    rows, under = [], set()
+    for root in sorted(spans, key=lambda e: (e["ts"], -e["dur"])):
+        if root["name"] != ROUND_SPAN or root["args"]["span"] in under:
+            continue  # another span, or a round inside a round: a column
+        cols: dict = {}
+        todo = [root]
+        while todo:
+            e = todo.pop()
+            own, at, stop = e["dur"], e["ts"], e["ts"] + e["dur"]
+            kids = children.get(e["args"]["span"], ())
+            for k in sorted(kids, key=lambda k: k["ts"]):
+                s, t = max(k["ts"], at), min(k["ts"] + k["dur"], stop)
+                if t > s:  # one thread's children: clip, never count twice
+                    own -= t - s
+                    at = t
+            name = "unnamed" if e is root else e["name"]
+            cols[name] = cols.get(name, 0.0) + own / 1e3
+            under.update(k["args"]["span"] for k in kids)
+            todo.extend(kids)
+        end = root["ts"] + root["dur"]
+        rows.append({
+            "trace": root["args"].get("trace"), "docs": root["args"].get("docs"),
+            "ms": root["dur"] / 1e3, "cols": cols,
+            "gc_ms": sum(p["args"]["ns"] for p in pauses
+                         if p["tid"] == root["tid"]
+                         and root["ts"] <= p["ts"] <= end) / 1e6,
+        })
+    if not rows:
+        raise ObsError(f"no {ROUND_SPAN} span in the trace: was a record "
+                       "kept (tracing.enable() or a profiler session) "
+                       "around the rounds?")
+    return rows
+
+
+def render_rounds(rows: List[dict]) -> str:
+    # a column a name, the costliest first (by median, then by its worst
+    # round: a drain is in one round of eight), `unnamed` last
+    names = {n for r in rows for n in r["cols"]} - {"unnamed"}
+    col = {n: [r["cols"].get(n, 0.0) for r in rows] for n in names | {"unnamed"}}
+    heads = sorted(names, key=lambda n: (-median(col[n]), -max(col[n]), n))
+    heads.append("unnamed")
+    width = [max(10, len(h)) for h in heads]
+
+    def line(first: str, ms: float, vals: List[float], gc_ms: float) -> str:
+        cells = "  ".join(f"{v:>{w}.2f}" for v, w in zip(vals, width))
+        return f"{first:<22} {ms:>10.2f}  {cells}  | {gc_ms:>8.2f}"
+
+    head = "  ".join(f"{h:>{w}}" for h, w in zip(heads, width))
+    lines = [f"{'trace (docs)':<22} {'ms':>10}  {head}  | {'gc.pause':>8}"]
+    for r in rows:
+        lines.append(line(f"{r['trace']} ({r['docs']})", r["ms"],
+                          [r["cols"].get(h, 0.0) for h in heads], r["gc_ms"]))
+    total, gcs = [r["ms"] for r in rows], [r["gc_ms"] for r in rows]
+    lines.append(line("median", median(total),
+                      [median(col[h]) for h in heads], median(gcs)))
+    lines.append(line("max", max(total), [max(col[h]) for h in heads], max(gcs)))
+    worst = max(rows, key=lambda r: r["ms"])
+    excess = {h: worst["cols"].get(h, 0.0) - median(col[h]) for h in heads}
+    held = max(excess, key=excess.get)
+    lines.append(
+        f"longest: {worst['trace']} {worst['ms']:.2f} ms, "
+        f"{worst['ms'] - median(total):+.2f} over the median; "
+        f"{held} holds {excess[held]:+.2f} of it")
+    return "\n".join(lines)
+
+
 # -- CLI ----------------------------------------------------------------
 def main(argv: Optional[List[str]] = None) -> int:
     argv = list(sys.argv[1:] if argv is None else argv)
@@ -250,8 +348,13 @@ def main(argv: Optional[List[str]] = None) -> int:
                     json.dump(merged_chrome(leader, follower), f)
                 print(f"merged chrome trace -> {out_path}")
             return 0
+        if cmd == "rounds":
+            if len(rest) != 1:
+                raise ObsError("rounds needs exactly <dump.json>")
+            print(render_rounds(round_rows(load_artifact(rest[0]))))
+            return 0
         raise ObsError(
-            f"unknown subcommand {cmd!r}: use dump | inspect | merge"
+            f"unknown subcommand {cmd!r}: use dump | inspect | merge | rounds"
         )
     except ObsError as e:
         print(f"obs.trace: {e}", file=sys.stderr)

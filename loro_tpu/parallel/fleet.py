@@ -1709,21 +1709,26 @@ class DeviceDocBatch:
                     "or call grow())"
                 )
         self.epoch += 1  # post-validation: dates this append's rows
-        # commit staged id maps + anchor metadata
-        for di, overlay in enumerate(overlays):
-            if overlay is None:
-                self.id2row[di].commit()
-            elif overlay:
-                self.id2row[di].update(overlay)
-        for di, stage in enumerate(anchor_stages or ()):
-            if stage:
-                self.anchor_meta[di].update(stage)
-                self.anchor_by_row[di].update(
-                    {a["row"]: pc for pc, a in stage.items()}
-                )
-        for di, vs in enumerate(value_stages or ()):
-            if vs:
-                self.value_store[di].extend(vs)
+        # commit staged id maps + anchor metadata: a row registers an id,
+        # so the round's new rows are the ids committed
+        told = {}
+        if tracing.is_enabled():
+            told = {"docs": sum(1 for k in n_new if k), "ids": sum(n_new)}
+        with tracing.span("resident.commit_ids", **told):
+            for di, overlay in enumerate(overlays):
+                if overlay is None:
+                    self.id2row[di].commit()
+                elif overlay:
+                    self.id2row[di].update(overlay)
+            for di, stage in enumerate(anchor_stages or ()):
+                if stage:
+                    self.anchor_meta[di].update(stage)
+                    self.anchor_by_row[di].update(
+                        {a["row"]: pc for pc, a in stage.items()}
+                    )
+            for di, vs in enumerate(value_stages or ()):
+                if vs:
+                    self.value_store[di].extend(vs)
         if max_new:
             from .order_maintenance import split_keys
 

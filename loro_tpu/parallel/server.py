@@ -391,9 +391,10 @@ class ResidentServer:
             # already a device failure and takes the degradation path.
             self._auto_ckpt_pending = False
             try:
-                self.last_checkpoint = sup.guard(
-                    self.checkpoint, label=f"server.checkpoint.{self.family}"
-                )
+                with tracing.span("server.checkpoint"):
+                    self.last_checkpoint = sup.guard(
+                        self.checkpoint, label=f"server.checkpoint.{self.family}"
+                    )
             except DeviceFailure as e:
                 return self._degrade_round(per_doc_updates, cid, e)
             obs.counter("server.auto_checkpoints_total").inc(family=self.family)

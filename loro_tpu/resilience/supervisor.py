@@ -46,6 +46,7 @@ from ..analysis.lockwitness import named_lock
 from ..errors import DeadlineExceeded, DeviceFailure, LoroError
 from ..obs import flight
 from ..obs import metrics as obs
+from ..utils import tracing
 from . import faultinject
 
 faultinject.register_site(
@@ -226,7 +227,8 @@ class DeviceSupervisor:
         obs.counter("resilience.launches_total").inc(label=label)
         obs.gauge("resilience.in_flight").set(depth)
         if depth >= self.drain_every:
-            self.drain(drain if drain is not None else self._auto_drain(out))
+            self.drain(drain if drain is not None else self._auto_drain(out),
+                       label=label)
         return out
 
     def _auto_drain(self, result) -> Callable[[], None]:
@@ -259,14 +261,19 @@ class DeviceSupervisor:
             obs.counter("resilience.launch_failures_total").inc(label=label)
             raise DeviceFailure(label, 1, f"{type(e).__name__}: {e}") from e
 
-    def drain(self, drain_fn: Optional[Callable[[], None]] = None) -> None:
+    def drain(self, drain_fn: Optional[Callable[[], None]] = None,
+              label: str = "drain") -> None:
         """Synchronize: run the drain fetch and zero the in-flight
         count (with no ``drain_fn`` it only resets the counters — the
-        caller already synced some other way)."""
+        caller already synced some other way).  The fetch is a host wait
+        inside whatever the caller times, one launch in ``drain_every``:
+        the span ``sup.drain`` names it, with the ``label`` of the launch
+        that filled the queue."""
         fn = drain_fn
         if fn is not None:
             try:
-                self.guard(fn, label="drain")
+                with tracing.span("sup.drain", label=label):
+                    self.guard(fn, label="drain")
             except BaseException:
                 # the queue state behind a failed drain is unknown, but
                 # the depth counter must not keep climbing past the

@@ -25,6 +25,12 @@ A recording span writes two records (docs/OBSERVABILITY.md "Spans"):
   record: ``events()`` returns the newest session's spans only.
   ``dump()`` writes it as chrome://tracing JSON.
 
+The collector's pauses: while a record is kept, a ``gc.callbacks`` hook
+keeps each collection in a bounded list of its OWN beside the spans
+(``pauses()``; ``dump()`` writes them as ``gc.pause`` instants).  A pause
+lands inside whatever span is open and would read as that stage's time;
+it never enters ``events()``, whose readers count spans.
+
 Span observers (obs bridge): loro_tpu.obs.enable_span_metrics()
 registers a callback that receives every span's (name, duration_s) so
 ONE instrumentation point feeds both the trace and the metrics
@@ -48,6 +54,7 @@ records the ambient id of its thread.
 """
 from __future__ import annotations
 
+import gc
 import itertools
 import json
 import os
@@ -71,6 +78,11 @@ _lock = threading.Lock()
 # replace the whole tuple under _lock (never mutate in place)
 _span_observers: Tuple[Callable[[str, float], None], ...] = ()
 _span_ids = itertools.count(1)
+RING_PAUSES = 1 << 12  # collections kept a session: the newest 4,096
+_pauses: deque = deque(maxlen=RING_PAUSES)
+_gc_t0 = 0  # start of the collection under way, 0 where none is recorded
+_gc_ns = 0  # pause time recorded so far, by the hook alone
+_gc_counted = 0  # how much of it trace.gc_pause_ns_total has been given
 # .trace: ambient trace id; .span: open span id; .leaf: under a leaf span
 _ambient = threading.local()
 
@@ -96,10 +108,11 @@ def _recording() -> bool:
 
 
 def _roll(on: bool) -> None:
-    global _live, _record
+    global _live, _record, _pauses
     with _lock:
         if on and not _live:
             _record = deque(maxlen=RING_SPANS)
+            _pauses = deque(maxlen=RING_PAUSES)
         _live = on
 
 
@@ -182,6 +195,8 @@ def _append(rec: tuple) -> None:
 
         obs.counter("trace.spans_dropped_total").inc()
     ring.append(rec)
+    if _gc_ns != _gc_counted:
+        _count_pauses()
 
 
 class span:
@@ -275,9 +290,56 @@ def events() -> List[Dict[str, Any]]:
 
 
 def clear() -> None:
-    global _record
+    global _record, _pauses
     with _lock:
         _record = deque(maxlen=RING_SPANS)
+        _pauses = deque(maxlen=RING_PAUSES)
+
+
+# -- the collector's pauses ----------------------------------------------
+def _on_gc(phase: str, info: dict) -> None:
+    """The ``gc.callbacks`` hook: one flag read while no record is kept
+    (``_live``: what THE switch read last).  It takes no lock and asks the
+    registry for nothing — a collection can start while its thread holds
+    one of their locks — so the counter is fed by the next span's end or
+    the next ``pauses()`` (``_count_pauses``).  Collections do not nest."""
+    global _gc_t0, _gc_ns
+    if phase == "start":
+        if _live:
+            _gc_t0 = time.perf_counter_ns()
+    elif _gc_t0:
+        end = time.perf_counter_ns()
+        _pauses.append((info["generation"], info["collected"],
+                        threading.get_ident(), _gc_t0, end))
+        _gc_ns += end - _gc_t0
+        _gc_t0 = 0
+
+
+gc.callbacks.append(_on_gc)
+
+
+def _count_pauses() -> None:
+    global _gc_counted
+    with _lock:
+        total = _gc_ns
+        ns, _gc_counted = total - _gc_counted, total
+    if ns:
+        from ..obs import metrics as obs
+
+        obs.counter("trace.gc_pause_ns_total").inc(ns)
+
+
+_PAUSE_FIELDS = ("gen", "collected", "tid", "start_ns", "end_ns")
+
+
+def pauses() -> List[Dict[str, Any]]:
+    """The newest session's collections, one dict of ``_PAUSE_FIELDS``
+    each, oldest first (the last ``RING_PAUSES`` of them): the generation
+    collected, the objects it freed, the thread it ran on and when
+    (``perf_counter_ns``, the spans' clock)."""
+    _recording()
+    _count_pauses()
+    return [dict(zip(_PAUSE_FIELDS, p)) for p in list(_pauses)]
 
 
 def _safe(v):
@@ -322,6 +384,11 @@ def dump(path: Optional[str] = None) -> str:
         if e["cpu_ns"] is not None:
             ev["args"]["cpu_us"] = e["cpu_ns"] / 1e3
         out.append(ev)
+    for p in pauses():  # an instant each, on the thread that collected
+        out.append({"name": "gc.pause", "ph": "i", "s": "t",
+                    "ts": p["start_ns"] / 1e3, "pid": pid, "tid": p["tid"],
+                    "args": {"gen": p["gen"], "collected": p["collected"],
+                             "ns": p["end_ns"] - p["start_ns"]}})
     with open(path, "w") as f:
         json.dump({"traceEvents": out}, f)
     return path

@@ -648,6 +648,67 @@ class TestTraceCli:
         merged = json.load(open(out_path))
         assert {e["pid"] for e in merged["traceEvents"]} == {1, 2}
 
+    def test_rounds_accounts_for_each_round_by_self_time(self, tmp_path, capsys):
+        """ISSUE 37: a hand-made dump of three rounds (ms): a plain one, one
+        whose journal has a child and whose supervisor drained, and one that
+        holds a round of its own (a column, not a line)."""
+        from loro_tpu.obs import trace as tcli
+
+        def x(name, span, parent, ts_ms, dur_ms, tid=7, **args):
+            return {"name": name, "ph": "X", "ts": ts_ms * 1e3, "dur": dur_ms * 1e3,
+                    "pid": 1, "tid": tid,
+                    "args": {"span": span, "parent": parent, "trace": None, **args}}
+
+        def pause(ts_ms, ms, tid=7):
+            return {"name": "gc.pause", "ph": "i", "s": "t", "ts": ts_ms * 1e3,
+                    "pid": 1, "tid": tid,
+                    "args": {"gen": 2, "collected": 0, "ns": int(ms * 1e6)}}
+
+        events = [
+            x("server.ingest", 1, 0, 0, 100, docs=16), x("resident.decode", 2, 1, 10, 50),
+            x("resident.commit_ids", 3, 1, 60, 30),
+            x("server.ingest", 4, 0, 200, 160, docs=16), x("resident.decode", 5, 4, 200, 60),
+            x("server.journal", 6, 4, 270, 40), x("wal.write", 7, 6, 280, 25),
+            x("sup.drain", 8, 4, 320, 35, label="server.ingest.text"),
+            x("server.fsync", 9, 0, 365, 5),  # the caller's flush: no round's
+            x("server.ingest", 10, 0, 400, 50, docs=1), x("server.ingest", 11, 10, 410, 30, docs=1),
+            x("resident.decode", 12, 11, 415, 20),
+            {"name": "server.epoch", "ph": "i", "s": "t", "ts": 5e3, "pid": 1, "tid": 7,
+             "args": {"span": 13, "parent": 1, "trace": None}},
+            pause(20, 4), pause(90, 1), pause(95, 2, tid=8),  # another thread's
+            pause(150, 9), pause(330, 3),
+        ]
+        for e, trace in zip((events[0], events[3], events[9]), "abc"):
+            e["args"]["trace"] = trace
+        path = tmp_path / "rounds.json"
+        path.write_text(json.dumps({"traceEvents": events}))
+        rows = tcli.round_rows(tcli.load_artifact(str(path)))
+        assert [(r["trace"], r["docs"], r["ms"], r["gc_ms"]) for r in rows] == [
+            ("a", 16, 100.0, 5.0), ("b", 16, 160.0, 3.0), ("c", 1, 50.0, 0.0)]
+        assert rows[0]["cols"] == {"unnamed": 20.0, "resident.decode": 50.0,
+                                   "resident.commit_ids": 30.0}
+        assert rows[1]["cols"] == {"unnamed": 25.0, "resident.decode": 60.0,
+                                   "server.journal": 15.0, "wal.write": 25.0,
+                                   "sup.drain": 35.0}
+        assert rows[2]["cols"] == {"unnamed": 20.0, "server.ingest": 10.0,
+                                   "resident.decode": 20.0}
+        assert tcli.main(["rounds", str(path)]) == 0
+        out = capsys.readouterr().out.splitlines()
+        assert [ln.split()[0] for ln in out] == ["trace", "a", "b", "c", "median",
+                                                 "max", "longest:"]
+        # the costliest column first (median, then worst round), `unnamed` last
+        assert out[0].split()[2:] == [
+            "ms", "resident.decode", "sup.drain", "resident.commit_ids", "wal.write",
+            "server.journal", "server.ingest", "unnamed", "|", "gc.pause"]
+        assert out[-1] == ("longest: b 160.00 ms, +60.00 over the median; "
+                           "sup.drain holds +35.00 of it")
+        # a flight snapshot has no spans; neither has a trace of no round
+        assert tcli.main(["rounds", self._flight_file(tmp_path)]) == 2
+        path.write_text(json.dumps({"traceEvents": events[1:3]}))
+        assert tcli.main(["rounds", str(path)]) == 2
+        assert tcli.main(["rounds"]) == 2
+        assert capsys.readouterr().err.count("obs.trace:") == 3
+
     def test_malformed_artifact_rc2(self, tmp_path, capsys):
         from loro_tpu.obs import trace as tcli
 

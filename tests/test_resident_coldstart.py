@@ -26,11 +26,13 @@ from loro_tpu import native  # noqa: E402
 from loro_tpu.codec.binary import decode_changes  # noqa: E402
 from loro_tpu.core.ids import ContainerID, ContainerType  # noqa: E402
 from loro_tpu.obs import metrics as obs  # noqa: E402
+from loro_tpu.obs import trace as obs_trace  # noqa: E402
 from loro_tpu.parallel.fleet import Fleet  # noqa: E402
 from loro_tpu.parallel.mesh import make_mesh  # noqa: E402
 from loro_tpu.parallel.server import ResidentServer  # noqa: E402
 from loro_tpu.persist import recover_server  # noqa: E402
 from loro_tpu.resilience.hostpath import HostEngine  # noqa: E402
+from loro_tpu.resilience.supervisor import get_supervisor  # noqa: E402
 from loro_tpu.utils import tracing  # noqa: E402
 
 with open(os.path.join(BENCH, "configs", "b4_resident.json")) as _f:
@@ -40,9 +42,10 @@ CID = ContainerID.root("text", ContainerType.Text)
 SLOTS, CAPACITY, K = TINY["resident_documents"], TINY["capacity"], 2
 SEEDS = [7, 2147483659]
 
-# item 2 of ISSUE 35: the spans of one ingest round, in the order they end,
-# and the one read-back's
-ROUND_SPANS = ["resident.decode", "resident.stage", "resident.order",
+# item 2 of ISSUE 35: the spans of one ingest round, in the order they end
+# (``resident.commit_ids``: ISSUE 37), and the one read-back's
+ROUND_SPANS = ["resident.decode", "resident.commit_ids", "resident.stage",
+               "resident.order",
                "resident.upload", "resident.scatter", "resident.tombstone",
                "server.journal", "server.ingest", "server.fsync"]
 READ_SPANS = ["resident.materialize", "resident.fetch"]
@@ -131,20 +134,25 @@ def test_a_fresh_server_recovered_from_the_directory_reads_the_same(
 
 
 def test_every_span_once_a_round_under_one_trace_id_and_the_counters_tick(
-        tmp_path, mesh, documents):
+        tmp_path, mesh, documents, capsys):
     fed, want = documents[SEEDS[0]]
     server = ResidentServer("text", SLOTS, mesh=mesh, capacity=CAPACITY,
                             durable_dir=str(tmp_path), durable_fsync="group")
     cold_start(server, fed, rounds=1)  # the first round also checkpoints
+    # the supervisor is the process's: whatever ran before, no drain (a
+    # ``sup.drain`` span, one launch in eight) falls into these two rounds
+    get_supervisor().drain()
     names = ("fleet.resident_rows_total", "fleet.resident_tombstones_total",
              "server.ingest_rounds_total", "persist.wal_bytes_appended_total",
              "persist.wal_fsyncs_total")
     c0 = [obs.counter(n).total() for n in names]
+    idmap0 = id_map_counts()
     tracing.clear()
     tracing.enable()
     try:
         cold_start(server, fed, rounds=2, first_round=1)
         spans = tracing.events()
+        dump = tracing.dump(str(tmp_path / "rounds.json"))
         tracing.clear()
         texts = server.texts()
         read = tracing.events()
@@ -174,6 +182,64 @@ def test_every_span_once_a_round_under_one_trace_id_and_the_counters_tick(
     # single-character patches: a row an insert, a tombstone a delete
     assert rows + tombs == ops and rows == 2 * K * TINY["insert_patches"]
     assert n_rounds == 2 and fsyncs == 2 and wal_bytes > payload_bytes
+    # ISSUE 37: the id maps' commit has a span of its own, a sibling of the
+    # stages, and the id map's boundary counts what it took and how long
+    commits = [e["args"] for e in spans if e["name"] == "resident.commit_ids"]
+    assert commits == [{"docs": K, "ids": K * TINY["insert_patches"]}] * 2
+    moved = {k: v - idmap0[k] for k, v in id_map_counts().items()}
+    assert moved["ids.commit"] == moved["ids.stage"] == rows
+    assert moved["ids.lookup"] > 0  # cross-epoch parents, delete targets
+    assert all(moved[k] > 0 for k in ("ns.stage", "ns.lookup", "ns.commit",
+                                      "decode_ns"))
+    # the account of the same two rounds, from the dump alone
+    table = obs_trace.round_rows(obs_trace.load_artifact(dump))
+    assert [(r["trace"], r["docs"]) for r in table] == [
+        (e["trace_id"], K) for e in rounds]
+    for r, e in zip(table, rounds):
+        assert r["ms"] == pytest.approx((e["end_ns"] - e["start_ns"]) / 1e6)
+        assert sum(r["cols"].values()) == pytest.approx(r["ms"])
+        assert set(r["cols"]) == {"unnamed", *ROUND_SPANS[:-2]}
+        assert all(v >= 0 for v in r["cols"].values())
+    assert obs_trace.main(["rounds", dump]) == 0
+    lines = capsys.readouterr().out.splitlines()
+    assert [ln.split()[0] for ln in lines] == [
+        "trace", *(e["trace_id"] for e in rounds), "median", "max", "longest:"]
+    assert all(name in lines[0] for name in ("resident.commit_ids", "unnamed",
+                                             "gc.pause"))
+
+
+def id_map_counts() -> dict:
+    """The counters at the id map's and the decoder's boundary (ISSUE 37)."""
+    ids, ns = obs.counter("fleet.idmap_ids_total"), obs.counter("fleet.idmap_ns_total")
+    out = {f"ids.{op}": ids.get(op=op) for op in ("stage", "lookup", "commit")}
+    out.update({f"ns.{op}": ns.get(op=op) for op in ("stage", "lookup", "commit")})
+    out["decode_ns"] = obs.counter("codec.native_decode_ns_total").get(fn="seq_delta")
+    return out
+
+
+def test_a_servers_first_round_records_its_checkpoint_and_no_later_one_does(
+        tmp_path, mesh, documents):
+    """ISSUE 37: the auto-checkpoint before a server's first launch is a
+    span of its own under the round, so that round is not one opaque span."""
+    fed, _want = documents[SEEDS[0]]
+    server = ResidentServer("text", SLOTS, mesh=mesh, capacity=CAPACITY,
+                            durable_dir=str(tmp_path), durable_fsync="group")
+    tracing.clear()
+    tracing.enable()
+    try:
+        cold_start(server, fed, rounds=2)
+        spans = tracing.events()
+    finally:
+        tracing.disable()
+        tracing.clear()
+        server.close()
+    first, second = [e for e in spans if e["name"] == "server.ingest"]
+    taken = [e for e in spans if e["name"] == "server.checkpoint"]
+    assert [e["parent_id"] for e in taken] == [first["span_id"]]
+    assert taken[0]["trace_id"] == first["trace_id"] != second["trace_id"]
+    # it ends before the round's first stage starts: a sibling of them
+    decode = next(e for e in spans if e["name"] == "resident.decode")
+    assert taken[0]["end_ns"] <= decode["start_ns"]
 
 
 @pytest.mark.parametrize("slots", [SLOTS, 3 * SLOTS])

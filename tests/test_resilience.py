@@ -189,6 +189,42 @@ class TestDrainBudget:
         with pytest.raises(KeyError):
             sup.guard(lambda: {}["x"])
 
+    @pytest.mark.parametrize("traced, drain_fn, want", [
+        (True, True, ["sup.drain", "round"]),
+        (True, False, ["round"]),  # nothing fetched: counters reset only
+        (False, True, []),
+    ])
+    def test_the_drain_is_a_span_under_whatever_is_open(self, traced, drain_fn, want):
+        """ISSUE 37: the fetch that drains the queue, one launch in
+        ``drain_every``, is named — under the caller's open span, with the
+        label of the launch that filled the queue — and only where a drain
+        function runs and a record is kept."""
+        from loro_tpu.utils import tracing
+
+        sup, _ = make_sup(drain_every=2)
+        drains = []
+        fn = (lambda: drains.append(1)) if drain_fn else None
+        tracing.clear()
+        if traced:
+            tracing.enable()
+        try:
+            with tracing.span("round"):
+                sup.launch(lambda: 1, label="a", drain=fn)
+                if fn is not None:
+                    sup.launch(lambda: 2, label="b", drain=fn)
+                else:
+                    sup.drain()
+            spans = tracing.events()
+        finally:
+            tracing.disable()
+            tracing.clear()
+        assert [e["name"] for e in spans] == want
+        assert drains == ([1] if drain_fn else [])
+        assert sup.in_flight == 0 and sup.report()["drains"] == 1
+        if want[:1] == ["sup.drain"]:
+            assert spans[0]["args"] == {"label": "b"}
+            assert spans[0]["parent_id"] == spans[1]["span_id"]
+
     def test_fetch_resets_depth(self):
         sup, _ = make_sup(drain_every=100)
         for _ in range(5):

@@ -317,6 +317,54 @@ def test_off_records_nothing_and_builds_no_annotation(monkeypatch):
     tracing.clear()
 
 
+def test_a_collection_is_kept_beside_the_spans_and_never_among_them(tmp_path):
+    """ISSUE 37: while a record is kept every collection is a pause of its
+    own list, counted in ns and dumped as an instant on its thread; the
+    span record, which readers count, holds none of it; off, nothing moves."""
+    import gc
+
+    paused = obs.counter("trace.gc_pause_ns_total")
+    tracing.disable()
+    tracing.clear()
+    before = paused.total()
+    gc.disable()  # the collections of this test are the ones it asks for
+    try:
+        gc.collect()
+        assert tracing.pauses() == [] and paused.total() == before
+        tracing.enable()
+        try:
+            with tracing.span("open"):
+                gc.collect()
+            kept, spans = tracing.pauses(), tracing.events()
+            path = tracing.dump(str(tmp_path / "trace.json"))
+        finally:
+            tracing.disable()
+        (pause,) = kept
+        assert pause["gen"] == 2 and pause["collected"] >= 0
+        assert pause["tid"] == threading.get_ident()
+        assert paused.total() - before == pause["end_ns"] - pause["start_ns"] > 0
+        (span,) = spans  # the pause fell inside the open span, on its clock
+        assert span["name"] == "open"
+        assert span["start_ns"] <= pause["start_ns"] and pause["end_ns"] <= span["end_ns"]
+        with open(path) as f:
+            dumped = [e for e in json.load(f)["traceEvents"] if e["name"] != "open"]
+        assert dumped == [{
+            "name": "gc.pause", "ph": "i", "s": "t", "ts": pause["start_ns"] / 1e3,
+            "pid": os.getpid(), "tid": pause["tid"],
+            "args": {"gen": 2, "collected": pause["collected"],
+                     "ns": pause["end_ns"] - pause["start_ns"]}}]
+        gc.collect()  # off again: the list stands as the session left it
+        assert tracing.pauses() == kept
+        tracing.enable()  # a new session, a new list
+        try:
+            assert tracing.pauses() == []
+        finally:
+            tracing.disable()
+    finally:
+        gc.enable()
+        tracing.clear()
+
+
 # ---------------------------------------------------------------------------
 # the two import paths: span trees and device scopes
 # ---------------------------------------------------------------------------

@@ -441,29 +441,33 @@ class TreeLogCols(NamedTuple):
     valid: jax.Array  # bool[M]
 
 
+def _sort_log(c: TreeLogCols):
+    """One document's standing log sorted by the global move key
+    (lamport, peer, counter), padding last: (TreeOpCols, the append row
+    of each sorted move)."""
+    m = c.lamport.shape[0]
+    big = jnp.int32(2**31 - 1)
+    lam = jnp.where(c.valid, c.lamport, big)  # pads sort last
+    row_idx = jnp.arange(m, dtype=jnp.int32)
+    _, _, _, _, t_s, p_s, v_s, row_s = jax.lax.sort(
+        (
+            lam,
+            c.peer_hi,
+            c.peer_lo,
+            c.counter,
+            c.target,
+            c.parent,
+            c.valid.astype(jnp.int32),
+            row_idx,
+        ),
+        num_keys=4,
+    )
+    return TreeOpCols(target=t_s, parent=p_s, valid=v_s.astype(bool)), row_s
+
+
 @_doc_jit_as("tree_replay_log_batch")
 def _tree_replay_log_batch_jit(cols: TreeLogCols, n_nodes: int, d_max: Optional[int]):
-    def sort_doc(c: TreeLogCols):
-        m = c.lamport.shape[0]
-        big = jnp.int32(2**31 - 1)
-        lam = jnp.where(c.valid, c.lamport, big)  # pads sort last
-        row_idx = jnp.arange(m, dtype=jnp.int32)
-        _, _, _, _, t_s, p_s, v_s, row_s = jax.lax.sort(
-            (
-                lam,
-                c.peer_hi,
-                c.peer_lo,
-                c.counter,
-                c.target,
-                c.parent,
-                c.valid.astype(jnp.int32),
-                row_idx,
-            ),
-            num_keys=4,
-        )
-        return TreeOpCols(target=t_s, parent=p_s, valid=v_s.astype(bool)), row_s
-
-    sorted_cols, row_s = jax.vmap(sort_doc)(cols)
+    sorted_cols, row_s = jax.vmap(_sort_log)(cols)
     parents, eff, _stats = tree_replay(sorted_cols, n_nodes, d_max)
     eff_rows = jax.vmap(lambda e, r: jnp.zeros_like(e).at[r].set(e))(eff, row_s)
     return parents, eff_rows
@@ -477,6 +481,56 @@ def tree_replay_log_batch(
     effected in ROW (append) order — the host resolves sibling positions
     from the last effected non-delete move per node in key order)."""
     return _tree_replay_log_batch_jit(cols, n_nodes, d_max)
+
+
+@functools.partial(jax.jit, donate_argnums=(1,), static_argnums=(3, 4))
+def tree_settle_docs(cols: TreeLogCols, standing, d_idx, n_nodes: int, mesh):
+    """Settle the documents ``d_idx`` names: their standing parents and
+    refusal counts become what a replay of their WHOLE logs gives.
+
+    ``cols`` is the resident log ``[d, M]``; ``standing`` = (parents
+    i32[d, n_nodes], refused i32[d]), donated; ``d_idx`` i32[k_pad] the
+    named documents, -1 for a pad entry.  One launch: the named logs are
+    gathered into a block ``[k_pad, M]``, each sorted by the move key
+    (`_sort_log`), the block replayed (`tree_replay`: on the chip the
+    lock-step kernel, one document to a lane) and row ``j``'s answers
+    written back at document ``d_idx[j]``.  A pad entry gathers an empty
+    log and its answers are dropped.  The device work follows ``k_pad``,
+    not the table's documents.
+
+    On a mesh of several devices the table and the standing state are
+    doc-sharded and ``d_idx`` replicated: under ``shard_map`` each device
+    gathers the named documents it holds (the others' rows of its block
+    stay empty), replays its block and writes its own rows — nothing
+    crosses devices."""
+    from ..parallel.mesh import DOC_AXIS
+
+    sharded = mesh is not None and mesh.size > 1
+
+    def on_device(cols, parents, refused, d_idx):
+        d_local = parents.shape[0]
+        lo = jax.lax.axis_index(DOC_AXIS) * d_local if sharded else 0
+        li = d_idx - lo
+        held = (li >= 0) & (li < d_local)
+        at = jnp.clip(li, 0, d_local - 1)
+        block = TreeLogCols(*(a[at] for a in cols))
+        block = block._replace(valid=block.valid & held[:, None])
+        sorted_cols, _rows = jax.vmap(_sort_log)(block)
+        new, _eff, stats = tree_replay(sorted_cols, n_nodes, None, want_eff=False)
+        to = jnp.where(held, li, d_local)  # out of range: dropped
+        return (parents.at[to].set(new, mode="drop"),
+                refused.at[to].set(stats[:, 0], mode="drop"))
+
+    if sharded:
+        from jax.sharding import PartitionSpec as P
+
+        docs, rep = P(DOC_AXIS), P()
+        on_device = jax.shard_map(
+            on_device, mesh=mesh, in_specs=(docs, docs, docs, rep),
+            out_specs=(docs, docs), check_vma=False,
+        )
+    parents, refused = standing
+    return on_device(cols, parents, refused, d_idx)
 
 
 def is_deleted_batch(parents: jax.Array) -> jax.Array:

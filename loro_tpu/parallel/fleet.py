@@ -2873,19 +2873,26 @@ class DeviceTreeBatch:
     """Device-resident movable-tree move logs for a doc batch (the tree
     member of the resident family next to DeviceDocBatch/DeviceMapBatch).
 
-    Appends ship only NEW moves (one block scatter); materialization
-    sorts each standing log by the global move key (lamport, peer,
-    counter) on device and replays the cycle-checked scan
-    (ops/tree_batch.tree_replay_log_batch).  Unlike LWW folds, tree
-    moves do not commute — a late-arriving concurrent move with a lower
-    lamport must replay BEFORE already-applied moves — so the resident
-    state is the log, not the folded parents (the reference's
-    TreeCacheForDiff keeps the same per-node move sets and re-walks
-    them, diff_calc/tree.rs:230-396)."""
+    Appends ship only NEW moves (one block scatter).  Unlike LWW folds,
+    tree moves do not commute — a late-arriving concurrent move with a
+    lower lamport must replay BEFORE already-applied moves — so the
+    resident state is the log (the reference's TreeCacheForDiff keeps
+    the same per-node move sets and re-walks them,
+    diff_calc/tree.rs:230-396) PLUS its answer, settled at every
+    commit: ``parents`` i32[d, node_cap] (ABSENT for a node never
+    created) and ``refused`` i32[d], the moves each document's log
+    refuses as cycles.  The commit settles the documents it names
+    (ops/tree_batch.tree_settle_docs: their whole logs gathered, sorted
+    by the global move key (lamport, peer, counter) and replayed, the
+    answers scattered back), so the device work of a round follows the
+    documents it names, and ``parent_maps`` reads the standing state.
+    Every path that changes a log keeps it settled: ``commit_detached``,
+    ``release_doc``, ``grow``, ``compact``, ``import_state`` (one whole
+    replay) and, through the commit, journal replay."""
 
     def __init__(self, n_docs: int, move_capacity: int, node_capacity: int, mesh=None,
                  auto_grow: bool = False):
-        from ..ops.tree_batch import ROOT, TreeLogCols
+        from ..ops.tree_batch import ABSENT, ROOT, TreeLogCols
 
         self.mesh = mesh if mesh is not None else make_mesh()
         self.n_docs = n_docs
@@ -2913,8 +2920,58 @@ class DeviceTreeBatch:
             parent=z(np.int32, ROOT),
             valid=z(bool, False),
         )
+        # the standing answer of every document's whole log
+        self.parents = jax.device_put(
+            np.full((self.d, node_capacity), ABSENT, np.int32), sh)
+        self.refused = jax.device_put(np.zeros(self.d, np.int32), sh)
         self._defer = None  # coalesced-ingest accumulator
         self._dev_lock = named_rlock("fleet.dev")
+
+    def _settle(self, named) -> None:
+        """Settle the documents ``named`` (slot indexes with new or
+        rewritten log rows): ONE launch of ``tree_settle_docs`` over a
+        block of ``_named_bucket(len(named))`` documents."""
+        from ..ops.tree_batch import replay_algo, tree_settle_docs
+
+        named = np.asarray(named, np.int32)
+        if not len(named):
+            return
+        k_pad = _named_bucket(len(named))
+        d_idx = np.full(k_pad, -1, np.int32)
+        d_idx[: len(named)] = named
+        algo = replay_algo(self.node_cap)
+        obs.counter("tree.settle_docs_total").inc(len(named))
+        # the sequential steps of the launch: the scan replays its padding
+        obs.counter("tree.replay_steps").inc(
+            int(self.counts[named].max()) if algo == "pallas:lockstep" else self.cap,
+            algo=algo,
+        )
+        with self._dev_lock, tracing.span(
+            "resident.settle", docs=len(named), rows=k_pad * self.cap
+        ):
+            self.parents, self.refused = tree_settle_docs(
+                self.cols, (self.parents, self.refused),
+                jax.device_put(d_idx, replicated(self.mesh)),
+                self.node_cap, self.mesh,
+            )
+
+    def _settle_whole(self) -> None:
+        """The standing state from ONE replay of the whole table
+        (``tree_replay_log_batch``): a batch built from saved logs."""
+        from ..ops.tree_batch import tree_replay_log_batch
+
+        with self._dev_lock:
+            parents, eff = tree_replay_log_batch(self.cols, self.node_cap)
+            self.parents = parents
+            self.refused = jnp.sum(
+                self.cols.valid & ~eff, axis=1, dtype=jnp.int32
+            )
+
+    def refusals(self) -> np.ndarray:
+        """The moves each document's standing log refuses as cycles,
+        read from the device: int64[n_docs]."""
+        with self._dev_lock:
+            return np.asarray(self.refused)[: self.n_docs].astype(np.int64)
 
     # -- round coalescing (same contract as DeviceDocBatch) ------------
     def begin_coalesce(self) -> None:
@@ -2969,6 +3026,8 @@ class DeviceTreeBatch:
                             blk[f][di, p : p + k] = rblk[f][di, :k]
                         pos[di] += k
                 self._device_commit_moves(blk, d.base0.astype(np.int32), total)
+            # the UNION of the documents the group's rounds name
+            self._settle(np.flatnonzero(total))
             obs.counter("pipeline.coalesced_rounds_total").inc(
                 len(d.rounds), family="tree"
             )
@@ -2982,7 +3041,9 @@ class DeviceTreeBatch:
             int(self.d * blk["valid"].shape[1] - int(np.sum(n_new))),
             family="resident_tree",
         )
-        with self._dev_lock:
+        with self._dev_lock, tracing.span(
+            "resident.upload", docs=int(np.count_nonzero(n_new))
+        ):
             sh = doc_sharding(self.mesh)
             self.cols = _scatter_tree_rows(
                 self.cols,
@@ -2995,7 +3056,7 @@ class DeviceTreeBatch:
     def release_doc(self, di: int) -> None:
         """Reset doc ``di`` to a never-used slot (tiered-residency
         eviction; see DeviceDocBatch.release_doc for the contract)."""
-        from ..ops.tree_batch import ROOT, TreeLogCols
+        from ..ops.tree_batch import ABSENT, ROOT, TreeLogCols
 
         self.counts[di] = 0
         self.move_epoch[di, :] = -1
@@ -3016,6 +3077,10 @@ class DeviceTreeBatch:
                 tuple(fills[f] for f in fields),
             )
             self.cols = TreeLogCols(**dict(zip(fields, out)))
+            # an empty log's answer: no node, nothing refused
+            (self.parents,) = _release_rows(
+                (self.parents,), jnp.int32(di), (ABSENT,))
+            self.refused = _release_count(self.refused, jnp.int32(di))
         obs.counter("fleet.doc_releases_total").inc(family="tree")
 
     def append_changes(self, per_doc_changes: Sequence[Optional[Sequence[Change]]], cid) -> None:
@@ -3027,13 +3092,14 @@ class DeviceTreeBatch:
         per_doc_changes = list(per_doc_changes) + [None] * (self.d - len(per_doc_changes))
         rows_per_doc: List[list] = []
         staged_nodes: List[list] = []
-        for di, changes in enumerate(per_doc_changes):
-            rows: list = []
-            staged_order: list = []
-            rows_per_doc.append(rows)
-            staged_nodes.append(staged_order)
-            if changes:
-                self._explode_changes_into(di, changes, cid, rows, staged_order)
+        with tracing.span("resident.decode"):
+            for di, changes in enumerate(per_doc_changes):
+                rows: list = []
+                staged_order: list = []
+                rows_per_doc.append(rows)
+                staged_nodes.append(staged_order)
+                if changes:
+                    self._explode_changes_into(di, changes, cid, rows, staged_order)
         self._commit_moves(rows_per_doc, staged_nodes)
 
     def append_payloads(self, per_doc_payloads: Sequence[Optional[bytes]], cid) -> None:
@@ -3057,79 +3123,80 @@ class DeviceTreeBatch:
         rows_per_doc: List[list] = []
         staged_nodes: List[list] = []
         fallback: List[Tuple[int, bytes]] = []
-        for di, payload in enumerate(per_doc_payloads):
-            rows: list = []
-            staged: Dict = {}
-            staged_order: list = []
-            rows_per_doc.append(rows)
-            staged_nodes.append(staged_order)
-            if not payload:
-                continue
-            ids = self.node_ids[di]
-            n_committed = len(self.nodes[di])
+        with tracing.span("resident.decode"):
+            for di, payload in enumerate(per_doc_payloads):
+                rows: list = []
+                staged: Dict = {}
+                staged_order: list = []
+                rows_per_doc.append(rows)
+                staged_nodes.append(staged_order)
+                if not payload:
+                    continue
+                ids = self.node_ids[di]
+                n_committed = len(self.nodes[di])
 
-            def node_idx(tid):
-                i = ids.get(tid)
-                if i is None:
-                    i = staged.get(tid)
-                if i is None:
-                    i = n_committed + len(staged_order)
-                    staged[tid] = i
-                    staged_order.append(tid)
-                return i
+                def node_idx(tid):
+                    i = ids.get(tid)
+                    if i is None:
+                        i = staged.get(tid)
+                    if i is None:
+                        i = n_committed + len(staged_order)
+                        staged[tid] = i
+                        staged_order.append(tid)
+                    return i
 
-            try:
-                peers_wire, _keys, cids, _r = read_tables(payload)
                 try:
-                    target = cids.index(cid)
-                except ValueError:
-                    continue  # no ops for this container
-                out = explode_tree_payload(payload, target)
-                fl = out["flags"]
-                for i in range(len(out["lamport"])):
-                    tid = TreeID(
-                        int(peers_wire[int(out["target_peer_idx"][i])]),
-                        int(out["target_ctr"][i]),
-                    )
-                    t = node_idx(tid)
-                    if fl[i] & 2:  # delete
-                        p = TRASH
-                        is_del = True
-                    elif fl[i] & 4:  # has parent
-                        p = node_idx(
-                            TreeID(
-                                int(peers_wire[int(out["parent_peer_idx"][i])]),
-                                int(out["parent_ctr"][i]),
+                    peers_wire, _keys, cids, _r = read_tables(payload)
+                    try:
+                        target = cids.index(cid)
+                    except ValueError:
+                        continue  # no ops for this container
+                    out = explode_tree_payload(payload, target)
+                    fl = out["flags"]
+                    for i in range(len(out["lamport"])):
+                        tid = TreeID(
+                            int(peers_wire[int(out["target_peer_idx"][i])]),
+                            int(out["target_ctr"][i]),
+                        )
+                        t = node_idx(tid)
+                        if fl[i] & 2:  # delete
+                            p = TRASH
+                            is_del = True
+                        elif fl[i] & 4:  # has parent
+                            p = node_idx(
+                                TreeID(
+                                    int(peers_wire[int(out["parent_peer_idx"][i])]),
+                                    int(out["parent_ctr"][i]),
+                                )
+                            )
+                            is_del = False
+                        else:
+                            p = ROOT
+                            is_del = False
+                        pos = None
+                        if fl[i] & 8:
+                            o = int(out["pos_off"][i])
+                            pos = bytes(payload[o : o + int(out["pos_len"][i])])
+                        rows.append(
+                            (
+                                int(out["lamport"][i]),
+                                int(peers_wire[int(out["peer_idx"][i])]),
+                                int(out["counter"][i]),
+                                t,
+                                p,
+                                is_del,
+                                pos,
                             )
                         )
-                        is_del = False
-                    else:
-                        p = ROOT
-                        is_del = False
-                    pos = None
-                    if fl[i] & 8:
-                        o = int(out["pos_off"][i])
-                        pos = bytes(payload[o : o + int(out["pos_len"][i])])
-                    rows.append(
-                        (
-                            int(out["lamport"][i]),
-                            int(peers_wire[int(out["peer_idx"][i])]),
-                            int(out["counter"][i]),
-                            t,
-                            p,
-                            is_del,
-                            pos,
-                        )
-                    )
-            except ValueError:
-                rows.clear()
-                staged.clear()
-                staged_order.clear()
-                fallback.append((di, payload))
-        for di, payload in fallback:  # python walk per unresolvable payload
-            self._explode_changes_into(
-                di, decode_changes(payload), cid, rows_per_doc[di], staged_nodes[di]
-            )
+                except ValueError:
+                    rows.clear()
+                    staged.clear()
+                    staged_order.clear()
+                    fallback.append((di, payload))
+            for di, payload in fallback:  # python walk per unresolvable payload
+                self._explode_changes_into(
+                    di, decode_changes(payload), cid, rows_per_doc[di], staged_nodes[di]
+                )
         self._commit_moves(rows_per_doc, staged_nodes)
 
     def _explode_changes_into(self, di, changes, cid, rows, staged_order) -> None:
@@ -3169,10 +3236,10 @@ class DeviceTreeBatch:
 
     def grow(self, move_capacity: int = None, node_capacity: int = None) -> None:
         """Repack move-log columns and/or raise the node ceiling
-        (resident lifecycle, r4 verdict #6).  node_capacity is a launch
-        parameter (tree_replay_log_batch pads per launch), so that half
-        is a scalar bump."""
-        from ..ops.tree_batch import ROOT, TreeLogCols
+        (resident lifecycle, r4 verdict #6).  The node ceiling widens
+        the standing parents (new nodes read ABSENT): the answers of the
+        logs do not change."""
+        from ..ops.tree_batch import ABSENT, ROOT, TreeLogCols
 
         if move_capacity is not None and move_capacity > self.cap:
             with self._dev_lock:  # vs an in-flight pipelined commit
@@ -3190,11 +3257,16 @@ class DeviceTreeBatch:
                 self.move_epoch = me
                 self.cap = move_capacity
         if node_capacity is not None and node_capacity > self.node_cap:
-            self.node_cap = node_capacity
+            with self._dev_lock:
+                self.parents = _pad_axis1(
+                    {"parents": self.parents}, node_capacity,
+                    {"parents": ABSENT}, doc_sharding(self.mesh),
+                )["parents"]
+                self.node_cap = node_capacity
 
     def _commit_moves(self, rows_per_doc, staged_nodes) -> None:
         """Shared tail: validate capacities, commit staged nodes, block-
-        scatter the new move rows."""
+        scatter the new move rows, settle the documents they name."""
         from ..ops.fugue_batch import pad_bucket
         from ..ops.tree_batch import ROOT
 
@@ -3239,47 +3311,53 @@ class DeviceTreeBatch:
         self.epoch += 1
         if not max_new:
             return
-        # commit staged node registrations
-        for di, staged_order in enumerate(staged_nodes):
-            for tid in staged_order:
-                self.node_ids[di][tid] = len(self.nodes[di])
-                self.nodes[di].append(tid)
-        blk_shape = (self.d, max_new)
-        blk = {
-            "lamport": np.zeros(blk_shape, np.int32),
-            "peer_hi": np.zeros(blk_shape, np.uint32),
-            "peer_lo": np.zeros(blk_shape, np.uint32),
-            "counter": np.zeros(blk_shape, np.int32),
-            "target": np.zeros(blk_shape, np.int32),
-            "parent": np.full(blk_shape, ROOT, np.int32),
-            "valid": np.zeros(blk_shape, bool),
-        }
-        offsets = np.zeros(self.d, np.int32)
-        for di, rows in enumerate(rows_per_doc):
-            if not rows:
-                continue
-            k = len(rows)
-            arr = np.asarray([(r[0], r[2], r[3], r[4]) for r in rows], np.int64)
-            pu = np.asarray([r[1] for r in rows], np.uint64)
-            blk["lamport"][di, :k] = arr[:, 0]
-            blk["peer_hi"][di, :k] = (pu >> np.uint64(32)).astype(np.uint32)
-            blk["peer_lo"][di, :k] = (pu & np.uint64(0xFFFFFFFF)).astype(np.uint32)
-            blk["counter"][di, :k] = arr[:, 1]
-            blk["target"][di, :k] = arr[:, 2]
-            blk["parent"][di, :k] = arr[:, 3]
-            blk["valid"][di, :k] = True
-            base = int(self.counts[di])
-            offsets[di] = base
-            self.move_epoch[di, base : base + k] = self.epoch
-            self.counts[di] += k
-            self.move_meta[di].extend(
-                (r[0], r[1], r[2], r[3], r[5], r[6]) for r in rows
-            )
+        obs.counter("fleet.resident_tree_moves_total").inc(
+            sum(len(r) for r in rows_per_doc)
+        )
+        with tracing.span("resident.stage", docs=sum(1 for r in rows_per_doc if r),
+                          bytes=25 * self.d * max_new):
+            # commit staged node registrations
+            for di, staged_order in enumerate(staged_nodes):
+                for tid in staged_order:
+                    self.node_ids[di][tid] = len(self.nodes[di])
+                    self.nodes[di].append(tid)
+            blk_shape = (self.d, max_new)
+            blk = {
+                "lamport": np.zeros(blk_shape, np.int32),
+                "peer_hi": np.zeros(blk_shape, np.uint32),
+                "peer_lo": np.zeros(blk_shape, np.uint32),
+                "counter": np.zeros(blk_shape, np.int32),
+                "target": np.zeros(blk_shape, np.int32),
+                "parent": np.full(blk_shape, ROOT, np.int32),
+                "valid": np.zeros(blk_shape, bool),
+            }
+            offsets = np.zeros(self.d, np.int32)
+            for di, rows in enumerate(rows_per_doc):
+                if not rows:
+                    continue
+                k = len(rows)
+                arr = np.asarray([(r[0], r[2], r[3], r[4]) for r in rows], np.int64)
+                pu = np.asarray([r[1] for r in rows], np.uint64)
+                blk["lamport"][di, :k] = arr[:, 0]
+                blk["peer_hi"][di, :k] = (pu >> np.uint64(32)).astype(np.uint32)
+                blk["peer_lo"][di, :k] = (pu & np.uint64(0xFFFFFFFF)).astype(np.uint32)
+                blk["counter"][di, :k] = arr[:, 1]
+                blk["target"][di, :k] = arr[:, 2]
+                blk["parent"][di, :k] = arr[:, 3]
+                blk["valid"][di, :k] = True
+                base = int(self.counts[di])
+                offsets[di] = base
+                self.move_epoch[di, base : base + k] = self.epoch
+                self.counts[di] += k
+                self.move_meta[di].extend(
+                    (r[0], r[1], r[2], r[3], r[5], r[6]) for r in rows
+                )
         n_new = [len(r) for r in rows_per_doc]
         if self._defer is not None:
             self._defer.rounds.append((blk, n_new))
         else:
             self._device_commit_moves(blk, offsets, n_new)
+            self._settle(np.flatnonzero(n_new))
 
     def _replay(self):
         from ..ops.tree_batch import tree_replay_log_batch
@@ -3319,6 +3397,7 @@ class DeviceTreeBatch:
         host = None
         eff = None
         reclaimed = 0
+        rewritten: List[int] = []
         for di, stable_e in enumerate(stable_epochs):
             if stable_e is None or not int(self.counts[di]):
                 continue
@@ -3350,6 +3429,7 @@ class DeviceTreeBatch:
             if n_keep == k:
                 continue
             reclaimed += k - n_keep
+            rewritten.append(di)
             old_rows = np.flatnonzero(keep)  # original append order
             for f in self.cols._fields:
                 row = host[f][di]
@@ -3366,6 +3446,8 @@ class DeviceTreeBatch:
             self.cols = TreeLogCols(
                 **{f: jax.device_put(v, sh) for f, v in host.items()}
             )
+            # the parents stand; the refused rows that went are recounted
+            self._settle(rewritten)
         return reclaimed
 
     def export_select(self, index, requests, sup=None):
@@ -3373,13 +3455,14 @@ class DeviceTreeBatch:
         return _batch_export_select(self, "tree", index, requests, sup)
 
     def parent_maps(self) -> List[dict]:
-        """{TreeID: parent TreeID | None} of alive nodes per doc (one
-        launch; same contract as Fleet.merge_tree_changes)."""
+        """{TreeID: parent TreeID | None} of alive nodes per doc, read
+        off the standing parents (same contract as
+        Fleet.merge_tree_changes)."""
         from ..ops.tree_batch import ABSENT, ROOT, is_deleted_batch
 
-        parents, _eff = self._replay()
-        deleted = np.asarray(is_deleted_batch(parents))
-        parents = np.asarray(parents)
+        with self._dev_lock:  # a commit donates the standing buffers
+            deleted = np.asarray(is_deleted_batch(self.parents))
+            parents = np.asarray(self.parents)
         out = []
         for di in range(self.n_docs):
             res = {}
@@ -3548,12 +3631,15 @@ class DeviceTreeBatch:
             raise DecodeError(f"DeviceTreeBatch state: malformed doc ({e})") from None
         sh = doc_sharding(batch.mesh)
         batch.cols = TreeLogCols(**{f: jax.device_put(v, sh) for f, v in host.items()})
+        batch._settle_whole()  # the standing state is derived, not saved
         return batch
 
     def children_maps(self) -> List[dict]:
         """{parent | None: [children in (fractional-index, move-key)
         order]} per doc — the materialized tree shape (same contract as
-        Fleet.merge_tree_children)."""
+        Fleet.merge_tree_children).  Sibling order needs every move's
+        effected flag, which the standing state does not keep: this read
+        replays the whole table."""
         from ..ops.tree_batch import ABSENT, ROOT, is_deleted_batch
 
         parents, eff = self._replay()
@@ -3671,6 +3757,13 @@ def _release_rows(arrays, di, fills):
         a.at[di].set(jnp.full((a.shape[1],), f, a.dtype))  # tpulint: disable=LT-PAD(in-jit row fill at the array's OWN static capacity — already bucketed at allocation, no new shape can exist)
         for a, f in zip(arrays, fills)
     )
+
+
+@functools.partial(jax.jit, donate_argnums=(0,))
+def _release_count(counts, di):
+    """Zero document ``di``'s entry of a per-document ``[d]`` count
+    (donated, one launch): ``release_doc``'s half for such arrays."""
+    return counts.at[di].set(0)
 
 
 # smallest block of named documents (as the key rows of

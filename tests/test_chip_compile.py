@@ -307,6 +307,40 @@ def test_resident_scatter_moves_the_named_block_and_no_table(
 
 
 @pytest.mark.slow
+@pytest.mark.parametrize("devices", [1, 4], ids=["one_chip", "mesh4"])
+def test_resident_tree_settle_compiles_at_the_cells_size(topo, tpu_branches, devices):
+    """``tree_settle_docs`` as ``tree_resident.coldstart16`` launches it: a
+    table of 144 x 131,072 log rows, the block of the 16 documents a round
+    names, 1,024 nodes.  The lock-step kernel is in the program, the
+    standing state is written in place, the temporaries follow the block
+    (not the table), and on four devices nothing crosses devices.  The
+    block's sort makes it a slow compile (about two minutes on a CPU host)."""
+    from loro_tpu.ops import tree_batch as tb
+
+    assert tb.replay_algo(1024) == "pallas:lockstep"
+    d, cap, n, k_pad = 144, 131_072, 1024, 16
+    mesh = Mesh(np.array(topo.devices[:devices]).reshape(devices, 1),
+                (DOC_AXIS, OP_AXIS))
+    sh, rep = NamedSharding(mesh, P(DOC_AXIS)), NamedSharding(mesh, P())
+    dts = (jnp.int32, jnp.uint32, jnp.uint32, jnp.int32, jnp.int32, jnp.int32, jnp.bool_)
+    cols = tb.TreeLogCols(*[sds((d, cap), dt, sh) for dt in dts])
+    standing = (sds((d, n), jnp.int32, sh), sds((d,), jnp.int32, sh))
+    t0 = time.perf_counter()
+    compiled = tb.tree_settle_docs.lower(
+        cols, standing, sds((k_pad,), jnp.int32, rep), n, mesh).compile()
+    mem, text = compiled.memory_analysis(), compiled.as_text()
+    block = 25 * k_pad * cap  # the named documents' logs
+    print(json.dumps({"compiled": f"tree_settle_docs:{devices}:[{d},{cap}]:[{k_pad}]:n{n}",
+                      "compile_s": round(time.perf_counter() - t0, 1),
+                      "temp_bytes": mem.temp_size_in_bytes,
+                      "alias_bytes": mem.alias_size_in_bytes, "block_bytes": block}))
+    assert "tpu_custom_call" in text
+    assert mem.alias_size_in_bytes >= 4 * (d // devices) * (n + 1)  # in place
+    assert mem.temp_size_in_bytes < 4 * block
+    assert "all-gather" not in text and "all-reduce" not in text
+
+
+@pytest.mark.slow
 def test_resident_materialise_compiles_at_real_size(one_chip):
     """``serve``: materialize_by_key over the 4096 x 16,384 resident
     ``SeqColumnsU`` — watch the sort temporaries."""

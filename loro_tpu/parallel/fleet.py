@@ -47,6 +47,7 @@ from ..ops.columnar import (
     contract_chains,
     extract_seq_container,
     pack_chain_row,
+    pack_wire_ids,
 )
 from ..ops.fugue_batch import (
     ChainColumns,
@@ -2890,9 +2891,15 @@ class DeviceTreeBatch:
     ``release_doc``, ``grow``, ``compact``, ``import_state`` (one whole
     replay) and, through the commit, journal replay."""
 
+    # a never-written log row, field by field (parent: tree_batch.ROOT)
+    _COL_FILLS = dict(
+        lamport=0, peer_hi=0, peer_lo=0, counter=0, target=0,
+        parent=-1, valid=False,
+    )
+
     def __init__(self, n_docs: int, move_capacity: int, node_capacity: int, mesh=None,
                  auto_grow: bool = False):
-        from ..ops.tree_batch import ABSENT, ROOT, TreeLogCols
+        from ..ops.tree_batch import ABSENT, TreeLogCols
 
         self.mesh = mesh if mesh is not None else make_mesh()
         self.n_docs = n_docs
@@ -2905,21 +2912,17 @@ class DeviceTreeBatch:
         self.epoch = 0
         self.move_epoch = np.full((self.d, move_capacity), -1, np.int64)
         # per-doc node dictionaries + host move metadata for sibling
-        # positions: (lamport, peer, counter, target_ord, is_delete, pos)
+        # positions, row-aligned with the log: a list of column chunks a
+        # doc (``_tree_columns_of``' fields, a round's chunk appended at
+        # each commit, joined by ``_move_meta`` when read)
         self.node_ids: List[Dict] = [dict() for _ in range(self.d)]
         self.nodes: List[list] = [[] for _ in range(self.d)]
         self.move_meta: List[list] = [[] for _ in range(self.d)]
         sh = doc_sharding(self.mesh)
-        z = lambda dt, fill: jax.device_put(np.full((self.d, move_capacity), fill, dt), sh)
-        self.cols = TreeLogCols(
-            lamport=z(np.int32, 0),
-            peer_hi=z(np.uint32, 0),
-            peer_lo=z(np.uint32, 0),
-            counter=z(np.int32, 0),
-            target=z(np.int32, 0),
-            parent=z(np.int32, ROOT),
-            valid=z(bool, False),
-        )
+        self.cols = TreeLogCols(**{
+            f: jax.device_put(v, sh)
+            for f, v in self._block(self.d, move_capacity).items()
+        })
         # the standing answer of every document's whole log
         self.parents = jax.device_put(
             np.full((self.d, node_capacity), ABSENT, np.int32), sh)
@@ -2984,50 +2987,43 @@ class DeviceTreeBatch:
         return d
 
     def commit_detached(self, d) -> None:
+        """Ship a detached group's round blocks as ONE scatter of the
+        UNION of the documents its rounds name, then settle that union
+        once.  A document's segments across rounds are contiguous
+        (appends only extend the tail), so its merged row is their
+        concatenation at its group-start offset (``DeviceDocBatch.
+        commit_detached``' layout).  Never grows: a merged window that
+        outgrew capacity by bucket rounding falls back to per-round
+        scatters, each validated at stage time."""
         from ..ops.fugue_batch import pad_bucket
-        from ..ops.tree_batch import ROOT
 
         if d is None or not d.rounds:
             return
         with self._dev_lock:
-            fills = dict(
-                lamport=0, peer_hi=0, peer_lo=0, counter=0, target=0,
-                parent=ROOT, valid=False,
-            )
             total = np.zeros(self.d, np.int64)
-            for _blk, n_new in d.rounds:
-                total += np.asarray(n_new, np.int64)
+            for _blk, docs, r_new in d.rounds:
+                total[docs] += r_new
+            named = np.flatnonzero(total)
             width = pad_bucket(int(total.max()), floor=16)
-            need = max(
-                (int(d.base0[di]) + width
-                 for di in range(self.d) if total[di]),
-                default=0,
-            )
-            if need > self.cap:
-                # bucket rounding outgrew capacity: per-round fallback
-                # (no grow here — it would race the next group's stage)
+            if int((d.base0[named] + width).max()) > self.cap:
                 off = d.base0.astype(np.int64).copy()
-                for blk, n_new in d.rounds:
-                    self._device_commit_moves(blk, off.astype(np.int32), n_new)
-                    off += np.asarray(n_new, np.int64)
+                for blk, docs, r_new in d.rounds:
+                    self._device_commit_moves(blk, docs, off[docs], int(r_new.sum()))
+                    off[docs] += r_new
             else:
-                blk = {
-                    f: np.full((self.d, width), fill,
-                               dtype=d.rounds[0][0][f].dtype)
-                    for f, fill in fills.items()
-                }
-                pos = np.zeros(self.d, np.int64)
-                for rblk, n_new in d.rounds:
-                    for di, k in enumerate(n_new):
-                        if not k:
-                            continue
-                        p = int(pos[di])
+                blk = self._block(_named_bucket(len(named)), width)
+                row_of = np.zeros(self.d, np.int64)
+                row_of[named] = np.arange(len(named))
+                pos = np.zeros(len(named), np.int64)
+                for rblk, docs, r_new in d.rounds:
+                    for j, k in enumerate(r_new.tolist()):
+                        i = int(row_of[docs[j]])
+                        p = int(pos[i])
                         for f in blk:
-                            blk[f][di, p : p + k] = rblk[f][di, :k]
-                        pos[di] += k
-                self._device_commit_moves(blk, d.base0.astype(np.int32), total)
-            # the UNION of the documents the group's rounds name
-            self._settle(np.flatnonzero(total))
+                            blk[f][i, p : p + k] = rblk[f][j, :k]
+                        pos[i] += k
+                self._device_commit_moves(blk, named, d.base0[named], int(total.sum()))
+            self._settle(named)
             obs.counter("pipeline.coalesced_rounds_total").inc(
                 len(d.rounds), family="tree"
             )
@@ -3035,28 +3031,47 @@ class DeviceTreeBatch:
     def flush_coalesce(self) -> None:
         self.commit_detached(self.detach_coalesce())
 
-    def _device_commit_moves(self, blk, offsets, n_new) -> None:
+    def _block(self, k_pad: int, width: int) -> dict:
+        """A scatter block of ``k_pad`` document rows, every field at its
+        never-written fill."""
+        dtypes = dict(self._STATE_SCHEMA, valid=np.bool_)
+        return {
+            f: np.full((k_pad, width), fill, dtypes[f]) if fill
+            else np.zeros((k_pad, width), dtypes[f])
+            for f, fill in self._COL_FILLS.items()
+        }
+
+    def _device_commit_moves(self, blk, named, offsets, n_rows) -> None:
+        """The device tail of an append: ONE ``_scatter_tree_rows`` of the
+        block of the documents a round names.  Block row ``j`` holds
+        document ``named[j]``'s new rows for ``offsets[j]``; rows past
+        ``len(named)`` go up under the document index -1 and are dropped;
+        ``n_rows`` is the block's live rows.  On a mesh of several devices
+        the block goes up replicated and each device writes the documents
+        it holds."""
+        k_pad = blk["valid"].shape[0]
+        d_idx = np.full(k_pad, -1, np.int32)
+        d_idx[: len(named)] = named
+        off = np.zeros(k_pad, np.int32)
+        off[: len(named)] = offsets
         obs.counter("fleet.device_launches_total").inc(family="resident_tree")
         obs.counter("fleet.pad_waste_rows_total").inc(
-            int(self.d * blk["valid"].shape[1] - int(np.sum(n_new))),
-            family="resident_tree",
+            int(blk["valid"].size - n_rows), family="resident_tree"
         )
-        with self._dev_lock, tracing.span(
-            "resident.upload", docs=int(np.count_nonzero(n_new))
-        ):
-            sh = doc_sharding(self.mesh)
+        with self._dev_lock, tracing.span("resident.upload", docs=len(named)):
+            rep = replicated(self.mesh)
             self.cols = _scatter_tree_rows(
                 self.cols,
-                {f: jax.device_put(v, sh) for f, v in blk.items()},
-                jax.device_put(
-                    np.asarray(offsets, np.int32), replicated(self.mesh)
-                ),
+                {f: jax.device_put(v, rep) for f, v in blk.items()},
+                jax.device_put(d_idx, rep),
+                jax.device_put(off, rep),
+                self.mesh,
             )
 
     def release_doc(self, di: int) -> None:
         """Reset doc ``di`` to a never-used slot (tiered-residency
         eviction; see DeviceDocBatch.release_doc for the contract)."""
-        from ..ops.tree_batch import ABSENT, ROOT, TreeLogCols
+        from ..ops.tree_batch import ABSENT, TreeLogCols
 
         self.counts[di] = 0
         self.move_epoch[di, :] = -1
@@ -3067,14 +3082,10 @@ class DeviceTreeBatch:
             self._defer.base0[di] = 0
         with self._dev_lock:
             fields = list(self.cols._fields)
-            fills = dict(
-                lamport=0, peer_hi=0, peer_lo=0, counter=0, target=0,
-                parent=ROOT, valid=False,
-            )
             out = _release_rows(
                 tuple(getattr(self.cols, f) for f in fields),
                 jnp.int32(di),
-                tuple(fills[f] for f in fields),
+                tuple(self._COL_FILLS[f] for f in fields),
             )
             self.cols = TreeLogCols(**dict(zip(fields, out)))
             # an empty log's answer: no node, nothing refused
@@ -3090,27 +3101,25 @@ class DeviceTreeBatch:
         a capacity error leaves the batch untouched (the DeviceDocBatch
         atomicity contract)."""
         per_doc_changes = list(per_doc_changes) + [None] * (self.d - len(per_doc_changes))
-        rows_per_doc: List[list] = []
-        staged_nodes: List[list] = []
+        staged: List[Optional[dict]] = [None] * self.d
+        new_nodes: List[list] = [[] for _ in range(self.d)]
         with tracing.span("resident.decode"):
             for di, changes in enumerate(per_doc_changes):
-                rows: list = []
-                staged_order: list = []
-                rows_per_doc.append(rows)
-                staged_nodes.append(staged_order)
                 if changes:
-                    self._explode_changes_into(di, changes, cid, rows, staged_order)
-        self._commit_moves(rows_per_doc, staged_nodes)
+                    staged[di] = self._walk_changes(di, changes, cid, new_nodes[di])
+        self._commit_moves(staged, new_nodes)
 
     def append_payloads(self, per_doc_payloads: Sequence[Optional[bytes]], cid) -> None:
         """Incremental NATIVE ingest: envelope-stripped binary payloads
         -> C++ tree explode (wire order — the device replay sorts by the
-        global move key anyway) -> one block scatter.  Falls back to the
-        Python decoder per payload on unresolvable input."""
-        from ..codec.binary import decode_changes, read_tables
-        from ..core.ids import TreeID
-        from ..native import available, explode_tree_payload
-        from ..ops.tree_batch import ROOT, TRASH
+        global move key anyway) -> each document's moves as columns
+        (``_stage_payload``: no Python object a move) -> one block of the
+        documents the round names.  A payload the native path cannot
+        resolve falls back to the Python walk
+        (``fleet.host_fallback_total{kind="tree_payload"}``); every other
+        one counts in ``fleet.resident_tree_column_docs_total``."""
+        from ..codec.binary import decode_changes
+        from ..native import available
 
         if not available():
             self.append_changes(
@@ -3120,105 +3129,133 @@ class DeviceTreeBatch:
         per_doc_payloads = list(per_doc_payloads) + [None] * (
             self.d - len(per_doc_payloads)
         )
-        rows_per_doc: List[list] = []
-        staged_nodes: List[list] = []
-        fallback: List[Tuple[int, bytes]] = []
+        staged: List[Optional[dict]] = [None] * self.d
+        new_nodes: List[list] = [[] for _ in range(self.d)]
+        column_docs = 0
         with tracing.span("resident.decode"):
             for di, payload in enumerate(per_doc_payloads):
-                rows: list = []
-                staged: Dict = {}
-                staged_order: list = []
-                rows_per_doc.append(rows)
-                staged_nodes.append(staged_order)
                 if not payload:
                     continue
-                ids = self.node_ids[di]
-                n_committed = len(self.nodes[di])
-
-                def node_idx(tid):
-                    i = ids.get(tid)
-                    if i is None:
-                        i = staged.get(tid)
-                    if i is None:
-                        i = n_committed + len(staged_order)
-                        staged[tid] = i
-                        staged_order.append(tid)
-                    return i
-
                 try:
-                    peers_wire, _keys, cids, _r = read_tables(payload)
-                    try:
-                        target = cids.index(cid)
-                    except ValueError:
-                        continue  # no ops for this container
-                    out = explode_tree_payload(payload, target)
-                    fl = out["flags"]
-                    for i in range(len(out["lamport"])):
-                        tid = TreeID(
-                            int(peers_wire[int(out["target_peer_idx"][i])]),
-                            int(out["target_ctr"][i]),
-                        )
-                        t = node_idx(tid)
-                        if fl[i] & 2:  # delete
-                            p = TRASH
-                            is_del = True
-                        elif fl[i] & 4:  # has parent
-                            p = node_idx(
-                                TreeID(
-                                    int(peers_wire[int(out["parent_peer_idx"][i])]),
-                                    int(out["parent_ctr"][i]),
-                                )
-                            )
-                            is_del = False
-                        else:
-                            p = ROOT
-                            is_del = False
-                        pos = None
-                        if fl[i] & 8:
-                            o = int(out["pos_off"][i])
-                            pos = bytes(payload[o : o + int(out["pos_len"][i])])
-                        rows.append(
-                            (
-                                int(out["lamport"][i]),
-                                int(peers_wire[int(out["peer_idx"][i])]),
-                                int(out["counter"][i]),
-                                t,
-                                p,
-                                is_del,
-                                pos,
-                            )
-                        )
-                except ValueError:
-                    rows.clear()
-                    staged.clear()
-                    staged_order.clear()
-                    fallback.append((di, payload))
-            for di, payload in fallback:  # python walk per unresolvable payload
-                self._explode_changes_into(
-                    di, decode_changes(payload), cid, rows_per_doc[di], staged_nodes[di]
-                )
-        self._commit_moves(rows_per_doc, staged_nodes)
+                    staged[di] = self._stage_payload(di, payload, cid, new_nodes[di])
+                    column_docs += 1
+                except (ValueError, IndexError):
+                    new_nodes[di].clear()
+                    _obs_fallback("tree_payload")
+                    staged[di] = self._walk_changes(
+                        di, decode_changes(payload), cid, new_nodes[di])
+        obs.counter("fleet.resident_tree_column_docs_total").inc(column_docs)
+        self._commit_moves(staged, new_nodes)
 
-    def _explode_changes_into(self, di, changes, cid, rows, staged_order) -> None:
-        """Python change walk appending into pre-staged row/node lists
-        (the append_payloads fallback)."""
+    def _stage_payload(self, di, payload, cid, new_nodes) -> Optional[dict]:
+        """One payload's moves of ``cid`` as columns, built from
+        ``native.explode_tree``'s arrays by array operations (the form
+        ``_commit_moves`` takes: ``_tree_columns_of``'s fields), or None
+        when it holds none.  Node references are numbered by
+        ``_node_numbers``; ``new_nodes`` gets the new ones.  Raises
+        ValueError (or IndexError) on input the native path cannot
+        resolve."""
+        from ..codec.binary import read_tables
+        from ..native import explode_tree_payload
+        from ..ops.tree_batch import ROOT, TRASH
+
+        peers_wire, _keys, cids, _r = read_tables(payload)
+        try:
+            target = cids.index(cid)
+        except ValueError:
+            return None  # no ops for this container
+        out = explode_tree_payload(payload, target)
+        n = len(out["lamport"])
+        if not n:
+            return None
+        fl = out["flags"]
+        is_del = (fl & 2) != 0
+        has_parent = ((fl & 4) != 0) & ~is_del
+        if out["target_ctr"].min() < 0 or (out["parent_ctr"][has_parent] < 0).any():
+            raise ValueError("tree node counter below 0")
+        # the node references move by move, the target before the parent
+        refs = np.empty((n, 2), np.int64)
+        refs[:, 0] = pack_wire_ids(out["target_peer_idx"], out["target_ctr"])
+        refs[:, 1] = pack_wire_ids(out["parent_peer_idx"], out["parent_ctr"])
+        referenced = np.empty((n, 2), bool)
+        referenced[:, 0] = True
+        referenced[:, 1] = has_parent
+        node = np.empty((n, 2), np.int32)
+        node[referenced] = self._node_numbers(
+            di, refs[referenced], peers_wire, new_nodes)
+        parent = np.where(has_parent, node[:, 1], ROOT).astype(np.int32)
+        parent[is_del] = TRASH
+        arena, pos_off, pos_len = _payload_positions(
+            payload, out["pos_off"], np.where((fl & 8) != 0, out["pos_len"], -1))
+        return {
+            "lamport": out["lamport"].astype(np.int64),
+            "peer": np.asarray(peers_wire, np.uint64)[out["peer_idx"]],
+            "counter": out["counter"].astype(np.int64),
+            "target": node[:, 0].copy(),
+            "parent": parent,
+            "is_del": is_del,
+            "pos_off": pos_off,
+            "pos_len": pos_len,
+            "arena": arena,
+        }
+
+    def _node_numbers(self, di, refs, peers_wire, new_nodes) -> np.ndarray:
+        """The node index of each packed (wire peer, counter) reference
+        of document ``di``, exactly as the Python walk numbers them:
+        committed nodes keep theirs, new ones follow the dictionary in
+        order of first appearance (their TreeIDs appended to
+        ``new_nodes``).  Python runs once a distinct NODE, never a
+        reference: first appearances come from a dense table over
+        (peer, counter) when that is small, else from one sort."""
+        from ..core.ids import TreeID
+
+        m = len(refs)
+        pidx, ctr = refs >> 32, refs & 0xFFFFFFFF
+        p0, c0 = int(pidx.min()), int(ctr.min())
+        span = int(ctr.max()) - c0 + 1
+        size = (int(pidx.max()) - p0 + 1) * span
+        if size <= 4 * m + 4096:
+            code = (pidx - p0) * span + (ctr - c0)
+        else:
+            uniq, code = np.unique(refs, return_inverse=True)
+            size = len(uniq)
+        first = np.full(size, m, np.int64)
+        np.minimum.at(first, code, np.arange(m))
+        seen = np.flatnonzero(first < m)
+        seen = seen[np.argsort(first[seen], kind="stable")]
+        lut = np.empty(size, np.int32)
+        ids, base = self.node_ids[di], len(self.nodes[di])
+        for u, key in zip(seen.tolist(), refs[first[seen]].tolist()):
+            tid = TreeID(peers_wire[key >> 32], key & 0xFFFFFFFF)
+            i = ids.get(tid)
+            if i is None:
+                i = base + len(new_nodes)
+                new_nodes.append(tid)
+            lut[u] = i
+        return lut[code]
+
+    def _walk_changes(self, di, changes, cid, new_nodes) -> Optional[dict]:
+        """The Python change walk (``append_changes``, and
+        ``append_payloads``' fallback): the moves of ``cid`` in
+        ``_stage_payload``'s columns, nodes numbered as it numbers them,
+        or None when there are none."""
         from ..core.change import TreeMove
         from ..ops.tree_batch import ROOT, TRASH
 
         ids = self.node_ids[di]
-        n_committed = len(self.nodes[di])
-        staged = {tid: n_committed + i for i, tid in enumerate(staged_order)}
+        base = len(self.nodes[di])
+        staged: Dict = {}
 
         def node_idx(tid):
             i = ids.get(tid)
             if i is None:
                 i = staged.get(tid)
             if i is None:
-                i = n_committed + len(staged_order)
-                staged[tid] = i
-                staged_order.append(tid)
+                i = staged[tid] = base + len(new_nodes)
+                new_nodes.append(tid)
             return i
 
+        rows = []
         for ch in changes:
             for op in ch.ops:
                 if op.container != cid or not isinstance(op.content, TreeMove):
@@ -3233,23 +3270,20 @@ class DeviceTreeBatch:
                 else:
                     p = node_idx(c.parent)
                 rows.append((lam, ch.peer, op.counter, t, p, c.is_delete, c.position))
+        return _tree_columns_of(*zip(*rows)) if rows else None
 
     def grow(self, move_capacity: int = None, node_capacity: int = None) -> None:
         """Repack move-log columns and/or raise the node ceiling
         (resident lifecycle, r4 verdict #6).  The node ceiling widens
         the standing parents (new nodes read ABSENT): the answers of the
         logs do not change."""
-        from ..ops.tree_batch import ABSENT, ROOT, TreeLogCols
+        from ..ops.tree_batch import ABSENT, TreeLogCols
 
         if move_capacity is not None and move_capacity > self.cap:
             with self._dev_lock:  # vs an in-flight pipelined commit
-                fills = dict(
-                    lamport=0, peer_hi=0, peer_lo=0, counter=0, target=0,
-                    parent=ROOT, valid=False,
-                )
                 cols = _pad_axis1(
                     {f: getattr(self.cols, f) for f in self.cols._fields},
-                    move_capacity, fills, doc_sharding(self.mesh),
+                    move_capacity, self._COL_FILLS, doc_sharding(self.mesh),
                 )
                 self.cols = TreeLogCols(**cols)
                 me = np.full((self.d, move_capacity), -1, np.int64)
@@ -3264,25 +3298,26 @@ class DeviceTreeBatch:
                 )["parents"]
                 self.node_cap = node_capacity
 
-    def _commit_moves(self, rows_per_doc, staged_nodes) -> None:
-        """Shared tail: validate capacities, commit staged nodes, block-
-        scatter the new move rows, settle the documents they name."""
+    def _commit_moves(self, staged, new_nodes) -> None:
+        """Shared tail: validate capacities, commit the new nodes, write
+        each named document's move columns into ONE block of the
+        documents the round names (``[_named_bucket(named), max_new]``,
+        row ``j`` document ``named[j]``), scatter it, settle them.
+        ``staged`` holds a slot's columns (``_tree_columns_of``'s fields
+        + ``parent``) or None; ``new_nodes`` the TreeIDs its columns
+        number past the slot's dictionary."""
         from ..ops.fugue_batch import pad_bucket
-        from ..ops.tree_batch import ROOT
 
-        max_new = (
-            pad_bucket(max((len(r) for r in rows_per_doc), default=0), floor=16)
-            if any(rows_per_doc)
-            else 0
+        named = np.asarray(
+            [di for di, c in enumerate(staged) if c is not None and len(c["target"])],
+            np.int64,
         )
+        n_new = np.asarray([len(staged[di]["target"]) for di in named], np.int64)
+        max_new = pad_bucket(int(n_new.max()), floor=16) if len(named) else 0
         # validate BEFORE mutating anything
-        req_moves = max(
-            (int(self.counts[di]) + max_new
-             for di, rows in enumerate(rows_per_doc) if rows),
-            default=0,
-        )
+        req_moves = int((self.counts[named] + max_new).max()) if len(named) else 0
         req_nodes = max(
-            (len(self.nodes[di]) + len(staged_nodes[di]) for di in range(self.d)),
+            (len(self.nodes[di]) + len(new) for di, new in enumerate(new_nodes)),
             default=0,
         )
         if req_moves > self.cap:
@@ -3309,55 +3344,45 @@ class DeviceTreeBatch:
         # which recovery replay skips and which un-pin WAL retention
         # under a live follower (chaos seed 4).
         self.epoch += 1
-        if not max_new:
+        if not len(named):
             return
-        obs.counter("fleet.resident_tree_moves_total").inc(
-            sum(len(r) for r in rows_per_doc)
-        )
-        with tracing.span("resident.stage", docs=sum(1 for r in rows_per_doc if r),
-                          bytes=25 * self.d * max_new):
-            # commit staged node registrations
-            for di, staged_order in enumerate(staged_nodes):
-                for tid in staged_order:
-                    self.node_ids[di][tid] = len(self.nodes[di])
-                    self.nodes[di].append(tid)
-            blk_shape = (self.d, max_new)
-            blk = {
-                "lamport": np.zeros(blk_shape, np.int32),
-                "peer_hi": np.zeros(blk_shape, np.uint32),
-                "peer_lo": np.zeros(blk_shape, np.uint32),
-                "counter": np.zeros(blk_shape, np.int32),
-                "target": np.zeros(blk_shape, np.int32),
-                "parent": np.full(blk_shape, ROOT, np.int32),
-                "valid": np.zeros(blk_shape, bool),
-            }
-            offsets = np.zeros(self.d, np.int32)
-            for di, rows in enumerate(rows_per_doc):
-                if not rows:
-                    continue
-                k = len(rows)
-                arr = np.asarray([(r[0], r[2], r[3], r[4]) for r in rows], np.int64)
-                pu = np.asarray([r[1] for r in rows], np.uint64)
-                blk["lamport"][di, :k] = arr[:, 0]
-                blk["peer_hi"][di, :k] = (pu >> np.uint64(32)).astype(np.uint32)
-                blk["peer_lo"][di, :k] = (pu & np.uint64(0xFFFFFFFF)).astype(np.uint32)
-                blk["counter"][di, :k] = arr[:, 1]
-                blk["target"][di, :k] = arr[:, 2]
-                blk["parent"][di, :k] = arr[:, 3]
-                blk["valid"][di, :k] = True
+        obs.counter("fleet.resident_tree_moves_total").inc(int(n_new.sum()))
+        k_pad = _named_bucket(len(named))
+        with tracing.span("resident.stage", docs=len(named),
+                          bytes=25 * k_pad * max_new):
+            for di, new in enumerate(new_nodes):
+                ids, nodes = self.node_ids[di], self.nodes[di]
+                for tid in new:
+                    ids[tid] = len(nodes)
+                    nodes.append(tid)
+            blk = self._block(k_pad, max_new)
+            offsets = self.counts[named].copy()
+            for j, (di, k) in enumerate(zip(named.tolist(), n_new.tolist())):
+                c = staged[di]
+                blk["lamport"][j, :k] = c["lamport"]
+                blk["peer_hi"][j, :k] = c["peer"] >> np.uint64(32)
+                blk["peer_lo"][j, :k] = c["peer"] & np.uint64(0xFFFFFFFF)
+                blk["counter"][j, :k] = c["counter"]
+                blk["target"][j, :k] = c["target"]
+                blk["parent"][j, :k] = c["parent"]
+                blk["valid"][j, :k] = True
                 base = int(self.counts[di])
-                offsets[di] = base
                 self.move_epoch[di, base : base + k] = self.epoch
                 self.counts[di] += k
-                self.move_meta[di].extend(
-                    (r[0], r[1], r[2], r[3], r[5], r[6]) for r in rows
-                )
-        n_new = [len(r) for r in rows_per_doc]
+                self.move_meta[di].append(
+                    {f: c[f] for f in _TREE_META_FIELDS + ("arena",)})
         if self._defer is not None:
-            self._defer.rounds.append((blk, n_new))
+            self._defer.rounds.append((blk, named, n_new))
         else:
-            self._device_commit_moves(blk, offsets, n_new)
-            self._settle(np.flatnonzero(n_new))
+            self._device_commit_moves(blk, named, offsets, int(n_new.sum()))
+            self._settle(named)
+
+    def _move_meta(self, di: int) -> dict:
+        """Document ``di``'s move metadata as ONE set of columns, row-
+        aligned with its log (the rounds' chunks joined on first read)."""
+        m = _tree_meta_join(self.move_meta[di])
+        self.move_meta[di] = [m] if len(m["target"]) else []
+        return m
 
     def _replay(self):
         from ..ops.tree_batch import tree_replay_log_batch
@@ -3384,7 +3409,7 @@ class DeviceTreeBatch:
         reclaimed (targets keep their ordinals).  Returns rows dropped.
         Reference analog: loro's tree uses the same last-writer state
         under its shallow-snapshot floor (shallow_snapshot.rs:16-40)."""
-        from ..ops.tree_batch import ROOT, TreeLogCols
+        from ..ops.tree_batch import TreeLogCols
 
         if len(stable_epochs) > self.d:
             raise ValueError(
@@ -3392,8 +3417,6 @@ class DeviceTreeBatch:
                 f"{self.d}-doc batch"
             )
         stable_epochs = list(stable_epochs) + [None] * (self.d - len(stable_epochs))
-        fills = dict(lamport=0, peer_hi=0, peer_lo=0, counter=0,
-                     target=0, parent=ROOT, valid=False)
         host = None
         eff = None
         reclaimed = 0
@@ -3434,12 +3457,14 @@ class DeviceTreeBatch:
             for f in self.cols._fields:
                 row = host[f][di]
                 vals = row[:k][old_rows]  # fancy index: already a copy
-                row[:] = fills[f]
+                row[:] = self._COL_FILLS[f]
                 row[:n_keep] = vals
             me = self.move_epoch[di, :k][old_rows]
             self.move_epoch[di, :] = -1
             self.move_epoch[di, :n_keep] = me
-            self.move_meta[di] = [self.move_meta[di][int(r)] for r in old_rows]
+            m = self._move_meta(di)
+            self.move_meta[di] = [
+                {**{f: m[f][old_rows] for f in _TREE_META_FIELDS}, "arena": m["arena"]}]
             self.counts[di] = n_keep
         if host is not None and reclaimed:
             sh = doc_sharding(self.mesh)
@@ -3522,16 +3547,19 @@ class DeviceTreeBatch:
                 w.u64le(tid.peer)
                 w.zigzag(tid.counter)
             kv.set(b"doc/%08d/nodes" % di, bytes(w.buf))
+            m = self._move_meta(di)
+            arena = m["arena"].tobytes()
             w = Writer()
-            w.varint(len(self.move_meta[di]))
-            for lam, peer, ctr, t, is_del, pos in self.move_meta[di]:
+            w.varint(len(m["target"]))
+            for lam, peer, ctr, t, is_del, off, n in zip(
+                    *(m[f].tolist() for f in _TREE_META_FIELDS)):
                 w.varint(lam)
                 w.u64le(peer)
                 w.zigzag(ctr)
                 w.varint(t)
-                w.u8((1 if is_del else 0) | (2 if pos is not None else 0))
-                if pos is not None:
-                    w.bytes_(pos)
+                w.u8((1 if is_del else 0) | (2 if n >= 0 else 0))
+                if n >= 0:
+                    w.bytes_(arena[off : off + n])
             kv.set(b"doc/%08d/meta" % di, bytes(w.buf))
         return kv.export_all()
 
@@ -3616,7 +3644,10 @@ class DeviceTreeBatch:
                         flags = r.u8()
                         pos = r.bytes_() if flags & 2 else None
                         mm.append((lam, peer, ctr, t, bool(flags & 1), pos))
-                    batch.move_meta[di] = mm
+                    if mm:
+                        lam, peer, ctr, t, dels, pos = zip(*mm)
+                        batch.move_meta[di] = [
+                            _tree_columns_of(lam, peer, ctr, t, None, dels, pos)]
                 if k:
                     # node ordinals must stay inside the node dict
                     # (parent_maps would IndexError on nodes[p])
@@ -3627,7 +3658,7 @@ class DeviceTreeBatch:
                         raise DecodeError("DeviceTreeBatch state: target ordinal")
                     if par.min() < -2 or par.max() >= n_nodes:
                         raise DecodeError("DeviceTreeBatch state: parent ordinal")
-        except (IndexError, ValueError, struct.error) as e:
+        except (IndexError, ValueError, OverflowError, struct.error) as e:
             raise DecodeError(f"DeviceTreeBatch state: malformed doc ({e})") from None
         sh = doc_sharding(batch.mesh)
         batch.cols = TreeLogCols(**{f: jax.device_put(v, sh) for f, v in host.items()})
@@ -3652,25 +3683,31 @@ class DeviceTreeBatch:
             # winning position = last effected non-delete move per node
             # in key order; sibling tiebreak = the winning move's key
             # order (exactly merge_tree_children's host walk)
-            meta = self.move_meta[di]
-            order = sorted(range(len(meta)), key=lambda i: meta[i][:3])
-            pos: Dict[int, object] = {}
-            last_eff: Dict[int, int] = {}
-            for oi, i in enumerate(order):
-                _lam, _peer, _ctr, t, is_del, p_ = meta[i]
-                if eff[di, i]:
-                    last_eff[t] = oi
-                    if not is_del:
-                        pos[t] = p_
+            m = self._move_meta(di)
+            order = np.lexsort((m["counter"], m["peer"], m["lamport"]))
+            tgt = m["target"][order]
+            eff_o = eff[di, : len(order)][order]
+            # per node, the place in key order of its last effected move
+            # and of its last effected non-delete move (-1: none)
+            last_eff = np.full(len(nodes), -1, np.int64)
+            at = np.flatnonzero(eff_o)
+            np.maximum.at(last_eff, tgt[at], at)
+            won = np.full(len(nodes), -1, np.int64)
+            at = np.flatnonzero(eff_o & ~m["is_del"][order])
+            np.maximum.at(won, tgt[at], at)
+            arena = m["arena"].tobytes()
             kids: Dict = {}
             for j, tid in enumerate(nodes):
                 p = int(parents[di, j])
                 if p == ABSENT or deleted[di, j]:
                     continue
                 key = None if p == ROOT else nodes[p]
-                kids.setdefault(key, []).append(
-                    (pos.get(j) or b"", last_eff.get(j, 0), tid)
-                )
+                pos = b""
+                if won[j] >= 0:
+                    r = order[won[j]]
+                    off, n = int(m["pos_off"][r]), int(m["pos_len"][r])
+                    pos = arena[off : off + n] if n > 0 else b""
+                kids.setdefault(key, []).append((pos, max(int(last_eff[j]), 0), tid))
             out.append(
                 {
                     k: [t for _, _, t in sorted(v, key=lambda x: (x[0], x[1]))]
@@ -3692,7 +3729,7 @@ class _DeferredSeqDevice:
         self.base0 = base0          # per-doc counts at group start
         # DeviceDocBatch: (blk, key_hi, key_lo, docs, rows) a round — the
         # named block, the document and the live rows of each of its
-        # rows; DeviceTreeBatch: (blk, n_new), a row a slot
+        # rows; DeviceTreeBatch: (blk, docs, rows), the same without keys
         self.rounds: List[tuple] = []
         self.renumbered: set = set()
         self.del_d: List[np.ndarray] = []
@@ -3719,14 +3756,6 @@ class _DeferredFold:
         for di, rows in enumerate(rows_per_doc):
             if rows:
                 self.rows[di].extend(rows)
-
-
-def _windowed_scatter_field(col, nbl, vbl, off):
-    """One doc-row of the block scatter: padding rows of a block restore
-    the window's previous values so short updates don't clobber
-    neighbors (shared by the seq and tree resident ingest paths)."""
-    window = jax.lax.dynamic_slice(col, (off,), (nbl.shape[0],))
-    return jax.lax.dynamic_update_slice(col, jnp.where(vbl, nbl, window), (off,))
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
@@ -4893,17 +4922,124 @@ def _fold_counter_rows(sums, slot, delta):
     return jax.vmap(per_doc)(sums, slot, delta)
 
 
-@functools.partial(jax.jit, donate_argnums=(0,))
-def _scatter_tree_rows(cols, blk, offsets):
-    """Tree-log variant of _scatter_rows (shared window semantics via
-    _windowed_scatter_field)."""
-    out = {
-        f: jax.vmap(_windowed_scatter_field)(
-            getattr(cols, f), blk[f], blk["valid"], offsets
+@functools.partial(jax.jit, donate_argnums=(0,), static_argnums=(4,))
+def _scatter_tree_rows(cols, blk, d_idx, offsets, mesh):
+    """Write the block of the documents a round NAMES into the resident
+    move log (donated update): the tree's copy of ``_scatter_rows``' row
+    loop.  ``cols`` is the ``TreeLogCols`` table ``[d, move_cap]``;
+    ``blk`` holds its seven fields as ``[k_pad, max_new]``, row ``j``
+    being document ``d_idx[j]``'s new rows for the window ``[offsets[j],
+    offsets[j] + max_new)``, replaced under ``valid`` (the window's
+    other rows keep their values).  One ``dynamic_update_slice`` a
+    block row and field, so the bytes moved follow ``k_pad * max_new``;
+    one compiled program per ``(k_pad, max_new, move_cap)`` bucket.  Pad
+    entries carry the document index -1, which no device holds, and
+    write back the window they read.  On a mesh of several devices the
+    table is doc-sharded and the block, ``d_idx`` and ``offsets`` are
+    replicated: under ``shard_map`` each device writes the rows of the
+    documents it holds."""
+    fields = cols._fields
+    arrays = tuple(getattr(cols, f) for f in fields)
+    news = tuple(blk[f] for f in fields)
+    sharded = mesh.size > 1
+
+    def on_device(arrays, news, valid, d_idx, offsets):
+        d_local = arrays[0].shape[0]
+        lo = jax.lax.axis_index(DOC_AXIS) * d_local if sharded else 0
+        width = valid.shape[1]
+
+        def write_row(j, arrays):
+            di = d_idx[j] - lo
+            held = (di >= 0) & (di < d_local)
+            at = (jnp.clip(di, 0, d_local - 1), offsets[j])
+            keep = (valid[j] & held)[None]
+            out = []
+            for a, nb in zip(arrays, news):
+                window = jax.lax.dynamic_slice(a, at, (1, width))
+                row = jax.lax.dynamic_slice(nb, (j, 0), (1, width))
+                out.append(jax.lax.dynamic_update_slice(
+                    a, jnp.where(keep, row, window), at))
+            return tuple(out)
+
+        return jax.lax.fori_loop(0, d_idx.shape[0], write_row, arrays)
+
+    if sharded:
+        docs, rep = P(DOC_AXIS), P()
+        on_device = jax.shard_map(
+            on_device, mesh=mesh, in_specs=(docs, rep, rep, rep, rep),
+            out_specs=docs, check_vma=False,
         )
-        for f in cols._fields
+    out = on_device(arrays, news, blk["valid"], d_idx, offsets)
+    return type(cols)(**dict(zip(fields, out)))
+
+
+# the fields of a tree move's host metadata (``DeviceTreeBatch.move_meta``),
+# each chunk's positions lying in its own ``arena`` (u8)
+_TREE_META_FIELDS = (
+    "lamport", "peer", "counter", "target", "is_del", "pos_off", "pos_len",
+)
+
+
+def _tree_columns_of(lamport, peer, counter, target, parent, is_del, positions) -> dict:
+    """Move columns from per-move sequences (the Python walk's rows, a
+    saved blob's metadata) in ``DeviceTreeBatch._stage_payload``'s form:
+    ``_TREE_META_FIELDS`` + ``arena`` (+ ``parent`` unless None);
+    ``positions`` are bytes or None, packed into the arena (``pos_len``
+    and ``pos_off`` -1 for None)."""
+    n = len(lamport)
+    pos_len = np.fromiter(
+        (-1 if p is None else len(p) for p in positions), np.int32, n)
+    lens = np.maximum(pos_len, 0).astype(np.int64)
+    cols = {
+        "lamport": np.asarray(lamport, np.int64),
+        "peer": np.asarray(peer, np.uint64),
+        "counter": np.asarray(counter, np.int64),
+        "target": np.asarray(target, np.int32),
+        "is_del": np.asarray(is_del, bool),
+        "pos_off": np.where(pos_len >= 0, np.cumsum(lens) - lens, -1),
+        "pos_len": pos_len,
+        "arena": np.frombuffer(b"".join(p for p in positions if p), np.uint8),
     }
-    return type(cols)(**out)
+    if parent is not None:
+        cols["parent"] = np.asarray(parent, np.int32)
+    return cols
+
+
+def _payload_positions(payload, pos_off, pos_len):
+    """The positions a payload's moves name as (arena, offsets, lengths)
+    with the payload itself as the arena (a u8 view, no copy); offset
+    and length -1 where a move has none.  Raises ValueError for a
+    position outside the payload."""
+    if not isinstance(payload, bytes):
+        payload = bytes(payload)  # the arena outlives the caller's buffer
+    has = pos_len >= 0
+    if has.any() and (pos_off[has].min() < 0
+                      or (pos_off + pos_len)[has].max() > len(payload)):
+        raise ValueError("tree position outside the payload")
+    return (np.frombuffer(payload, np.uint8),
+            np.where(has, pos_off, -1).astype(np.int64),
+            np.where(has, pos_len, -1).astype(np.int32))
+
+
+def _tree_meta_join(chunks) -> dict:
+    """One document's metadata chunks as one set of columns whose arena
+    holds their positions alone, in row order (a chunk staged from a
+    payload keeps the whole payload as its arena until this read)."""
+    if not chunks:
+        return _tree_columns_of((), (), (), (), None, (), ())
+    lens = [np.maximum(c["pos_len"], 0).astype(np.int64) for c in chunks]
+    if len(chunks) == 1 and len(chunks[0]["arena"]) == int(lens[0].sum()):
+        return chunks[0]  # one chunk, already packed
+    out = {f: np.concatenate([c[f] for c in chunks]) for f in _TREE_META_FIELDS}
+    arenas = []
+    for c, n in zip(chunks, lens):
+        starts = np.cumsum(n) - n
+        arenas.append(c["arena"][np.repeat(c["pos_off"] - starts, n)
+                                 + np.arange(int(n.sum()))])
+    n = np.concatenate(lens)
+    out["pos_off"] = np.where(out["pos_len"] >= 0, np.cumsum(n) - n, -1)
+    out["arena"] = np.concatenate(arenas)
+    return out
 
 
 @functools.lru_cache(maxsize=32)

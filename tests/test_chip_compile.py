@@ -306,6 +306,35 @@ def test_resident_scatter_moves_the_named_block_and_no_table(
     assert "all-gather" not in text and text.count(" while(") == 1
 
 
+@pytest.mark.parametrize("devices", [1, 4], ids=["one_chip", "mesh4"])
+def test_resident_tree_scatter_moves_the_named_block_and_no_table(topo, devices):
+    """``_scatter_tree_rows`` as ``tree_resident.coldstart16`` launches it:
+    the block of the 16 documents a round names, ``[16, 131,072]``, into
+    the table of 144 x 131,072 log rows.  One loop over the block's rows,
+    every column written in place (no ``[d, move_cap]`` temporary), and on
+    four devices no all-gather."""
+    from loro_tpu.ops import tree_batch as tb
+    from loro_tpu.parallel.fleet import _scatter_tree_rows
+
+    d, cap, k_pad, width = 144, 131_072, 16, 131_072
+    mesh = Mesh(np.array(topo.devices[:devices]).reshape(devices, 1),
+                (DOC_AXIS, OP_AXIS))
+    sh, rep = NamedSharding(mesh, P(DOC_AXIS)), NamedSharding(mesh, P())
+    dts = (jnp.int32, jnp.uint32, jnp.uint32, jnp.int32, jnp.int32, jnp.int32, jnp.bool_)
+    cols = tb.TreeLogCols(*[sds((d, cap), dt, sh) for dt in dts])
+    blk = {f: sds((k_pad, width), dt, rep) for f, dt in zip(tb.TreeLogCols._fields, dts)}
+    idx = sds((k_pad,), jnp.int32, rep)
+    compiled = _scatter_tree_rows.lower(cols, blk, idx, idx, mesh).compile()
+    mem, text = compiled.memory_analysis(), compiled.as_text()
+    table = 25 * (d // devices) * cap  # a device's share of the table
+    print(json.dumps({"compiled": f"_scatter_tree_rows:{devices}:[{d},{cap}]:[{k_pad},{width}]",
+                      "temp_bytes": mem.temp_size_in_bytes,
+                      "alias_bytes": mem.alias_size_in_bytes, "table_bytes": table}))
+    assert mem.alias_size_in_bytes >= table  # donated: written in place
+    assert mem.temp_size_in_bytes < (d // devices) * cap  # under one bool column
+    assert "all-gather" not in text and text.count(" while(") == 1
+
+
 @pytest.mark.slow
 @pytest.mark.parametrize("devices", [1, 4], ids=["one_chip", "mesh4"])
 def test_resident_tree_settle_compiles_at_the_cells_size(topo, tpu_branches, devices):

@@ -1320,7 +1320,7 @@ class TestTreePayloadIngest:
         def boom(*a, **k):
             raise AssertionError("python fallback must not run")
 
-        monkeypatch.setattr(batch, "_explode_changes_into", boom)
+        monkeypatch.setattr(batch, "_walk_changes", boom)
         marks = [a.oplog_vv() for a, _ in pairs]
         batch.append_payloads(
             [strip_envelope(a.export_updates(None)) for a, _ in pairs], cid

@@ -11,6 +11,7 @@ export / import of state, checkpoint / restore, WAL recovery and coalesced
 rounds; and the settle's block follows the documents a round names."""
 from __future__ import annotations
 
+import gc
 import json
 import os
 import random
@@ -28,11 +29,14 @@ if BENCH not in sys.path:
 import tree_gen  # noqa: E402
 import tree_reference  # noqa: E402
 
-from loro_tpu import LoroDoc  # noqa: E402
-from loro_tpu.core.ids import ContainerID, ContainerType  # noqa: E402
+from loro_tpu import LoroDoc, native  # noqa: E402
+from loro_tpu.codec.binary import decode_changes  # noqa: E402
+from loro_tpu.core.ids import ContainerID, ContainerType, TreeID  # noqa: E402
 from loro_tpu.doc import strip_envelope  # noqa: E402
 from loro_tpu.obs import metrics as obs  # noqa: E402
 from loro_tpu.ops import tree_batch  # noqa: E402
+from loro_tpu.ops.fugue_batch import pad_bucket  # noqa: E402
+from loro_tpu.parallel import fleet  # noqa: E402
 from loro_tpu.ops.tree_batch import ABSENT, tree_replay_log_batch  # noqa: E402
 from loro_tpu.parallel.fleet import DeviceTreeBatch  # noqa: E402
 from loro_tpu.parallel.mesh import make_mesh  # noqa: E402
@@ -347,3 +351,355 @@ def test_a_coalesced_group_settles_the_union_of_its_rounds_once(documents, mesh)
                                   np.asarray(serial.batch.parents))
     assert_reads_the_reference(grouped.batch, refs, set(range(3 * K)))
     assert_settled(grouped.batch)
+
+
+# ---------------------------------------------------------------------------
+# the staging: a round as columns of the documents it names
+# ---------------------------------------------------------------------------
+
+def _walked(payloads) -> list:
+    """The same round for the Python change walk (``append_changes``)."""
+    return [decode_changes(p) if p else None for p in payloads]
+
+
+def _twins(mesh, n_docs, cap=TINY["move_capacity"], nodes=TINY["node_capacity"]):
+    return (DeviceTreeBatch(n_docs, cap, nodes, mesh=mesh),
+            DeviceTreeBatch(n_docs, cap, nodes, mesh=mesh))
+
+
+def assert_twins(columns, walk) -> None:
+    """The columnar staging and the Python walk left the same batch."""
+    assert columns.nodes == walk.nodes
+    assert columns.node_ids == walk.node_ids
+    np.testing.assert_array_equal(np.asarray(columns.parents), np.asarray(walk.parents))
+    np.testing.assert_array_equal(columns.refusals(), walk.refusals())
+    assert columns.children_maps() == walk.children_maps()
+    assert columns.export_state() == walk.export_state()
+    assert_settled(columns)
+
+
+def _concurrent_rounds(seed, rounds, positions=True):
+    """Per round, three documents' deltas: creates, moves, deletes made by
+    two replicas each and exchanged, so a delta names nodes the slot
+    knows and nodes it does not.  Without ``positions`` the second
+    replica's moves ship no fractional index."""
+    rng = random.Random(seed)
+    pairs = []
+    for i in range(3):
+        a, b = LoroDoc(peer=10 * i + 1), LoroDoc(peer=10 * i + 2)
+        root = a.get_tree("tr").create()
+        for _ in range(3):
+            a.get_tree("tr").create(root)
+        a.commit()
+        b.import_(a.export_updates())
+        if not positions:
+            b.get_tree("tr").disable_fractional_index()
+        pairs.append((a, b))
+    marks = [None] * 3
+    for _r in range(rounds):
+        for a, b in pairs:
+            for d in (a, b):
+                tr = d.get_tree("tr")
+                for _ in range(4):
+                    nodes = list(tr.nodes())
+                    x = rng.random()
+                    if x < 0.3 or len(nodes) < 2:
+                        tr.create(rng.choice(nodes) if nodes else None)
+                    elif x < 0.85:
+                        t1, t2 = rng.sample(nodes, 2)
+                        try:
+                            tr.move(t1, t2)
+                        except Exception:  # noqa: BLE001 — a cycle refused where it is made
+                            pass
+                    else:
+                        tr.delete(rng.choice(nodes))
+                d.commit()
+            a.import_(b.export_updates(a.oplog_vv()))
+        ups = []
+        for i, (a, _b) in enumerate(pairs):
+            ups.append(_payload(a, marks[i]))
+            marks[i] = a.oplog_vv()
+        yield ups, pairs[0][0].get_tree("tr").id
+
+
+def test_columns_and_the_python_walk_stage_full_histories_alike(documents, mesh):
+    fed, _refs = documents
+    columns, walk = _twins(mesh, SLOTS)
+    for r in range(2):
+        ups = round_of(fed, range(r * K, (r + 1) * K))
+        columns.append_payloads(ups, CID)
+        walk.append_changes(_walked(ups), CID)
+        assert_twins(columns, walk)
+    # compaction and a release after columnar rounds
+    assert columns.compact([columns.epoch] * 2) == walk.compact([walk.epoch] * 2) > 0
+    assert_twins(columns, walk)
+    for b in (columns, walk):
+        b.release_doc(3)
+    assert_twins(columns, walk)
+    ups = round_of(fed, [3, 4])
+    columns.append_payloads(ups, CID)
+    walk.append_changes(_walked(ups), CID)
+    assert_twins(columns, walk)
+
+
+@pytest.mark.parametrize("positions", [True, False], ids=["positions", "no_positions"])
+def test_columns_and_the_python_walk_stage_deltas_alike(positions, mesh):
+    columns, walk = _twins(mesh, 4, cap=512, nodes=64)
+    for ups, cid in _concurrent_rounds(5, 4, positions):
+        columns.append_payloads(ups + [None], cid)
+        walk.append_changes(_walked(ups), cid)
+        assert_twins(columns, walk)
+    metas = [columns._move_meta(di) for di in range(3)]
+    assert sum(int(m["is_del"].sum()) for m in metas) > 0
+    without = sum(int(((m["pos_len"] < 0) & ~m["is_del"]).sum()) for m in metas)
+    assert (without > 0) == (not positions)
+    assert walk.refusals().sum() > 0
+    # a round whose payload holds no tree container names nothing
+    other = LoroDoc(peer=99)
+    other.get_map("m").set("k", 1)
+    other.commit()
+    before = columns.export_state()
+    columns.append_payloads([_payload(other)] + [None] * 3, cid)
+    walk.append_changes(_walked([_payload(other)]), cid)
+    assert columns.export_state() != before  # the epoch ticked, nothing else
+    assert_twins(columns, walk)
+
+
+@pytest.mark.parametrize("gap", [0, 50_000], ids=["dense", "sparse"])
+def test_new_nodes_are_numbered_in_order_of_first_appearance(gap, mesh):
+    """A delta into a slot that knows one node, whose changes alternate
+    between two authors: the new nodes follow the dictionary in the
+    order the moves name them (w, z, x), which is not the order of their
+    ids, whichever author the payload's peer table puts first.  With a
+    ``gap`` of text between two creates the node counters lie too far
+    apart for the dense first-appearance table, and one sort numbers
+    them."""
+    a, b = LoroDoc(peer=1), LoroDoc(peer=2)
+    tb = b.get_tree("tr")
+    y = tb.create()
+    b.commit()
+    first = _payload(b)  # the slot knows y
+    mark = b.oplog_vv()
+    if gap:
+        b.get_text("t").insert(0, "x" * gap)
+    w = tb.create(y)
+    b.commit()
+    a.import_(b.export_updates())
+    z = a.get_tree("tr").create(w)
+    a.commit()
+    b.import_(a.export_updates())
+    x = tb.create(z)
+    b.commit()
+    columns, walk = _twins(mesh, 2, cap=64, nodes=16)
+    for ups in ([first, None], [_payload(b, mark), None]):
+        columns.append_payloads(ups, tb.id)
+        walk.append_changes(_walked(ups), tb.id)
+        assert_twins(columns, walk)
+    assert columns.nodes[0] == [y, w, z, x]
+
+
+def test_a_coalesced_group_of_columns_is_the_walks(mesh, monkeypatch):
+    columns, walk = _twins(mesh, 4, cap=512, nodes=64)
+    blocks = []
+    real = fleet._scatter_tree_rows
+
+    def watched(cols, blk, d_idx, offsets, mesh_):
+        blocks.append(np.asarray(d_idx).tolist())
+        return real(cols, blk, d_idx, offsets, mesh_)
+
+    monkeypatch.setattr(fleet, "_scatter_tree_rows", watched)
+    for b in (columns, walk):
+        b.begin_coalesce()
+    for ups, cid in _concurrent_rounds(7, 3):  # three deltas of each document
+        columns.append_payloads(ups + [None], cid)
+        walk.append_changes(_walked(ups), cid)
+    for b in (columns, walk):
+        b.flush_coalesce()
+    # ONE block a group: a row a document of the union, its three
+    # segments concatenated
+    assert blocks == [[0, 1, 2, -1]] * 2
+    assert_twins(columns, walk)
+
+
+def test_a_round_stages_a_block_of_the_documents_it_names(documents, mesh, monkeypatch):
+    fed, _refs = documents
+    batch = DeviceTreeBatch(64, 2 * TINY["move_capacity"], TINY["node_capacity"], mesh=mesh)
+    batch.append_payloads([fed[k % len(fed)]["payload"] for k in range(64)], CID)
+    before = {f: np.asarray(getattr(batch.cols, f)).copy() for f in batch.cols._fields}
+    blocks = []
+    real = fleet._scatter_tree_rows
+
+    def watched(cols, blk, d_idx, offsets, mesh_):
+        blocks.append(({f: v.shape for f, v in blk.items()}, np.asarray(d_idx).tolist()))
+        return real(cols, blk, d_idx, offsets, mesh_)
+
+    monkeypatch.setattr(fleet, "_scatter_tree_rows", watched)
+    named = [5, 40, 63]
+    ups = [None] * 64
+    for k in named:
+        ups[k] = fed[k % len(fed)]["payload"]
+    tracing.enable()
+    try:
+        tracing.clear()
+        batch.append_payloads(ups, CID)
+        stage = [e["args"] for e in tracing.events() if e["name"] == "resident.stage"]
+    finally:
+        tracing.disable()
+    width = max(fed[k % len(fed)]["n_ops"] for k in named)
+    max_new = pad_bucket(width, floor=16)
+    assert blocks == [({f: (fleet._named_bucket(3), max_new) for f in before},
+                       named + [-1])]
+    assert stage == [{"docs": 3, "bytes": 25 * 4 * max_new}]
+    after = {f: np.asarray(getattr(batch.cols, f)) for f in before}
+    others = [k for k in range(64) if k not in named]  # slot 0 is the pad row's
+    for f in before:
+        np.testing.assert_array_equal(after[f][others], before[f][others], f)
+        for k in named:  # the window past the old rows took the new ones
+            n = fed[k % len(fed)]["n_ops"]
+            assert after["valid"][k, : 2 * n].all() and not after["valid"][k, 2 * n:].any()
+    assert_settled(batch)
+
+
+@pytest.mark.parametrize("short", ["nodes", "moves"])
+def test_a_capacity_error_leaves_the_batch_untouched(documents, short):
+    fed, _refs = documents
+    width = pad_bucket(max(d["n_ops"] for d in fed), floor=16)
+    if short == "nodes":  # room for every move, 8 nodes past the document's
+        batch = DeviceTreeBatch(4, 2 * width, TINY["nodes"] + 8,
+                                mesh=make_mesh(jax.devices()[:1]))
+    else:  # room for one document's log
+        batch = DeviceTreeBatch(4, width, TINY["node_capacity"],
+                                mesh=make_mesh(jax.devices()[:1]))
+    batch.append_payloads([fed[0]["payload"], None, None, None], CID)
+    a = LoroDoc(peer=77)  # slot 0's delta: 16 new nodes, or one more move
+    tr = a.get_tree(tree_gen.CONTAINER)
+    for _ in range(16 if short == "nodes" else 1):
+        tr.create()
+    a.commit()
+    ups = [_payload(a), fed[1]["payload"], None, None]
+    state = batch.export_state()
+    cols = {f: np.asarray(getattr(batch.cols, f)).copy() for f in batch.cols._fields}
+    nodes = [list(n) for n in batch.nodes]
+    with pytest.raises(RuntimeError, match=f"{short[:-1]} capacity exceeded"):
+        batch.append_payloads(ups, CID)
+    # counts, epoch, node dictionaries, metadata and log, as they were
+    assert batch.export_state() == state
+    assert batch.nodes == nodes and len(batch.node_ids[1]) == 0
+    for f, v in cols.items():
+        np.testing.assert_array_equal(np.asarray(getattr(batch.cols, f)), v)
+
+
+def test_a_payload_the_native_path_cannot_resolve_falls_back_and_counts(documents, monkeypatch):
+    fed, _refs = documents
+    mesh = make_mesh(jax.devices()[:1])
+    columns, walk = _twins(mesh, SLOTS)
+    ups = round_of(fed, range(4))
+    real = native.explode_tree_payload
+
+    def refuse_one(payload, target):
+        if payload is ups[2]:
+            raise ValueError("unresolvable")
+        return real(payload, target)
+
+    monkeypatch.setattr(native, "explode_tree_payload", refuse_one)
+    fell = obs.counter("fleet.host_fallback_total")
+    cols = obs.counter("fleet.resident_tree_column_docs_total")
+    f0, c0 = fell.get(kind="tree_payload"), cols.total()
+    columns.append_payloads(ups, CID)
+    assert fell.get(kind="tree_payload") - f0 == 1
+    assert cols.total() - c0 == 3
+    walk.append_changes(_walked(ups), CID)
+    assert_twins(columns, walk)
+
+
+def test_an_old_blob_imports_and_exports_the_same_bytes():
+    """A v2 blob written field by field in the layout of the tuple era:
+    one document of four moves (two with a position, a move without
+    one, a delete), one empty."""
+    from loro_tpu.codec.binary import Writer
+    from loro_tpu.storage import MemKvStore
+
+    assert DeviceTreeBatch.STATE_VERSION == 2
+    kv = MemKvStore()
+    w = Writer()
+    w.u8(2)  # version
+    for v in (2, 2, 16, 8):  # n_docs, d, move capacity, node capacity
+        w.varint(v)
+    for k in (4, 0):  # counts
+        w.varint(k)
+    w.varint(7)  # epoch
+    w.u8(0)
+    kv.set(b"meta", bytes(w.buf))
+    peer = (1 << 63) + 5
+    rows = [  # lamport, peer, counter, target, parent, is_del, position
+        (0, peer, 0, 0, -1, False, b"\x80"),
+        (1, peer, 9, 1, 0, False, b"\x7f\x10"),
+        (2, peer, 10, 0, -1, False, None),
+        (3, peer, 11, 1, -2, True, None),
+    ]
+    cols = list(zip(*rows))
+    w = Writer()
+    for (_f, dt), vals in zip(DeviceTreeBatch._STATE_SCHEMA,
+                              [cols[0], [p >> 32 for p in cols[1]],
+                               [p & 0xFFFFFFFF for p in cols[1]]] + cols[2:5]):
+        w.bytes_(np.asarray(vals, dt).tobytes())
+    kv.set(b"doc/%08d/log" % 0, bytes(w.buf))
+    kv.set(b"doc/%08d/moveepoch" % 0, np.asarray([5, 6, 6, 7], np.int64).tobytes())
+    w = Writer()
+    w.varint(2)
+    for c in (0, 9):
+        w.u64le(peer)
+        w.zigzag(c)
+    kv.set(b"doc/%08d/nodes" % 0, bytes(w.buf))
+    w = Writer()
+    w.varint(len(rows))
+    for lam, pr, ctr, t, _p, is_del, pos in rows:
+        w.varint(lam)
+        w.u64le(pr)
+        w.zigzag(ctr)
+        w.varint(t)
+        w.u8((1 if is_del else 0) | (2 if pos is not None else 0))
+        if pos is not None:
+            w.bytes_(pos)
+    kv.set(b"doc/%08d/meta" % 0, bytes(w.buf))
+    w = Writer()
+    for _f, dt in DeviceTreeBatch._STATE_SCHEMA:
+        w.bytes_(b"")
+    kv.set(b"doc/%08d/log" % 1, bytes(w.buf))
+    w = Writer()
+    w.varint(0)
+    kv.set(b"doc/%08d/nodes" % 1, bytes(w.buf))
+    kv.set(b"doc/%08d/meta" % 1, bytes(w.buf))
+    blob = kv.export_all()
+    back = DeviceTreeBatch.import_state(blob, mesh=make_mesh(jax.devices()[:1]))
+    assert back.export_state() == blob
+    assert back.children_maps()[0] == {None: [TreeID(peer, 0)]}
+    assert back.parent_maps() == [{TreeID(peer, 0): None}, {}]
+
+
+def test_staging_a_document_makes_no_object_a_move():
+    """One document of 20,000 moves over 1,000 nodes: what staging it
+    keeps is a few objects a NODE (its TreeID, its counter), never one a
+    move."""
+    a = LoroDoc(peer=7)
+    tr = a.get_tree("tr")
+    ids = [tr.create() for _ in range(1000)]
+    rng = random.Random(5)
+    made = 1000
+    while made < 20_000:
+        try:
+            tr.move(ids[rng.randrange(1000)], ids[rng.randrange(1000)])
+            made += 1
+        except Exception:  # noqa: BLE001 — a cycle refused where it is made
+            pass
+    a.commit()
+    payload, cid = _payload(a), tr.id
+    batch = DeviceTreeBatch(2, 32_768, 1_024, mesh=make_mesh(jax.devices()[:1]))
+    batch.append_payloads([payload, None], cid)  # every shape compiled here
+    gc.collect()
+    before = sys.getallocatedblocks()
+    batch.append_payloads([None, payload], cid)
+    gc.collect()
+    grew = sys.getallocatedblocks() - before
+    assert int(batch.counts[1]) == made
+    assert grew < 5_000, grew

@@ -264,6 +264,37 @@ def _read_value(r: Reader, cids: List[ContainerID]) -> Any:
     raise ValueError(f"bad value tag {t}")
 
 
+def read_values_at(buf: bytes, offsets, cids: List[ContainerID]) -> list:
+    """The tagged values that start at byte ``offsets`` of ``buf``, in
+    that order.  When every one is a string of under 128 ASCII bytes (a
+    one-byte length: what a list of typed characters holds), they are
+    gathered by array operations and decoded at once; otherwise each is
+    read by ``_read_value``, as are a few values, for which the array
+    operations would cost more than they save."""
+    import numpy as np
+
+    offsets = np.asarray(offsets, np.int64)
+    data = np.frombuffer(buf, np.uint8)
+    if len(offsets) >= 16 and (data[offsets] == VSTR).all():
+        n = data[offsets + 1].astype(np.int64)
+        if (n < 0x80).all():
+            ends = np.cumsum(n)
+            raw = data[np.repeat(offsets + 2 - ends + n, n)
+                       + np.arange(int(ends[-1]), dtype=np.int64)]
+            if not (raw & 0x80).any():
+                text = raw.tobytes().decode("ascii")
+                if (n == 1).all():
+                    return list(text)
+                ends = ends.tolist()
+                return [text[a:b] for a, b in zip([0] + ends[:-1], ends)]
+    r = Reader(buf)
+    out = []
+    for at in offsets.tolist():
+        r.i = at
+        out.append(_read_value(r, cids))
+    return out
+
+
 def _write_cid(w: Writer, d: _Dicts, c: ContainerID) -> None:
     if c.is_root:
         w.u8(int(c.ctype) | 0x80)

@@ -175,8 +175,9 @@ def _load() -> Optional[ctypes.CDLL]:
             ctypes.c_char_p,
             ctypes.c_longlong,
             ctypes.c_int,
-        ] + [ctypes.c_void_p] * 7 + [ctypes.c_longlong] + [ctypes.c_void_p] * 3 + [
+        ] + [ctypes.c_void_p] * 8 + [ctypes.c_longlong] + [ctypes.c_void_p] * 3 + [
             ctypes.c_longlong,
+            ctypes.c_void_p,
             ctypes.c_void_p,
         ]
         lib.loro_explode_seq_anchor_meta.restype = ctypes.c_longlong
@@ -343,7 +344,11 @@ def explode_seq_payload(payload: bytes, target_cid_index: int):
 def explode_seq_delta_payload(payload: bytes, target_cid_index: int):
     """Incremental decode: element rows whose cross-payload parents come
     back as (peer_idx, counter) for host resolution (out_parent == -2),
-    plus raw delete spans.  Returns a dict of numpy arrays or None if
+    plus raw delete spans.  For a List container ``value_off`` is, for an
+    element of an insert, the byte offset of its tagged value in the
+    payload (``decode_value_at`` reads it; ``content`` numbers the values
+    from 0 within the payload), and -1 for a text or anchor row; for any
+    other container it is None.  Returns a dict of numpy arrays or None if
     the native library is unavailable."""
     lib = _load()
     if lib is None:
@@ -360,10 +365,12 @@ def explode_seq_delta_payload(payload: bytes, target_cid_index: int):
     content = np.empty(n, np.int32)
     ext_peer = np.empty(n, np.int32)
     ext_ctr = np.empty(n, np.int64)
+    value_off = np.empty(n, np.int64)
     del_peer = np.empty(nd, np.int32)
     del_start = np.empty(nd, np.int64)
     del_end = np.empty(nd, np.int64)
     n_del_out = ctypes.c_longlong(0)
+    is_list = ctypes.c_int(0)
     wrote = lib.loro_explode_seq_delta(
         payload,
         len(payload),
@@ -375,12 +382,14 @@ def explode_seq_delta_payload(payload: bytes, target_cid_index: int):
         content.ctypes.data_as(ctypes.c_void_p),
         ext_peer.ctypes.data_as(ctypes.c_void_p),
         ext_ctr.ctypes.data_as(ctypes.c_void_p),
+        value_off.ctypes.data_as(ctypes.c_void_p),
         n,
         del_peer.ctypes.data_as(ctypes.c_void_p),
         del_start.ctypes.data_as(ctypes.c_void_p),
         del_end.ctypes.data_as(ctypes.c_void_p),
         nd,
         ctypes.byref(n_del_out),
+        ctypes.byref(is_list),
     )
     if wrote != n:
         raise CodecDecodeError("native delta decode failed")
@@ -392,6 +401,7 @@ def explode_seq_delta_payload(payload: bytes, target_cid_index: int):
         "content": content,
         "ext_peer_idx": ext_peer,
         "ext_counter": ext_ctr,
+        "value_off": value_off if is_list.value else None,
         "del_peer_idx": del_peer[: n_del_out.value],
         "del_start": del_start[: n_del_out.value],
         "del_end": del_end[: n_del_out.value],

@@ -72,6 +72,7 @@ enum { K_MAP_SET = 0, K_MAP_DEL, K_INSERT_TEXT, K_INSERT_VALUES,
        K_UNKNOWN };
 // value tags
 enum { VNULL = 0, VTRUE, VFALSE, VINT, VF64, VSTR, VBYTES, VLIST, VMAP, VCID };
+enum { CT_LIST = 1 };  // ContainerType.List (core/ids.py)
 enum { PT_NONE = 0, PT_ID = 1, PT_RUNCONT = 2 };
 
 bool skip_value(Reader& r) {
@@ -223,6 +224,53 @@ inline int decode_utf8_cp(const uint8_t* s, uint64_t nb, uint64_t i, uint32_t* o
   if (cp > 0x10ffff) return -1;
   *out = cp;
   return extra + 1;
+}
+
+// skip_value that also checks what the host's decoder (codec/binary.py
+// _read_value) would refuse: strings and map keys strict UTF-8, a
+// container value's index inside the payload's table.  For values the
+// host decodes only when they are read: refused here, at ingest, as the
+// Python walk refuses them.
+bool check_utf8(const uint8_t* s, uint64_t n) {
+  uint64_t i = 0;
+  uint32_t cp;
+  while (i < n) {
+    if (s[i] < 0x80) { i++; continue; }
+    int used = decode_utf8_cp(s, n, i, &cp);
+    if (used < 0) return false;
+    i += used;
+  }
+  return true;
+}
+
+bool check_value(Reader& r, uint64_t n_cids) {
+  switch (r.u8()) {
+    case VNULL: case VTRUE: case VFALSE: return r.ok;
+    case VINT: r.zigzag(); return r.ok;
+    case VF64: r.f64(); return r.ok;
+    case VSTR: {
+      uint64_t n; const uint8_t* s = r.bytes(&n);
+      return r.ok && check_utf8(s, n);
+    }
+    case VBYTES: return r.skip_bytes();
+    case VLIST: {
+      uint64_t n = r.varint();
+      for (uint64_t i = 0; i < n && r.ok; i++)
+        if (!check_value(r, n_cids)) return false;
+      return r.ok;
+    }
+    case VMAP: {
+      uint64_t n = r.varint();
+      for (uint64_t i = 0; i < n && r.ok; i++) {
+        uint64_t nk; const uint8_t* k = r.bytes(&nk);
+        if (!r.ok || !check_utf8(k, nk)) return false;
+        if (!check_value(r, n_cids)) return false;
+      }
+      return r.ok;
+    }
+    case VCID: return r.varint() < n_cids && r.ok;
+    default: r.ok = false; return false;
+  }
 }
 
 // Parse header tables + change meta.  Returns false on malformed input.
@@ -532,20 +580,32 @@ long long loro_count_seq_delta_rows(const uint8_t* buf, long long len,
 // pairs with out_parent = -2, for host-side resolution against the
 // resident batch's id map; deletes are returned as spans instead of
 // folded, for the same reason; style anchors emit rows with
-// out_content = -1.  out_del_* must hold n_del_max entries (from
-// loro_count_seq_deletes).  Returns rows written or -1.
+// out_content = -1.  When the target is a List (*out_is_list = 1; 0
+// otherwise, and out_value_off is left unwritten: text holds no values)
+// out_value_off receives, for an element of an insert of values, the
+// BYTE OFFSET of its tagged value in the payload (decoded lazily
+// host-side, like the anchors' and the movable list's values), and -1
+// for a text or anchor row; such values are checked as the Python
+// decoder would read them (strict UTF-8, container index in the table),
+// so a payload the host could not read later is refused here.
+// out_del_* must hold n_del_max entries (from loro_count_seq_deletes).
+// Returns rows written or -1.
 long long loro_explode_seq_delta(const uint8_t* buf, long long len, int target_cid,
                                  int32_t* out_parent, int32_t* out_side,
                                  int32_t* out_peer, int32_t* out_counter,
                                  int32_t* out_content,
                                  int32_t* out_ext_peer, int64_t* out_ext_ctr,
+                                 int64_t* out_value_off,
                                  long long n_elems,
                                  int32_t* out_del_peer, int64_t* out_del_start,
                                  int64_t* out_del_end, long long n_del_max,
-                                 long long* n_del_out) {
+                                 long long* n_del_out, int* out_is_list) {
   Reader r{buf, buf + len};
   uint64_t n_peers; std::vector<int32_t> cid_types; std::vector<ChangeMeta> metas;
   if (!parse_prelude(r, &n_peers, cid_types, metas)) return -1;
+  bool is_list = target_cid >= 0 && (size_t)target_cid < cid_types.size()
+                 && cid_types[target_cid] == CT_LIST;
+  *out_is_list = is_list ? 1 : 0;
   IdMap map((size_t)(n_elems > 16 ? n_elems : 16));
   long long row = 0, n_del = 0;
   int32_t value_base = 0;
@@ -580,7 +640,7 @@ long long loro_explode_seq_delta(const uint8_t* buf, long long len, int target_c
           parent_row = map.get(idkey(p_peer, p_ctr));
           if (parent_row < 0) { parent_row = -2; ext_peer = p_peer; ext_ctr = p_ctr; }
         }
-        auto emit = [&](int64_t j, uint32_t cp) -> bool {
+        auto emit = [&](int64_t j, uint32_t cp, int64_t voff) -> bool {
           if (row >= n_elems) return false;
           out_parent[row] = (j == 0) ? parent_row : (int32_t)(row - 1);
           out_side[row] = (j == 0) ? side : 1;
@@ -589,6 +649,7 @@ long long loro_explode_seq_delta(const uint8_t* buf, long long len, int target_c
           out_content[row] = (int32_t)cp;
           out_ext_peer[row] = (j == 0 && parent_row == -2) ? (int32_t)ext_peer : -1;
           out_ext_ctr[row] = (j == 0 && parent_row == -2) ? ext_ctr : -1;
+          if (is_list) out_value_off[row] = voff;
           map.put(idkey(m.peer_idx, ctr + j), (int32_t)row);
           row++;
           return true;
@@ -601,7 +662,7 @@ long long loro_explode_seq_delta(const uint8_t* buf, long long len, int target_c
           if (!skip_value(r)) return -1;
           r.u8(); r.varint();
           if (!r.ok) return -1;
-          if (!emit(0, (uint32_t)-1)) return -1;
+          if (!emit(0, (uint32_t)-1, -1)) return -1;
           ctr += 1;
         } else if (kind == K_INSERT_TEXT) {
           uint64_t nb; const uint8_t* s = r.bytes(&nb);
@@ -612,15 +673,16 @@ long long loro_explode_seq_delta(const uint8_t* buf, long long len, int target_c
             int used = decode_utf8_cp(s, nb, i, &cp);
             if (used < 0) return -1;
             i += used;
-            if (!emit(j, cp)) return -1;
+            if (!emit(j, cp, -1)) return -1;
             j++;
           }
           ctr += j;
         } else {
           uint64_t n = r.varint();
           for (uint64_t j = 0; j < n; j++) {
-            if (!skip_value(r)) return -1;
-            if (!emit((int64_t)j, (uint32_t)value_base++)) return -1;
+            int64_t voff = (int64_t)(r.p - buf);
+            if (!check_value(r, cid_types.size())) return -1;
+            if (!emit((int64_t)j, (uint32_t)value_base++, voff)) return -1;
           }
           ctr += (int64_t)n;
         }

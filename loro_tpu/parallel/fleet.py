@@ -32,6 +32,7 @@ from ..core.ids import ContainerID
 from ..errors import DeviceFailure
 from ..obs import metrics as obs
 from ..analysis.lockwitness import named_rlock
+from ..codec.binary import read_values_at
 from ..resilience import get_supervisor
 from ..resilience.faultinject import register_site
 
@@ -950,6 +951,148 @@ def _resolve_row(overlay, idmap, key, di, what):
         ) from None
 
 
+_KEEP_MIN_VALUES = 64
+_KEEP_BYTES_PER_VALUE = 256
+
+
+def _keeps_positions(payload: bytes, n: int) -> bool:
+    """Whether ``n`` values a store names in ``payload`` are worth holding
+    the payload for.  Fewer values are decoded when first read, so a
+    document fed by many small pushes reads from one list and not from
+    thousands of payloads; more than 256 payload bytes a value are
+    decoded at compaction, so the bytes a store holds follow the values
+    that live, not the history that made them."""
+    return n >= _KEEP_MIN_VALUES and len(payload) <= _KEEP_BYTES_PER_VALUE * n
+
+
+def _by_chunk(s: np.ndarray):
+    """``(chunk, positions in s)`` for each chunk ``s`` names, the
+    positions in their order in ``s``."""
+    if (s == s[0]).all():
+        yield int(s[0]), np.arange(len(s))
+        return
+    order = np.argsort(s, kind="stable")
+    for idx in np.split(order, np.flatnonzero(np.diff(s[order])) + 1):
+        yield int(s[idx[0]]), idx
+
+
+class _ValueColumns:
+    """One document's List values as columns, in content-ordinal order:
+    value ``i`` is in chunk ``src[i]`` at ``off[i]``.  Chunk 0 is
+    ``decoded``, a list of values (the Python walk's, a restored state's,
+    a small payload's), the offset an index into it; chunk ``c > 0`` is
+    ``payloads[c - 1]``, a payload the native explode read, ``(bytes,
+    cids)``, the offset that of the tagged value in the bytes.  A payload
+    is the object the round and its WAL record already hold: a round
+    copies and decodes nothing and makes no Python object a value; what
+    it adds is settled into the columns when next read
+    (``_keeps_positions``)."""
+
+    __slots__ = ("decoded", "payloads", "_src", "_off", "_parts", "_n")
+
+    def __init__(self, values=()):
+        self.decoded = list(values)
+        self.payloads: list = []
+        self._src = np.zeros(len(self.decoded), np.int32)
+        self._off = np.arange(len(self.decoded), dtype=np.int64)
+        self._parts: list = []  # (payload or None, cids, offsets) since the last read
+        self._n = len(self.decoded)
+
+    def __len__(self) -> int:
+        return self._n
+
+    def __eq__(self, other) -> bool:
+        if isinstance(other, _ValueColumns):
+            other = other.tolist()
+        return isinstance(other, list) and self.tolist() == other
+
+    __hash__ = None
+
+    def add_positions(self, payload: bytes, cids, offsets: np.ndarray) -> None:
+        if len(offsets):
+            self._parts.append((payload, cids, offsets))
+            self._n += len(offsets)
+
+    def add_values(self, values: list) -> None:
+        if values:
+            at = len(self.decoded)
+            self.decoded.extend(values)
+            self._parts.append(
+                (None, None, np.arange(at, len(self.decoded), dtype=np.int64)))
+            self._n += len(values)
+
+    def _columns(self):
+        if self._parts:
+            src, off = [self._src], [self._off]
+            for payload, cids, o in self._parts:
+                c = 0
+                if payload is not None:
+                    if _keeps_positions(payload, len(o)):
+                        self.payloads.append((payload, cids))
+                        c = len(self.payloads)
+                    else:
+                        at = len(self.decoded)
+                        self.decoded.extend(read_values_at(payload, o, cids))
+                        o = np.arange(at, len(self.decoded), dtype=np.int64)
+                src.append(np.full(len(o), c, np.int32))
+                off.append(o)
+            self._src, self._off = np.concatenate(src), np.concatenate(off)
+            self._parts = []
+        return self._src, self._off
+
+    def _read(self, c: int, offsets: np.ndarray) -> list:
+        if c == 0:
+            return [self.decoded[i] for i in offsets.tolist()]
+        payload, cids = self.payloads[c - 1]
+        return read_values_at(payload, offsets, cids)
+
+    def take(self, ordinals) -> list:
+        """The values at ``ordinals``, decoded, in that order."""
+        from ..errors import DecodeError
+
+        ordinals = np.asarray(ordinals, np.int64)
+        if not len(ordinals):
+            return []
+        if ordinals.min() < 0 or ordinals.max() >= self._n:
+            raise DecodeError(
+                "resident batch: content ordinal outside the value store "
+                "(corrupt restored state?)")
+        src, off = self._columns()
+        s, o = src[ordinals], off[ordinals]
+        out = [None] * len(ordinals)
+        for c, idx in _by_chunk(s):
+            if len(idx) == len(out):
+                return self._read(c, o)
+            for i, v in zip(idx.tolist(), self._read(c, o[idx])):
+                out[i] = v
+        return out
+
+    def tolist(self) -> list:
+        return self.take(np.arange(self._n))
+
+    def select(self, ordinals: np.ndarray) -> "_ValueColumns":
+        """A store of the values at ``ordinals`` alone (compaction): the
+        decoded values that survive go into a new list, a payload is kept
+        while ``_keeps_positions`` holds for its survivors and is decoded
+        into that list otherwise."""
+        src, off = self._columns()
+        s, o = src[ordinals], off[ordinals]
+        out = _ValueColumns()
+        out._src = np.zeros(len(s), np.int32)
+        out._off = np.empty(len(s), np.int64)
+        out._n = len(s)
+        for c, idx in (_by_chunk(s) if len(s) else ()):
+            if c and _keeps_positions(self.payloads[c - 1][0], len(idx)):
+                out.payloads.append(self.payloads[c - 1])
+                out._src[idx] = len(out.payloads)
+                out._off[idx] = o[idx]
+            else:
+                at = len(out.decoded)
+                out.decoded.extend(self._read(c, o[idx]))
+                out._off[idx] = np.arange(at, len(out.decoded))
+        return out
+
+
 class DeviceDocBatch:
     """Device-resident document batch with incremental ingest.
 
@@ -991,7 +1134,9 @@ class DeviceDocBatch:
         from .idmap import make_idmap
 
         self.id2row = [make_idmap() for _ in range(n_docs)]
-        self.value_store: List[List] = [[] for _ in range(n_docs)]
+        # List batches: each document's values as columns of payload
+        # positions (_ValueColumns), decoded only where they are read
+        self.value_store: List[_ValueColumns] = [_ValueColumns() for _ in range(n_docs)]
         # richtext: per-doc style-anchor metadata ((peer, ctr) -> dict)
         # + device-row backmap so delete tombstones deactivate pairs
         self.anchor_meta: List[Dict[Tuple[int, int], dict]] = [dict() for _ in range(n_docs)]
@@ -1293,7 +1438,7 @@ class DeviceDocBatch:
         self.tomb_epoch[di, :] = -1
         self.row_epoch[di, :] = -1
         self.id2row[di] = make_idmap()
-        self.value_store[di] = []
+        self.value_store[di] = _ValueColumns()
         self.anchor_meta[di] = {}
         self.anchor_by_row[di] = {}
         self.order[di] = self._fresh_order()
@@ -1515,9 +1660,7 @@ class DeviceDocBatch:
                 host["content"][di, :n_keep] = np.where(
                     cvals >= 0, vmap[np.clip(cvals, 0, None)], cvals
                 ).astype(host["content"].dtype)
-                self.value_store[di] = [
-                    self.value_store[di][int(o)] for o in uniq
-                ]
+                self.value_store[di] = self.value_store[di].select(uniq)
             host["parent"][di, :n_keep] = new_parent
             host["side"][di, :n_keep] = new_side  # promoted rows inherit
             te_new = te[old_rows].copy()
@@ -1727,9 +1870,8 @@ class DeviceDocBatch:
                     self.anchor_by_row[di].update(
                         {a["row"]: pc for pc, a in stage.items()}
                     )
-            for di, vs in enumerate(value_stages or ()):
-                if vs:
-                    self.value_store[di].extend(vs)
+        if any(value_stages or ()):
+            self._commit_values(value_stages)
         if max_new:
             from .order_maintenance import split_keys
 
@@ -1841,25 +1983,33 @@ class DeviceDocBatch:
                 )
         self.mark_deleted(del_pairs)
 
+    def _commit_values(self, value_stages) -> None:
+        """The round's values into each document's columns: a stage is
+        the Python walk's list of decoded values, or the native explode's
+        ``(payload, cids, value offsets)``, kept as positions."""
+        docs = sum(1 for vs in value_stages if vs)
+        n = sum(len(vs[2]) if isinstance(vs, tuple) else len(vs)
+                for vs in value_stages if vs)
+        with tracing.span("resident.values", docs=docs, values=n):
+            for di, vs in enumerate(value_stages):
+                if isinstance(vs, tuple):
+                    self.value_store[di].add_positions(*vs)
+                elif vs:
+                    self.value_store[di].add_values(vs)
+        obs.counter("fleet.resident_values_total").inc(n, family="list")
+
     def append_payloads(self, per_doc_payloads: Sequence[Optional[bytes]], cid) -> None:
         """Incremental NATIVE ingest: envelope-stripped binary payloads
         -> C++ delta explode (cross-epoch parents/deletes resolved
-        through the per-doc id maps) -> one block scatter.  Falls back
-        to append_changes via the Python decoder per payload when the
+        through the per-doc id maps; a List's values kept as their
+        positions in the payload) -> one block scatter.  Falls back to
+        append_changes via the Python decoder per payload when the
         native library is unavailable."""
-        from ..codec.binary import decode_changes, read_tables
-        from ..native import (
-            available,
-            decode_value_at,
-            explode_seq_anchor_meta,
-            explode_seq_delta_payload,
-        )
+        from ..codec.binary import decode_changes
+        from ..native import available
 
-        if not available() or not self.as_text:
-            # no native lib, or a value batch (the native explode only
-            # understands text payloads): python decode per payload
-            if not available():
-                _obs_fallback("payload_decode")
+        if not available():
+            _obs_fallback("payload_decode")
             self.append_changes(
                 [decode_changes(p) if p else None for p in per_doc_payloads], cid
             )
@@ -1917,7 +2067,7 @@ class DeviceDocBatch:
                     continue  # no ops for this container
                 out = explode_seq_delta_payload(payload, target)
                 anchor_cols = None
-                if (np.asarray(out["content"]) == -1).any():
+                if self.as_text and (out["content"] == -1).any():
                     # style anchors: fetch their metadata natively (same
                     # row numbering as the main explode) so richtexts()
                     # keeps its pair table without the python walk
@@ -1944,11 +2094,21 @@ class DeviceDocBatch:
                     if (res < 0).any():
                         raise KeyError("unresolved cross-epoch parent")
                     prow_arr[ext_rows] = res
+                content = out["content"]
+                if not self.as_text:
+                    voff = out["value_off"]
+                    if voff is None or (voff < 0).any():
+                        raise ValueError("a List batch names a text container")
+                    # the payload numbers its values from 0: they follow
+                    # the document's own, and stay positions until read
+                    content = content + len(self.value_store[di])
+                    if len(voff):
+                        value_stages[di] = (payload, cids, voff)
                 rows_per_doc[di] = {
                     "parent": prow_arr,
                     "side": out["side"],
                     "counter": out["counter"],
-                    "content": out["content"],
+                    "content": content,
                     "peer": peer_u64,
                 }
                 overlays[di] = None  # marker: ids staged in the idmap
@@ -1983,7 +2143,7 @@ class DeviceDocBatch:
             except (KeyError, ValueError):
                 # unresolvable refs or malformed input for the native
                 # path: python fallback for this payload only
-                _obs_fallback("payload_decode")
+                _obs_fallback("payload_decode" if self.as_text else "list_payload")
                 self.id2row[di].abort()
                 rows.clear()
                 rows_per_doc[di] = rows
@@ -1991,6 +2151,7 @@ class DeviceDocBatch:
                 overlays[di] = overlay
                 stage.clear()
                 vstage.clear()
+                value_stages[di] = vstage
                 del del_pairs[n_dels_start:]
                 self._python_rows(
                     di, decode_changes(payload), cid, rows, overlay, del_pairs,
@@ -2097,24 +2258,13 @@ class DeviceDocBatch:
         return [text_from_codes(codes[i], counts[i]) for i in range(self.n_docs)]
 
     def values(self, use_solver: bool = False) -> List[list]:
-        """Materialize value lists (as_text=False batches)."""
-        from ..errors import DecodeError
-
+        """Materialize value lists (as_text=False batches): the device's
+        order of each document's visible content ordinals, gathered from
+        its value columns and decoded."""
         assert not self.as_text, "values() is for as_text=False batches"
         codes, counts = self._materialize(use_solver)
-        out = []
-        for i in range(self.n_docs):
-            store = self.value_store[i]
-            row = []
-            for j in codes[i, : counts[i]]:
-                if not 0 <= j < len(store):
-                    raise DecodeError(
-                        "resident batch: content ordinal outside the value store "
-                        "(corrupt restored state?)"
-                    )
-                row.append(store[j])
-            out.append(row)
-        return out
+        return [self.value_store[i].take(codes[i, : counts[i]])
+                for i in range(self.n_docs)]
 
     # -- checkpoint/resume (fleet-scale; SURVEY §5) --------------------
     STATE_VERSION = 2  # v2: + ingest epoch in meta, tomb-epoch columns
@@ -2173,7 +2323,7 @@ class DeviceDocBatch:
                     self.row_epoch[di, :k].astype(np.int64).tobytes(),
                 )
             w = Writer()
-            _state_write_values(w, d, self.value_store[di])
+            _state_write_values(w, d, self.value_store[di].tolist())
             kv.set(b"doc/%08d/values" % di, bytes(w.buf))
             w = Writer()
             w.varint(len(self.anchor_meta[di]))
@@ -2307,7 +2457,8 @@ class DeviceDocBatch:
             try:
                 vals_b = kv.get(b"doc/%08d/values" % di)
                 if vals_b is not None:
-                    batch.value_store[di] = _state_read_values(vals_b, cids)
+                    batch.value_store[di] = _ValueColumns(
+                        _state_read_values(vals_b, cids))
                 if k:
                     c_col = host["content"][di, :k].astype(np.int64)
                     if as_text:
@@ -3815,8 +3966,9 @@ def _scatter_rows(state, blk, d_idx, offsets, mesh):
     (SeqColumnsU, key_hi, key_lo), each ``[d, capacity]``; ``blk`` holds
     the same ten arrays as ``[k_pad, max_new]``, row ``j`` being document
     ``d_idx[j]``'s new rows for the window ``[offsets[j], offsets[j] +
-    max_new)``, replaced under ``valid`` (padding rows of a window keep
-    the window's previous values, as ``_windowed_scatter_field``).
+    max_new)``, replaced under ``valid``: a window's cells past the
+    document's new rows keep what the table held there (``jnp.where``
+    over the window read back by ``dynamic_slice``, in ``write_row``).
 
     One loop over the block's rows, each a ``dynamic_update_slice`` at
     ``(d_idx[j], offsets[j])`` of every array: the bytes moved follow

@@ -79,6 +79,37 @@ _FAMILIES = {
 }
 _COMPACTABLE = ("text", "list", "tree", "movable")
 
+# glibc mallopt parameters, and the thresholds a resident server fixes
+_M_TRIM_THRESHOLD, _M_MMAP_THRESHOLD = -1, -3
+_MMAP_THRESHOLD = 64 << 20
+_TRIM_THRESHOLD = 2 << 30
+_pages_held = False
+
+
+def _hold_freed_pages() -> bool:
+    """Fix this process's malloc thresholds so freed pages stay in the heap.
+
+    Every resident round allocates and frees the same multi-MB host
+    columns (the explode's outputs, the id map's tables, the staging
+    block).  Under glibc's adaptive thresholds whether such a column
+    reuses heap pages or is a fresh ``mmap`` faulted in page by page
+    depends on what the process freed before, so a round's host time
+    differs from process to process by a fifth.  Fixed thresholds serve
+    columns under 64 MiB from the heap and keep up to 2 GiB of freed heap
+    for the next round.  Once a process; False where the C library has
+    no ``mallopt``."""
+    global _pages_held
+    if not _pages_held:
+        import ctypes
+
+        mallopt = getattr(ctypes.CDLL(None), "mallopt", None)
+        if mallopt is None:
+            return False
+        mallopt(_M_MMAP_THRESHOLD, _MMAP_THRESHOLD)
+        mallopt(_M_TRIM_THRESHOLD, _TRIM_THRESHOLD)
+        _pages_held = True
+    return True
+
 # host-side data errors: poison payloads / bad change lists.  These
 # route to the per-doc isolation pass — anything else escaping an
 # append is a config/logic error that must surface to the caller.
@@ -175,6 +206,7 @@ class ResidentServer:
             mirror_anchor = "deep"
             caps = dict(caps)
             caps["hot_slots"] = int(hot_slots)
+        _hold_freed_pages()
         self.family = family
         self.batch = self._build_batch(family, n_docs, mesh, auto_grow, caps)
         self.n_docs = n_docs
@@ -1499,6 +1531,7 @@ class ResidentServer:
             anchor = MirrorAnchor.decode(anchor_b)
             if anchor.family != family or anchor.n_docs != n_docs:
                 raise DecodeError("ResidentServer state: anchor shape mismatch")
+        _hold_freed_pages()
         srv = cls.__new__(cls)
         srv.family = family
         srv.n_docs = n_docs

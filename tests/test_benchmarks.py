@@ -1,5 +1,5 @@
 """Tier-1 entry for the benchmark's own tests (``benchmarks/tests``, CPU
-rehearsals at tiny sizes; ROADMAP D2): every case of its six modules is
+rehearsals at tiny sizes; ROADMAP D2): every case of its seven modules is
 collected here under a class of its module's name, so that the tier-1
 command (``pytest tests/``) runs them and counts each.  The modules stay
 where the benchmark keeps them and still run on their own
@@ -36,3 +36,4 @@ TestTree = _cases_of("test_tree")
 TestMovable = _cases_of("test_movable")
 TestResident = _cases_of("test_resident")
 TestTreeResident = _cases_of("test_tree_resident")
+TestListResident = _cases_of("test_list_resident")

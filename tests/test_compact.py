@@ -245,6 +245,9 @@ class TestCompact:
         n_vals_before = len(batch.value_store[0])
         assert batch.compact([batch.epoch]) > 0
         assert len(batch.value_store[0]) < n_vals_before
+        # what the store holds, not only what it counts: the survivors
+        assert len(batch.value_store[0].decoded) == len(batch.value_store[0])
+        assert batch.value_store[0].payloads == []
         assert batch.values() == [want]
         # the compacted batch keeps ingesting
         vv = doc.oplog_vv()

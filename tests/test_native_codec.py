@@ -437,3 +437,107 @@ class TestRowTableFallback:
         _assert_same(ex_py, ex_forced)
         _assert_same(ex_py, ex_fast)
         np.testing.assert_array_equal(ex_forced.content, ex_fast.content)
+
+
+class TestListValuePositions:
+    """The delta explode's ``value_off``: where each value of a List
+    insert lies in the payload, the same bytes the Python decoder reads
+    the value from; text rows read -1 and their columns do not change."""
+
+    @staticmethod
+    def _list_payload(seed):
+        from loro_tpu import ContainerType
+
+        rng = random.Random(seed)
+        docs = [LoroDoc(peer=i + 1) for i in range(3)]
+        pool = [None, True, False, 0, -5, 1 << 40, 2.5, "", "a", "ü€𝄞", "x" * 300,
+                b"\x00\xff", [1, ["two"]], {"k": None, "é": 3.5}]
+        for _ in range(40):
+            d = rng.choice(docs)
+            lst = d.get_list("l")
+            if len(lst) and rng.random() < 0.3:
+                lst.delete(rng.randrange(len(lst)), 1)
+            elif rng.random() < 0.1:
+                lst.insert_container(rng.randint(0, len(lst)), ContainerType.Map)
+            else:
+                lst.insert(rng.randint(0, len(lst)), rng.choice(pool))
+            d.commit()
+            if rng.random() < 0.3:
+                src, dst = rng.sample(docs, 2)
+                dst.import_(src.export_updates(dst.oplog_vv()))
+        for src in docs[1:]:
+            docs[0].import_(src.export_updates(docs[0].oplog_vv()))
+        return _payload(docs[0]), docs[0].get_list("l")
+
+    @pytest.mark.parametrize("seed", [1, 2, 3])
+    def test_value_offsets_are_where_the_python_decoder_reads(self, seed, monkeypatch):
+        from loro_tpu.codec import binary
+        from loro_tpu.native import decode_value_at, explode_seq_delta_payload
+
+        payload, lst = self._list_payload(seed)
+        read_at, depth = [], [0]
+        real = binary._read_value
+
+        def recorded(r, cids):
+            if not depth[0]:
+                read_at.append(r.i)
+            depth[0] += 1
+            try:
+                return real(r, cids)
+            finally:
+                depth[0] -= 1
+
+        monkeypatch.setattr(binary, "_read_value", recorded)
+        changes = binary.decode_changes(payload)
+        monkeypatch.undo()
+        _peers, _keys, cids, _r = binary.read_tables(payload)
+        out = explode_seq_delta_payload(payload, cids.index(lst.id))
+        off = out["value_off"]
+        assert off.dtype == np.int64 and (off >= 0).all()
+        np.testing.assert_array_equal(off, read_at)  # every value a List insert's
+        np.testing.assert_array_equal(out["content"], np.arange(len(off)))
+        values = [v for ch in changes for op in ch.ops
+                  if isinstance(op.content, binary.SeqInsert) for v in op.content.content]
+        assert [decode_value_at(payload, int(o), cids) for o in off] == values
+
+    def test_a_text_payloads_columns_are_unchanged(self):
+        from loro_tpu.codec.binary import read_tables
+        from loro_tpu.native import explode_seq_delta_payload, explode_seq_payload
+
+        doc = LoroDoc(peer=3)
+        t = doc.get_text("t")
+        t.insert(0, "héllo wörld")
+        t.mark(0, 4, "bold", True)
+        t.delete(2, 3)
+        doc.get_list("l").insert(0, "v")  # another container's values
+        payload = _payload(doc)
+        target = read_tables(payload)[2].index(t.id)
+        out = explode_seq_delta_payload(payload, target)
+        assert out["value_off"] is None  # text holds no values
+        # the payload-local explode's rows, then the style anchors' (-1)
+        whole = explode_seq_payload(payload, target)
+        n = len(whole[0])
+        for k, v in zip(("parent", "side", "peer_idx", "counter", None, "content"), whole):
+            if k:
+                np.testing.assert_array_equal(out[k][:n], v, k)
+        assert len(out["content"]) == n + 2 and (out["content"][n:] == -1).all()
+        chars = [c for c in out["content"].tolist() if c >= 0]
+        assert "".join(map(chr, chars)) == "héllo wörld"
+
+    @pytest.mark.parametrize("value", ["ab", {"ab": 1}], ids=["string", "map_key"])
+    def test_a_value_the_host_cannot_read_is_refused(self, value):
+        from loro_tpu.codec.binary import decode_changes, read_tables
+        from loro_tpu.errors import CodecDecodeError
+        from loro_tpu.native import explode_seq_delta_payload
+
+        doc = LoroDoc(peer=4)
+        lst = doc.get_list("l")
+        lst.push(value)
+        payload = bytearray(_payload(doc))
+        at = bytes(payload).rindex(b"\x02ab")
+        payload[at + 1] = 0xFF  # not UTF-8
+        payload = bytes(payload)
+        with pytest.raises(CodecDecodeError):  # the Python decoder refuses it too
+            decode_changes(payload)
+        with pytest.raises(CodecDecodeError):
+            explode_seq_delta_payload(payload, read_tables(payload)[2].index(lst.id))
